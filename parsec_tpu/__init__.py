@@ -12,7 +12,11 @@ Public API mirrors the reference's surface (parsec/runtime.h):
     tp.wait()
     ctx.fini()
 """
-from .runtime.context import Context, init
+from .utils.compile_cache import configure_compile_cache
+
+configure_compile_cache()   # before anything below can compile
+
+from .runtime.context import Context, init  # noqa: E402
 from .runtime.compound import CompoundTaskpool, compose
 from .runtime.recursive import recursive_call
 from .runtime.taskpool import (Chore, Dep, Flow, HookReturn, Task, TaskClass,

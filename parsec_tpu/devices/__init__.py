@@ -22,15 +22,20 @@ params.reg_string("device_tpu_platform", "",
 def build_devices(context, enable_tpu: bool = True) -> List[Device]:
     devices: List[Device] = [CPUDevice(0)]
     if enable_tpu and params.get("device_tpu_enabled"):
+        import jax
+        plat = params.get("device_tpu_platform")
         try:
-            import jax
-            plat = params.get("device_tpu_platform")
-            jdevs = jax.devices(plat) if plat else jax.local_devices()
-        except Exception as exc:  # no jax backend available
+            # local: under jax.distributed the global list also holds
+            # the other processes' devices, which nobody here can drive
+            jdevs = jax.local_devices(backend=plat or None)
+        except RuntimeError as exc:
+            # no silent host-only run: a caller that wants one says so
+            # (device_tpu_enabled=0 / enable_tpu=False)
             from ..utils.show_help import show_help
-            show_help("help-runtime.txt", "tpu-device-unavailable",
-                      want_error=True, error=exc)
-            jdevs = []
+            raise RuntimeError(show_help(
+                "help-runtime.txt", "tpu-device-unavailable",
+                want_error=True, platform=plat or "jax default",
+                error=exc)) from exc
         cap = params.get("device_tpu_max")
         if cap >= 0:
             jdevs = jdevs[:cap]
@@ -55,9 +60,8 @@ def _maybe_mesh_device(context, jdevs):
     asks for one (ISSUE 6): this rank takes a contiguous slice of the
     local chips offset by rank*chips (in-process SPMD ranks carve
     disjoint sub-meshes of the virtual device pool; a multi-process
-    deployment owns its local chips outright). Falls back — with a
-    warning, never an error — to one device per chip when the jax
-    build lacks shard_map or too few chips exist."""
+    deployment owns its local chips outright). A shape the attached
+    chips cannot seat is an error."""
     shape = params.get("device_mesh_shape")
     if not shape or not jdevs:
         return None
@@ -66,17 +70,10 @@ def _maybe_mesh_device(context, jdevs):
     need = gp * gq
     if need <= 1:
         return None
-    from ..parallel.mesh import has_shard_map
-    if not has_shard_map():
-        plog.warning("device_mesh_shape=%s ignored: this jax build has "
-                     "no shard_map; attaching one device per chip",
-                     shape)
-        return None
     if len(jdevs) < need:
-        plog.warning("device_mesh_shape=%s needs %d chips, have %d; "
-                     "attaching one device per chip", shape, need,
-                     len(jdevs))
-        return None
+        raise RuntimeError(
+            f"device_mesh_shape={shape} needs {need} chips, this "
+            f"process has {len(jdevs)} ({[str(d) for d in jdevs]})")
     rank = int(getattr(context, "rank", 0) or 0)
     off = (rank * need) % len(jdevs)
     chips = (list(jdevs) * 2)[off:off + need]   # wraps, stays distinct

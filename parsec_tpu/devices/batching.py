@@ -268,7 +268,7 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from ..parallel.mesh import shard_map_fwd
+    from ..parallel.mesh import shard_map_compat
 
     call = spec.call
     k = int(mesh.devices.size)
@@ -292,7 +292,7 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
             return tuple(jnp.stack([rows[i][o] for i in range(n_local)])
                          for o in range(n_out))
 
-    sharded = shard_map_fwd(local_fn, mesh,
+    sharded = shard_map_compat(local_fn, mesh,
                             in_specs=(batch_spec,) * nargs,
                             out_specs=(batch_spec,) * n_out)
     in_sh = NamedSharding(mesh, batch_spec)

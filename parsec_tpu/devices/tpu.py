@@ -132,6 +132,10 @@ class JaxDevice(Device):
                       "dispatch_ns": 0, "dispatch_tasks": 0,
                       "prefetch_issued": 0, "prefetch_hits": 0,
                       "donated": 0,
+                      # every rung a dispatch gave up (a run that must
+                      # prove it stayed on the fast path asserts zero):
+                      # stacked -> per-task, donated -> undonated retry
+                      "batch_downgrades": 0, "donate_retries": 0,
                       # segmented flush (ISSUE 7): flush groups that were
                       # carved into pipelined sub-calls, and the total
                       # sub-calls dispatched for them
@@ -159,14 +163,20 @@ class JaxDevice(Device):
         self._prefetched: Dict[int, int] = {}
 
     def _probe_budget(self) -> int:
-        try:
-            stats = self.jax_device.memory_stats()
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                return int(limit * params.get("tpu_memory_fraction_pct") / 100)
-        except Exception:
-            pass
-        return 8 << 30  # fall back to 8 GiB of accounting space
+        stats = self.jax_device.memory_stats() or {}
+        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+        if limit:
+            return int(limit * params.get("tpu_memory_fraction_pct") / 100)
+        if self.jax_device.platform == "tpu":
+            # the LRU's budget IS the chip's HBM: guessing one would
+            # either evict what fits or overcommit what does not
+            raise RuntimeError(
+                f"{self.jax_device}: memory_stats() reports no "
+                f"bytes_limit ({sorted(stats)}); cannot size the HBM "
+                f"budget of a TPU device")
+        # XLA's host (CPU) backend reports no limit: 8 GiB of
+        # accounting space for the virtual-device test substrate
+        return 8 << 30
 
     # ------------------------------------------------------------------ #
     # submission: the accelerator chore calls this and returns ASYNC     #
@@ -536,10 +546,15 @@ class JaxDevice(Device):
                         spec, n, nargs, static, shapes,
                         self.batch_mode, donate)
                     outs = fn(*flat)
+                    self.stats["donate_retries"] += 1
+                    plog.warning("donated dispatch of %s failed (%s: %s); "
+                                 "retried undonated", spec.name,
+                                 type(exc).__name__, exc)
                     exc = None
                 except Exception as exc2:
                     exc = exc2
             if exc is not None:
+                self.stats["batch_downgrades"] += 1
                 spec.batchable = False
                 spec.cache.clear()
                 if spec.cache_token is not None:
@@ -1029,11 +1044,11 @@ class JaxMeshDevice(JaxDevice):
       the chip count compiles through ``shard_map`` over the mesh
       (devices/batching.build_sharded_callable): ONE jitted call
       executes the batch spread across the chips, each chip running
-      its slot-block of per-example subgraphs (bit-exact vs the
-      single-chip stacked path in ``unroll`` mode).
-    - **Fallback semantics**: groups that do not divide the chip count,
-      classes whose sharded trace fails (``spec.mesh_ok`` cleared), or
-      jax builds without ``shard_map`` fall back to the single-chip
+      its slot-block of per-example subgraphs (equal to the
+      single-chip stacked path to rounding in ``unroll`` mode).
+    - **Fallback semantics**: groups that do not divide the chip count
+      and classes whose sharded trace fails (``spec.mesh_ok`` cleared,
+      counted in ``mesh_downgrades``) fall back to the single-chip
       stacked path (rows colocated on one chip), and below that to
       per-task dispatch — semantics are never at risk.  Buffer
       donation is forced off in mesh mode (donated global assembly
@@ -1058,7 +1073,9 @@ class JaxMeshDevice(JaxDevice):
         # HBM accounting spans every chip of the mesh
         self.mem_budget *= len(self.chips)
         self.stats.update({"mesh_dispatches": 0, "mesh_tasks": 0,
-                           "mesh_moves": 0, "collective_bytes": 0})
+                           "mesh_moves": 0, "collective_bytes": 0,
+                           # sharded -> single-chip stacked
+                           "mesh_downgrades": 0})
         self.donate = False   # see class docstring: forced off on mesh
         # per-progress-cycle memo of transient chip hops: the same tile
         # read by several same-flush tasks homed on one chip moves once
@@ -1169,6 +1186,7 @@ class JaxMeshDevice(JaxDevice):
             try:
                 return self._dispatch_sharded(es, spec, static, chunk)
             except _MeshDispatchFailed as exc:
+                self.stats["mesh_downgrades"] += 1
                 spec.mesh_ok = False
                 plog.warning(
                     "mesh-sharded dispatch of %s disabled (%s); falling "

@@ -24,7 +24,7 @@ mutation model (a flow's body mutates the copy bound to it).  There is
 no antichain batching and no gather-before-scatter wave semantics;
 this is genuine per-task dispatch.
 
-The honest floor (tools/turbo_profile.py, table in BASELINE.md): the
+The honest floor (tools/turbo_profile.py prints the table): the
 C select/release loop itself runs at reference scale (~0.3 us/task)
 and the Python trampoline adds well under 1 us, but every task is
 still ONE XLA executable submission, and that submission — even

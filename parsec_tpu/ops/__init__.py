@@ -14,12 +14,8 @@ from .dgetrf import (dgetrf, dgetrf_factory, dgetrf_nopiv, dgetrf_nopiv_taskpool
 from .pdgemm import pdgemm, pdgemm_factory, pdgemm_taskpool
 from .dtrsm import (dposv, dtrsm_lower_taskpool, dtrsm_lower_trans_taskpool)
 
-try:  # pallas.tpu is optional at import time (older/partial jax builds)
-    from . import pallas_kernels
-    from .pallas_kernels import flash_attention
-except ImportError:  # pragma: no cover
-    pallas_kernels = None
-    flash_attention = None
+from . import pallas_kernels
+from .pallas_kernels import flash_attention
 
 __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "gemm_nn_sub", "gemm", "axpy", "scal", "transpose",

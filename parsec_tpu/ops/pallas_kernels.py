@@ -42,10 +42,7 @@ def _on_tpu() -> bool:
     plat = params.get_or("device_tpu_platform", "string", "")
     if plat:
         return plat == "tpu"
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -261,7 +258,7 @@ def _out_struct(shape, like, dtype=None):
     rejects the call)."""
     from ..parallel.mesh import _vma_of
     dtype = like.dtype if dtype is None else dtype
-    vma = _vma_of(like)  # None on jax versions without VMA tracking
+    vma = _vma_of(like)
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
     return jax.ShapeDtypeStruct(shape, dtype)

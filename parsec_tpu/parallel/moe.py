@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mesh import axis_size
-
 
 def moe_ffn(x: Any, gate_w: Any, w1: Any, w2: Any,
             axis_name: str = "ep", top_k: int = 2,
@@ -28,7 +26,7 @@ def moe_ffn(x: Any, gate_w: Any, w1: Any, w2: Any,
     w2: [E_local, F, D]. Returns [..., D]. Pass precomputed ``gate_logits``
     to share the gating einsum with the load-balance loss."""
     E_local = w1.shape[0]
-    ep = axis_size(axis_name)
+    ep = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     E_total = E_local * ep
 

@@ -15,8 +15,6 @@ from typing import Any, Callable
 import jax.numpy as jnp
 from jax import lax
 
-from .mesh import axis_size
-
 
 def gpipe(stage_fn: Callable[[Any, Any], Any], stage_params: Any,
           x_micro: Any, axis_name: str = "pp", with_aux: bool = False) -> Any:
@@ -33,7 +31,7 @@ def gpipe(stage_fn: Callable[[Any, Any], Any], stage_params: Any,
     garbage and are masked out) and returns (outs, aux_sum) where aux_sum
     is THIS stage's total over its layers x all microbatches.
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = x_micro.shape[0]
     steps = M + S - 1
@@ -76,6 +74,6 @@ def gpipe(stage_fn: Callable[[Any, Any], Any], stage_params: Any,
 def last_stage_value(x: Any, axis_name: str = "pp") -> Any:
     """Reduce a per-shard value to the LAST pp stage's contribution,
     replicated everywhere (masked psum)."""
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     return lax.psum(jnp.where(idx == S - 1, x, jnp.zeros_like(x)), axis_name)

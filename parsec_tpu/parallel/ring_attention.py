@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mesh import axis_size
-
 
 def ring_attention(q: Any, k: Any, v: Any, axis_name: str = "sp",
                    causal: bool = True, scale: float | None = None,
@@ -48,7 +46,7 @@ def ring_attention(q: Any, k: Any, v: Any, axis_name: str = "sp",
 
 def _ring_jnp(q: Any, k: Any, v: Any, axis_name: str,
               causal: bool, scale: float | None) -> Any:
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Tl, Dh = q.shape
     if scale is None:
@@ -99,7 +97,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name: str, causal: bool,
     from ..ops.pallas_kernels import _NEG_INF, flash_attention_stats
     from .mesh import match_vma
 
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Tl, Dh = q.shape
     perm = [(i, (i + 1) % sp) for i in range(sp)]

@@ -13,8 +13,6 @@ from typing import Any
 
 from jax import lax
 
-from .mesh import axis_size
-
 from .ring_attention import local_attention
 
 
@@ -36,7 +34,7 @@ def ulysses_attention(q: Any, k: Any, v: Any, axis_name: str = "sp",
 
     q/k/v: [B, H, T_local, Dh] (H divisible by the sp axis size).
     """
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     assert q.shape[1] % sp == 0, \
         f"ulysses needs heads ({q.shape[1]}) divisible by sp ({sp})"
     qg = heads_to_sequence(q, axis_name)

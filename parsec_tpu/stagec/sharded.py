@@ -1,5 +1,5 @@
 """Sharded stage variants: compile a wave-front stage through
-``parallel/mesh.py::shard_map_fwd`` over the rank's chip mesh
+``parallel/mesh.py::shard_map_compat`` over the rank's chip mesh
 (ISSUE 12 tentpole, part 3).
 
 When the rank's accelerator is a chip MESH (``device_mesh_shape``,
@@ -165,7 +165,7 @@ def build_wavefront_callable(mesh, info: WavefrontInfo, rank: int,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from ..parallel.mesh import shard_map_fwd
+    from ..parallel.mesh import shard_map_compat
 
     k = int(mesh.devices.size)
     n, nargs = info.n, info.nargs
@@ -197,7 +197,7 @@ def build_wavefront_callable(mesh, info: WavefrontInfo, rank: int,
         return tuple(jnp.stack([rows[r][o] for r in range(per)])
                      for o in range(len(flow_names)))
 
-    sharded = shard_map_fwd(local_fn, mesh,
+    sharded = shard_map_compat(local_fn, mesh,
                             in_specs=(batch,) * n_in,
                             out_specs=(batch,) * len(flow_names))
     sh = NamedSharding(mesh, batch)

@@ -877,7 +877,7 @@ def build_xrank_callable(mesh, info, n_max: int, R: int, B: int,
 
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from ..parallel.mesh import shard_map_fwd
+    from ..parallel.mesh import shard_map_compat
 
     nargs = info.nargs
     code, rep_env, flow_names = info.code, info.rep_env, info.flow_names
@@ -919,7 +919,7 @@ def build_xrank_callable(mesh, info, n_max: int, R: int, B: int,
         return tuple(jnp.stack([rows[r][o] for r in range(n_max)])
                      for o in range(nargs))
 
-    sharded = shard_map_fwd(local_fn, mesh,
+    sharded = shard_map_compat(local_fn, mesh,
                             in_specs=(batch,) * n_in,
                             out_specs=(batch,) * nargs)
     sh = NamedSharding(mesh, batch)

@@ -11,13 +11,7 @@ import pytest
 import parsec_tpu
 from parsec_tpu.collections import TwoDimBlockCyclic
 from parsec_tpu.ops import dpotrf_taskpool, make_spd
-from parsec_tpu.parallel.mesh import has_shard_map
 from parsec_tpu.utils.params import params
-
-if not has_shard_map():
-    pytest.skip("no shard_map spelling in this jax build (mesh-sharded "
-                "dispatch falls back to single-chip there)",
-                allow_module_level=True)
 
 
 def _mesh_ctx(shape="2x2", nb_cores=2):
@@ -47,16 +41,11 @@ def test_mesh_shape_parse():
     assert parse_mesh_shape("1x1") == (1, 1)
 
 
-def test_mesh_falls_back_when_too_few_chips():
-    """Fallback semantics: asking for more chips than exist must warn
-    and attach the per-chip devices, never error."""
-    ctx = _mesh_ctx("8x4")
-    try:
-        devs = [d for d in ctx.devices if d.device_type == "tpu"]
-        assert devs and all(not hasattr(d, "chips") for d in devs)
-        assert ctx.device_mesh is None
-    finally:
-        ctx.fini()
+def test_mesh_shape_beyond_the_chips_is_an_error():
+    """Asking for more chips than exist is an error at context build:
+    a quiet one-device-per-chip run would pass for a mesh run."""
+    with pytest.raises(RuntimeError, match="needs 32 chips"):
+        _mesh_ctx("8x4")
 
 
 def test_mesh_block_cyclic_placement():

@@ -73,11 +73,8 @@ def test_dpotrf_mesh_sharded_residual_gate():
     (unroll mode lowers the identical per-example subgraphs, one chip
     or four)."""
     import parsec_tpu
-    from parsec_tpu.parallel.mesh import has_shard_map
     from parsec_tpu.utils.params import params
 
-    if not has_shard_map():
-        pytest.skip("no shard_map spelling in this jax build")
     M = make_spd(192)
 
     def run(shape):

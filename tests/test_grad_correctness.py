@@ -15,12 +15,6 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):
-    # see test_parallel.py: the VMA-tracking shard_map is load-bearing
-    # for the psum-transpose rule these gradient checks validate
-    pytest.skip("jax.shard_map (VMA tracking) not available in this jax",
-                allow_module_level=True)
-
 from parsec_tpu.models import TransformerConfig, init_params, param_specs
 from parsec_tpu.models.transformer import loss_shard
 from parsec_tpu.parallel import make_mesh, shard_map_compat, sync_axes

@@ -246,11 +246,8 @@ def test_mesh_gauges_in_exposition():
     inferred."""
     from parsec_tpu.collections import TwoDimBlockCyclic
     from parsec_tpu.ops import dpotrf_taskpool, make_spd
-    from parsec_tpu.parallel.mesh import has_shard_map
     from parsec_tpu.utils.params import params
 
-    if not has_shard_map():
-        pytest.skip("no shard_map spelling in this jax build")
     with params.cmdline_override("device_mesh_shape", "2x2"):
         ctx = parsec_tpu.Context(nb_cores=2)
         try:

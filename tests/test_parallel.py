@@ -10,13 +10,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):
-    # shard_map_compat needs the VMA-tracking jax.shard_map (the
-    # jax.experimental spelling transposes psum differently, so grads
-    # would be silently wrong, not just shaped differently)
-    pytest.skip("jax.shard_map (VMA tracking) not available in this jax",
-                allow_module_level=True)
-
 from parsec_tpu.parallel import (make_mesh, shard_map_compat, sync_axes,
                                  gpipe, last_stage_value, local_attention,
                                  moe_ffn, ring_attention, ulysses_attention)

@@ -1,38 +1,20 @@
 """Direct coverage for the parallel/mesh.py shard_map seams (ISSUE 6
-satellite): axis-name plumbing through ``shard_map_compat``, the
-``vary_on`` / ``match_vma`` VMA-promotion helpers, and the forward-only
-``shard_map_fwd`` fallback the mesh device dispatches through.
-
-Module-level skip on jax builds without the VMA-tracking
-``jax.shard_map`` (the PR-5 pattern from test_parallel): the compat
-wrapper deliberately refuses the ``jax.experimental`` spelling because
-it transposes psum differently — gradients would be silently wrong.
-``shard_map_fwd`` / ``has_shard_map`` get their no-VMA coverage in
-test_device_mesh.py, which runs on either spelling.
+satellite): axis-name plumbing through ``shard_map_compat`` and the
+``vary_on`` / ``match_vma`` VMA-promotion helpers.
 """
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-if not hasattr(jax, "shard_map"):
-    pytest.skip("jax.shard_map (VMA tracking) not available in this jax",
-                allow_module_level=True)
-
-from parsec_tpu.parallel import make_mesh, shard_map_compat  # noqa: E402
-from parsec_tpu.parallel.mesh import (has_shard_map, match_vma,  # noqa: E402
-                                      shard_map_fwd, vary_on)
+from parsec_tpu.parallel import make_mesh, shard_map_compat
+from parsec_tpu.parallel.mesh import match_vma, vary_on
 
 
 def _mesh22():
     return make_mesh(sizes={"tp": 2, "sp": 2},
                      devices=jax.devices("cpu")[:4])
-
-
-def test_has_shard_map_true_here():
-    assert has_shard_map()
 
 
 def test_axis_name_plumbing_psum_per_axis():
@@ -140,20 +122,3 @@ def test_grad_of_replicated_leaf_is_presummed():
     g = jax.grad(loss)(jnp.float32(2.0), jnp.asarray(x))
     # d/dw sum(w * x) = sum(x), gathered across every shard exactly once
     np.testing.assert_allclose(float(g), float(x.sum()), rtol=1e-6)
-
-
-def test_shard_map_fwd_matches_compat_forward():
-    """The forward-only seam must produce the same forward values as
-    the compat wrapper on builds where both exist (the fallback only
-    ever changes grad transposition, which dispatch never uses)."""
-    mesh = _mesh22()
-    x = np.arange(16, dtype=np.float32)
-
-    def body(xs):
-        return xs * 2.0
-
-    a = shard_map_compat(body, mesh, in_specs=P(("tp", "sp")),
-                         out_specs=P(("tp", "sp")))(jnp.asarray(x))
-    b = shard_map_fwd(body, mesh, in_specs=P(("tp", "sp")),
-                      out_specs=P(("tp", "sp")))(jnp.asarray(x))
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

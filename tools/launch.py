@@ -43,11 +43,22 @@ Multi-host (the thing mpiexec exists to do):
 The v5p-style deployment recipe lives in docs/guide.md ("Multi-host
 deployment").
 
+One process per chip: a TPU chip belongs to one process at a time. A
+single local rank owns every chip of its host (the one-process-per-host
+pod layout). When SEVERAL ranks share this host and it has chips, each
+is given its OWN chip through libtpu's visibility variables (local rank
+r sees chip r alone), and a launch with more local ranks than chips is
+refused — it never has two ranks open one chip. Ranks held to the host
+(JAX_PLATFORMS=cpu in the environment or via --env) need no chip and
+are not bound. The launcher itself never initialises JAX: a parent that
+touched it would hold the chips its ranks need.
+
 Each rank's stdout/stderr is streamed line-by-line with a "[r]" prefix.
 Exit status: 0 when every rank exits 0; otherwise the first non-zero
 rank's status (remaining ranks are killed — fail fast, like mpiexec).
 """
 import argparse
+import glob
 import os
 import shlex
 import signal
@@ -64,6 +75,23 @@ _LOCAL_NAMES = ("localhost", "127.", "::1")
 def _is_local(host: str) -> bool:
     return host == "" or host == "::1" or \
         any(host == n or host.startswith(n) for n in _LOCAL_NAMES)
+
+
+def local_chip_count() -> int:
+    """TPU chips of this host, counted from their device nodes
+    (/dev/accel* on older generations, /dev/vfio/<n> from v5e on) —
+    without JAX, which would open them."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def chip_binding(local_rank: int) -> dict:
+    """libtpu's visibility variables for a process that owns exactly
+    one chip: it sees chip ``local_rank`` as a 1x1x1 topology of its
+    own (no ICI to its neighbours; ranks talk over the comm engine)."""
+    return {"TPU_VISIBLE_CHIPS": str(local_rank),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 def main() -> int:
@@ -124,6 +152,31 @@ def main() -> int:
     base_env = dict(os.environ)
     base_env.update(wired)
 
+    # one process per chip (module docstring): bind, or refuse
+    local_ranks = [r for r in range(n)
+                   if not (args.hosts and not _is_local(host_of[r]))]
+    chips = local_chip_count()
+    bind_chips = (len(local_ranks) > 1 and chips > 0
+                  and base_env.get("JAX_PLATFORMS") != "cpu")
+    if bind_chips and len(local_ranks) > chips:
+        sys.stderr.write(
+            f"launch.py: {len(local_ranks)} local ranks but this host "
+            f"has {chips} TPU chip(s), and a chip belongs to one "
+            f"process at a time. Launch at most {chips} rank(s) here, "
+            f"spread them with --hosts, run the ranks in ONE process "
+            f"(in-process ranks or device_mesh_shape drive all chips), "
+            f"or hold them to the host with --env JAX_PLATFORMS=cpu.\n")
+        return 2
+    if bind_chips and args.jax_distributed:
+        sys.stderr.write(
+            "launch.py: --jax-distributed with several ranks on one "
+            "host with TPU chips is not supported: each rank is bound "
+            "to a chip of its own (a 1x1x1 topology), which cannot "
+            "join ONE global mesh. Run one rank per host (it owns all "
+            "of the host's chips), or use --env JAX_PLATFORMS=cpu for "
+            "the CPU substrate.\n")
+        return 2
+
     procs = []
     for r in range(n):
         rank_over = {"PARSEC_MCA_comm_rank": str(r)}
@@ -150,6 +203,8 @@ def main() -> int:
         else:
             env = dict(base_env)
             env.update(rank_over)
+            if bind_chips:
+                env.update(chip_binding(local_ranks.index(r)))
             procs.append(subprocess.Popen(
                 [sys.executable, args.prog] + args.prog_args,
                 env=env, stdout=subprocess.PIPE,
