@@ -48,10 +48,11 @@ movement is XLA's job, not the wire's.
 """
 from __future__ import annotations
 
+import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
 __all__ = ["DeviceBatchSpec", "bucket_size", "segment_plan",
-           "stacked_callable_key",
+           "stacked_callable_key", "program_name", "KernelsNamedFor",
            "build_stacked_callable", "cached_stacked_callable",
            "build_sharded_callable", "cached_sharded_callable",
            "cached_stage_callable"]
@@ -135,6 +136,65 @@ def segment_plan(n: int, requested: int) -> int:
     while s * 2 <= limit:
         s *= 2
     return s
+
+
+def program_name(spec_name: str, n: int) -> str:
+    """The name a dispatched program carries in a device trace
+    (``XLA Modules`` reads ``jit_<name>``): ``<CLASS>_x<n>`` for a
+    stacked or sharded batch of ``n`` tasks, ``<CLASS>`` for the
+    kernels one task's body calls.  From the class name and the bucket
+    alone — no ids — so the persistent compile cache hits from taskpool
+    to taskpool and from process to process."""
+    cls = re.sub(r"\W", "_", spec_name.split("[", 1)[0]) or "task"
+    return cls if n == 1 else f"{cls}_x{n}"
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+#: (class name, jitted kernel) -> the kernel's clone named for the class
+_class_kernels: Dict[Tuple[str, Any], Any] = {}
+
+
+class KernelsNamedFor:
+    """A module as a per-task device body sees it: every plainly jitted
+    kernel it holds (``ops.potrf``, ...) comes back as a clone named for
+    the body's task class, so a task dispatched alone runs
+    ``jit_<CLASS>`` and not ``jit_potrf``.  The clones are built once
+    per process per (class, kernel), as the kernels themselves are;
+    anything else the module holds passes through."""
+
+    __slots__ = ("_module", "_name")
+
+    def __init__(self, module: Any, cls: str) -> None:
+        self._module = module
+        self._name = program_name(cls, 1)
+
+    def __getattr__(self, attr: str) -> Any:
+        obj = getattr(self._module, attr)
+        info = getattr(obj, "_jit_info", None)     # jax 0.9.0 PjitFunction
+        if info is None or not hasattr(obj, "__wrapped__"):
+            return obj
+        clone = _class_kernels.get((self._name, obj))
+        if clone is None:
+            import functools
+
+            import jax
+            fun = obj.__wrapped__
+
+            @functools.wraps(fun)
+            def kernel(*args, **kwargs):
+                return fun(*args, **kwargs)
+
+            clone = _class_kernels[(self._name, obj)] = jax.jit(
+                _named(kernel, self._name),
+                static_argnums=info.static_argnums,
+                static_argnames=info.static_argnames,
+                donate_argnums=info.donate_argnums,
+                donate_argnames=info.donate_argnames)
+        return clone
 
 
 def stacked_callable_key(n: int, nargs: int, static: Any,
@@ -223,7 +283,9 @@ def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
 
     donate_argnums = tuple(j * n + i for j, d in enumerate(donate) if d
                            for i in range(n))
-    return jax.jit(stacked, donate_argnums=donate_argnums)
+    name = program_name(spec.name, n)
+    return _Program(jax.jit(_named(stacked, name),
+                            donate_argnums=donate_argnums), name)
 
 
 def cached_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
@@ -296,22 +358,40 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
                             in_specs=(batch_spec,) * nargs,
                             out_specs=(batch_spec,) * n_out)
     in_sh = NamedSharding(mesh, batch_spec)
-    fn = jax.jit(sharded, in_shardings=(in_sh,) * nargs,
+    name = program_name(spec.name, n)
+
+    def program(*gargs):
+        return sharded(*gargs)
+
+    fn = jax.jit(_named(program, name), in_shardings=(in_sh,) * nargs,
                  out_shardings=(in_sh,) * n_out)
-    return _ShardedCallable(fn, n_out, in_sh)
+    return _Program(fn, name, n_out, in_sh)
 
 
-class _ShardedCallable:
-    """A jitted shard_map dispatch plus the metadata the device module
-    needs to assemble inputs / slice outputs (jit objects reject
-    attribute assignment, hence the wrapper)."""
+class _Program:
+    """A jitted dispatch plus what the device module needs to know about
+    it (jit objects reject attribute assignment, hence the wrapper): its
+    trace name, whether a device has called it yet (the first call
+    traces, lowers and loads: ``first_call_ns``), and for a shard_map
+    dispatch the metadata to assemble inputs / slice outputs."""
 
-    __slots__ = ("fn", "n_out", "sharding")
+    __slots__ = ("fn", "name", "n_out", "sharding", "_called_on")
 
-    def __init__(self, fn: Callable, n_out: int, sharding: Any) -> None:
+    def __init__(self, fn: Callable, name: str, n_out: int = 0,
+                 sharding: Any = None) -> None:
         self.fn = fn
+        self.name = name
         self.n_out = n_out
         self.sharding = sharding
+        self._called_on: set = set()
 
     def __call__(self, *args):
         return self.fn(*args)
+
+    def first_call_on(self, device: str) -> bool:
+        """True the first time the device named ``device`` asks: each
+        device's first call loads the program onto its own chip(s)."""
+        if device in self._called_on:
+            return False
+        self._called_on.add(device)
+        return True

@@ -28,6 +28,10 @@ class Device:
         # telemetry sink (obs.spans.DeviceObs); wired by ContextObs —
         # None keeps transfer sites on the one-attribute-check fast path
         self._obs = None
+        # the open root span's phase clock (obs.phases.root_span sets
+        # and clears it); None keeps the span sites on the same
+        # one-attribute-check fast path
+        self._phases = None
 
     # registration hooks (no-ops by default)
     def taskpool_register(self, tp) -> None:

@@ -24,6 +24,7 @@ eviction drops our reference (clean) or writes back to host first (owned).
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -82,6 +83,34 @@ def _array_ready(arr: Any) -> bool:
         return True  # host/numpy arrays are always ready
 
 
+class _Xfer:
+    """One host<->device transfer, timed for whoever listens: the
+    telemetry sink (``DeviceObs.xfer``: histogram, overlap gauge, trace
+    span) and, for a stage-in, the open root span's phase clock."""
+
+    __slots__ = ("obs", "clock", "direction", "nbytes", "t0")
+
+    def __init__(self, obs, clock, direction: str, nbytes: int) -> None:
+        self.obs, self.clock = obs, clock
+        self.direction, self.nbytes = direction, nbytes
+        self.t0 = 0
+
+    def __enter__(self) -> None:
+        if self.clock is not None:
+            self.clock.push("stage_in", bytes=self.nbytes)
+        self.t0 = time.monotonic_ns()
+
+    def __exit__(self, *exc) -> bool:
+        if self.obs is not None:
+            self.obs.xfer(self.direction, self.nbytes, self.t0)
+        if self.clock is not None:
+            self.clock.pop("stage_in")
+        return False
+
+
+_NO_XFER = contextlib.nullcontext()
+
+
 class _InFlight:
     __slots__ = ("task", "outputs", "out_flows", "es_hint", "est", "t0",
                  "last_poll", "done_est")
@@ -130,6 +159,12 @@ class JaxDevice(Device):
                       # batched-dispatch pipeline telemetry (guide §9.1)
                       "batches": 0, "batched_tasks": 0,
                       "dispatch_ns": 0, "dispatch_tasks": 0,
+                      # the part of dispatch_ns spent in each program's
+                      # first call on this device (trace + lower + load)
+                      "first_call_ns": 0, "first_calls": 0,
+                      # the part of stage_in_bytes pulled from a copy
+                      # on another chip
+                      "stage_in_peer_bytes": 0,
                       "prefetch_issued": 0, "prefetch_hits": 0,
                       "donated": 0,
                       # every rung a dispatch gave up (a run that must
@@ -214,6 +249,9 @@ class JaxDevice(Device):
     def progress(self, es) -> int:
         if not self._manager_lock.acquire(blocking=False):
             return 0  # someone else is the manager (CAS-owner pattern)
+        clock = self._phases
+        if clock is not None:
+            clock.push("manager")
         try:
             n = 0
             # push phase: drain everything pending and dispatch it —
@@ -262,11 +300,23 @@ class JaxDevice(Device):
                 n += 1
             return n
         finally:
+            if clock is not None:
+                clock.pop("manager")
             self._manager_lock.release()
 
     # ------------------------------------------------------------------ #
     # stage-in / execute                                                 #
     # ------------------------------------------------------------------ #
+    def _xfer(self, direction: str, nbytes: int):
+        """``with self._xfer("in", nbytes): <the transfer>`` — the one
+        timing site of every stage-in / stage-out; a shared no-op
+        unless telemetry or a root span listens."""
+        obs = self._obs
+        clock = self._phases if direction == "in" else None
+        if obs is None and clock is None:
+            return _NO_XFER
+        return _Xfer(obs, clock, direction, nbytes)
+
     def _stage_in(self, task: Task,
                   donate_ok: Optional[Dict[int, bool]] = None) -> List[Any]:
         """Resolve every input flow to an array on this device
@@ -276,7 +326,19 @@ class JaxDevice(Device):
         flows whose device buffer is exclusively ours — either freshly
         device_put here or device-resident with no readers — and hence
         safe to donate to a batched call."""
+        clock = self._phases
+        if clock is None:
+            return self._stage_in_flows(task, donate_ok)
+        clock.push("stage_in", cls=task.task_class.name)
+        try:
+            return self._stage_in_flows(task, donate_ok)
+        finally:
+            clock.pop("stage_in")
+
+    def _stage_in_flows(self, task: Task,
+                        donate_ok: Optional[Dict[int, bool]]) -> List[Any]:
         import jax
+        from ..data.data import is_device_array
         target = self._stage_target(task)
         arrays: List[Any] = []
         for flow in task.task_class.flows:
@@ -303,13 +365,12 @@ class JaxDevice(Device):
                 # credit the stale payload being replaced before reserving
                 self._account(-getattr(copy.payload, "nbytes", 0))
                 self._reserve(nbytes)
-                obs = self._obs
-                t0 = time.monotonic_ns() if obs is not None else 0
-                copy.payload = jax.device_put(src.payload,
-                                              self._placement(data, target))
-                if obs is not None:
-                    obs.xfer("in", nbytes, t0)
+                with self._xfer("in", nbytes):
+                    copy.payload = jax.device_put(
+                        src.payload, self._placement(data, target))
                 self.stats["stage_in_bytes"] += nbytes
+                if is_device_array(src.payload):
+                    self.stats["stage_in_peer_bytes"] += nbytes
                 self._prefetched.pop(id(copy), None)  # staged-over: stale
             elif self._prefetched.pop(id(copy), None) is not None:
                 # the prefetcher staged this tile while an earlier batch
@@ -368,9 +429,16 @@ class JaxDevice(Device):
         fn = chore.dyld_fn
         assert fn is not None, f"tpu chore of {tc.name} has no executable"
         # fn is the DSL's wrapper: (task, per-flow device arrays) -> outputs
+        clock = self._phases
+        if clock is not None:
+            clock.push("dispatch", cls=tc.name, n=1)
         t0 = time.perf_counter_ns()
-        outputs = fn(task, inputs)
-        dt = time.perf_counter_ns() - t0
+        try:
+            outputs = fn(task, inputs)
+        finally:
+            dt = time.perf_counter_ns() - t0
+            if clock is not None:
+                clock.pop("dispatch", tasks=1)
         self.stats["dispatch_ns"] += dt
         self.stats["dispatch_tasks"] += 1
         self._note_profile(es, tc.name, dt / 1e3, 1)
@@ -532,6 +600,11 @@ class JaxDevice(Device):
             donate = tuple(False for _ in donate)
         fn = cached_stacked_callable(spec, n, nargs, static, shapes,
                                      self.batch_mode, donate)
+        first = fn.first_call_on(self.name)
+        span = "first_call" if first else "dispatch"
+        clock = self._phases
+        if clock is not None:
+            clock.push(span, cls=chunk[0][0].task_class.name, n=n)
         t0 = time.perf_counter_ns()
         try:
             outs = fn(*flat)
@@ -545,6 +618,7 @@ class JaxDevice(Device):
                     fn = cached_stacked_callable(
                         spec, n, nargs, static, shapes,
                         self.batch_mode, donate)
+                    first = fn.first_call_on(self.name) or first
                     outs = fn(*flat)
                     self.stats["donate_retries"] += 1
                     plog.warning("donated dispatch of %s failed (%s: %s); "
@@ -554,6 +628,8 @@ class JaxDevice(Device):
                 except Exception as exc2:
                     exc = exc2
             if exc is not None:
+                if clock is not None:
+                    clock.pop(span)
                 self.stats["batch_downgrades"] += 1
                 spec.batchable = False
                 spec.cache.clear()
@@ -567,8 +643,13 @@ class JaxDevice(Device):
                     self._submit_prepared(es, task, est, inputs)
                 return
         dt = time.perf_counter_ns() - t0
+        if clock is not None:
+            clock.pop(span, tasks=n)
         self.stats["dispatch_ns"] += dt
         self.stats["dispatch_tasks"] += n
+        if first:
+            self.stats["first_call_ns"] += dt
+            self.stats["first_calls"] += 1
         self.stats["batches"] += 1
         self.stats["batched_tasks"] += n
         self._note_profile(es, chunk[0][0].task_class.name, dt / 1e3 / n, n)
@@ -593,15 +674,22 @@ class JaxDevice(Device):
         Runs on the submitting worker while the manager executes the
         previous batch, so every check re-validates under the data lock
         before committing (a racing stage-in must win)."""
-        target = self._stage_target(task)
-        for flow in task.task_class.flows:
-            if flow.ctl:
-                continue
-            ref = task.data[flow.flow_index]
-            if ref.data_in is None or ref.data_in.data is None:
-                continue
-            self.prestage_data(ref.data_in.data, dtt=ref.data_in.dtt,
-                               target=target)
+        clock = self._phases
+        if clock is not None:
+            clock.push("stage_in", cls=task.task_class.name)
+        try:
+            target = self._stage_target(task)
+            for flow in task.task_class.flows:
+                if flow.ctl:
+                    continue
+                ref = task.data[flow.flow_index]
+                if ref.data_in is None or ref.data_in.data is None:
+                    continue
+                self.prestage_data(ref.data_in.data, dtt=ref.data_in.dtt,
+                                   target=target)
+        finally:
+            if clock is not None:
+                clock.pop("stage_in")
 
     def prestage_data(self, data: Data, dtt=None, target=None) -> bool:
         """Stage one Data's newest host payload onto this device EARLY
@@ -634,9 +722,8 @@ class JaxDevice(Device):
             return False   # nothing to pull, or source is device-side
         nbytes = getattr(src.payload, "nbytes", 0)
         self._reserve(nbytes)
-        obs = self._obs
-        t0 = time.monotonic_ns() if obs is not None else 0
-        buf = jax.device_put(src.payload, self._placement(data, target))
+        with self._xfer("in", nbytes):
+            buf = jax.device_put(src.payload, self._placement(data, target))
         committed = False
         old = 0
         with data._lock:
@@ -661,8 +748,6 @@ class JaxDevice(Device):
         if committed:
             self._account(-old)
             self._lru_touch(copy, owned=False)
-            if obs is not None:
-                obs.xfer("in", nbytes, t0)
             self.stats["prefetch_issued"] += 1
             self.stats["stage_in_bytes"] += nbytes
         else:
@@ -702,11 +787,10 @@ class JaxDevice(Device):
         nbytes = sum(getattr(s.payload, "nbytes", 0)
                      for _d, _c, s, _v in plan)
         self._reserve(nbytes)
-        obs = self._obs
-        t0 = time.monotonic_ns() if obs is not None else 0
-        bufs = jax.device_put(
-            [s.payload for _d, _c, s, _v in plan],
-            [self._placement(d, target) for d, _c, _s, _v in plan])
+        with self._xfer("in", nbytes):
+            bufs = jax.device_put(
+                [s.payload for _d, _c, s, _v in plan],
+                [self._placement(d, target) for d, _c, _s, _v in plan])
         committed_datas: List[Data] = []
         undo = 0
         for (data, copy, src, src_version), buf in zip(plan, bufs):
@@ -737,8 +821,6 @@ class JaxDevice(Device):
                 undo += getattr(src.payload, "nbytes", 0)
         if undo:
             self._account(-undo)
-        if obs is not None:
-            obs.xfer("in", nbytes, t0)
         self.stats["prefetch_issued"] += len(committed_datas)
         self.stats["stage_in_bytes"] += nbytes - undo
         return committed_datas
@@ -817,6 +899,9 @@ class JaxDevice(Device):
         any async kernel error — against the task that DISPATCHED it
         (es or context present: recorded as a task error; teardown:
         logged)."""
+        clock = self._phases
+        if clock is not None:   # may wait for the kernel: backpressure
+            clock.push("epilog", cls=rec.task.task_class.name)
         self.load_sub(rec.est)
         try:
             for a in rec.outputs:
@@ -843,11 +928,16 @@ class JaxDevice(Device):
             # about when the kernel finished.
             obs.tracker.note("compute", rec.t0,
                              rec.done_est or time.monotonic_ns())
+        if clock is not None:
+            clock.pop("epilog")
 
     def _epilog(self, es, rec: _InFlight) -> None:
         """ref: parsec_cuda_kernel_epilog (device_cuda_module.c:2365-2430)."""
         from ..runtime.scheduling import complete_execution
         task = rec.task
+        clock = self._phases
+        if clock is not None:
+            clock.push("epilog", cls=task.task_class.name)
         if not self.eager_complete:
             # non-eager: the poll loop just observed every output ready —
             # note the device-busy interval (eager mode notes at window
@@ -877,6 +967,8 @@ class JaxDevice(Device):
         if not self.eager_complete:
             self.load_sub(rec.est)  # eager mode releases at window exit
         self.executed_tasks += 1
+        if clock is not None:
+            clock.pop("epilog")
         complete_execution(es, task)
 
     # ------------------------------------------------------------------ #
@@ -929,11 +1021,8 @@ class JaxDevice(Device):
             host = data.get_copy(0)
             if host is not None:
                 # np.array (not asarray): jax arrays view as READ-ONLY numpy
-                obs = self._obs
-                t0 = time.monotonic_ns() if obs is not None else 0
-                host.payload = np.array(copy.payload)
-                if obs is not None:
-                    obs.xfer("out", getattr(host.payload, "nbytes", 0), t0)
+                with self._xfer("out", getattr(copy.payload, "nbytes", 0)):
+                    host.payload = np.array(copy.payload)
                 host.version = copy.version
                 host.coherency = Coherency.OWNED
                 data.owner_device = 0
@@ -964,11 +1053,8 @@ class JaxDevice(Device):
         host = data.get_copy(0)
         # np.array (not asarray): numpy views of jax arrays are READ-ONLY,
         # and host bodies mutate the pulled payload in place
-        obs = self._obs
-        t0 = time.monotonic_ns() if obs is not None else 0
-        arr = np.array(copy.payload)
-        if obs is not None:
-            obs.xfer("out", arr.nbytes, t0)
+        with self._xfer("out", getattr(copy.payload, "nbytes", 0)):
+            arr = np.array(copy.payload)
         if host is None:
             host = DataCopy(data, 0, payload=arr)
             data.attach_copy(host)
@@ -1216,11 +1302,16 @@ class JaxMeshDevice(JaxDevice):
                        for a in chunk[0][3])
         # phase 1 — fallible: trace/assemble/dispatch. Nothing has been
         # submitted yet, so a failure here retries on the fallback path.
+        clock, span = self._phases, None
         try:
             fn = cached_sharded_callable(spec, n, nargs, static, shapes,
                                          self.batch_mode, self.mesh)
             order = sorted(range(n), key=lambda i: self._chip_pos.get(
                 self._stage_target(chunk[i][0]), 0))
+            first = fn.first_call_on(self.name)
+            span = "first_call" if first else "dispatch"
+            if clock is not None:
+                clock.push(span, cls=chunk[0][0].task_class.name, n=n)
             t0 = time.perf_counter_ns()
             # per-chip assembly: ONE jitted stack call per chip builds
             # that chip's shard of every batch arg (rows already
@@ -1242,11 +1333,18 @@ class JaxMeshDevice(JaxDevice):
                 for j in range(nargs)]
             outs = fn(*gargs)
         except Exception as exc:
+            if clock is not None and span is not None:
+                clock.pop(span)
             raise _MeshDispatchFailed(
                 f"{type(exc).__name__}: {exc}") from exc
         dt = time.perf_counter_ns() - t0
+        if clock is not None:
+            clock.pop(span, tasks=n)
         self.stats["dispatch_ns"] += dt
         self.stats["dispatch_tasks"] += n
+        if first:
+            self.stats["first_call_ns"] += dt
+            self.stats["first_calls"] += 1
         self.stats["batches"] += 1
         self.stats["batched_tasks"] += n
         self.stats["mesh_dispatches"] += 1

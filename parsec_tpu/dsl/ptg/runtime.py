@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import threading
+import types
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -580,12 +581,27 @@ class PTGTaskClass(TaskClass):
         written = [(i, f.name) for i, f in enumerate(self.ast.flows)
                    if not f.is_ctl and (self.flows[i].access & FlowAccess.WRITE)]
 
+        named: Optional[Dict[str, Any]] = None
+
         def fn(task: Task, arrays: List[Any]):
+            nonlocal named
             payloads = {}
             for i, f in enumerate(self.ast.flows):
                 if not f.is_ctl:
                     payloads[f.name] = arrays[i]
             env = self._body_env(task, payloads)
+            if named is None:
+                # asked at the first task, when the taskpool's globals
+                # are final: the kernels a task dispatched alone calls
+                # run under the class's name (jit_<CLASS>), as its
+                # stacked programs do
+                from ...devices.batching import KernelsNamedFor
+                named = {
+                    nm: KernelsNamedFor(self.tp.global_env[nm], self.name)
+                    for nm in code.co_names
+                    if isinstance(self.tp.global_env.get(nm),
+                                  types.ModuleType)}
+            env.update(named)
             exec(code, env)
             return tuple(env[name] for i, name in written
                          if task.data[i].data_in is not None)

@@ -1,0 +1,318 @@
+"""obs.phases — where the host time of one blocking request went.
+
+One :class:`PhaseClock` per root span (one ``ops.dpotrf`` /
+``ops.dgeqrf`` / ... call).  Every instrumentation site that has a
+begin and an end — the PINS pairs of ``runtime/scheduling.py`` through
+``TaskProfilerModule``, the device module's manager / stage-in /
+dispatch / epilog sites, ``Context.park`` and ``progress_engines`` —
+pushes and pops a per-thread stack here, and the clock books each
+span's SELF time (duration less what its child spans cover) under the
+span's phase name.  What a thread spent outside every span between the
+root span's start and end is its ``other``; so per thread
+``sum(self_ns) + other_ns == t1_ns - t0_ns`` exactly.
+
+A clock exists only while someone can read it: a JAX profiler session
+is recording when the root span opens (``session_recording``), or the
+context was built with ``profile=True``.  Otherwise ``root_span`` is
+one check and every site stays on its ``is None`` fast path.  Under a
+session every span (but ``UNANNOTATED``) is also written into the
+profiler's own trace as a ``jax.profiler.TraceAnnotation`` named
+``parsec:<phase>``, so the runtime's spans sit on the ``/host:CPU``
+lines on the same clock as the device's ``XLA Ops`` lines.
+
+Closed root spans leave one record each in a bounded process-wide list
+(``completed()``); ``format_report(record)`` prints the table.
+"""
+from __future__ import annotations
+
+import contextlib
+import threading
+import time
+from collections import deque
+from typing import Any, Dict, Iterator, List, Optional
+
+__all__ = ["PHASES", "PhaseClock", "root_span", "session_recording",
+           "completed", "clear_completed", "format_report"]
+
+#: every phase a site books under, in the order the report prints them
+PHASES = ("select", "idle_poll", "parked", "prepare_input", "exec",
+          "schedule", "complete", "release_deps", "manager", "stage_in",
+          "dispatch", "first_call", "epilog", "other")
+
+_now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
+_get_ident = threading.get_ident
+
+#: booked, but not written into the profiler's trace: tens of thousands
+#: of microsecond spans a factorization from idle workers, whose
+#: annotations would cost more than the spans they describe
+UNANNOTATED = frozenset(("select", "idle_poll"))
+
+#: rows of the context's Profile that carry the phase spans, one per
+#: thread (above obs.spans' comm / device / health rows)
+PHASE_STREAM_TID = (1 << 20) + (1 << 11)
+
+_COMPLETED_MAX = 64
+_completed: "deque[Dict[str, Any]]" = deque(maxlen=_COMPLETED_MAX)
+
+
+def completed() -> List[Dict[str, Any]]:
+    """The records of the last (at most 64) closed root spans, oldest
+    first."""
+    return list(_completed)
+
+
+def clear_completed() -> None:
+    _completed.clear()
+
+
+def session_recording() -> bool:
+    """Is a JAX profiler session recording right now?  ``TraceMe``'s own
+    switch (jax 0.9.0): true on every thread from ``start_trace`` (or a
+    profiler-server capture) to ``stop_trace``, ~60 ns to ask."""
+    from jax._src.lib import _profiler
+    return bool(_profiler.TraceMe.is_enabled())
+
+
+class _Thread:
+    """One thread's open spans and booked self times."""
+
+    __slots__ = ("lock", "stack", "phases", "closed", "name", "stream")
+
+    def __init__(self, name: str, stream: Any = None) -> None:
+        self.lock = threading.Lock()
+        # frames: [phase, start_ns, ns covered by closed children,
+        #          TraceAnnotation or None]
+        self.stack: List[list] = []
+        # phase -> [self_ns, spans, tasks]
+        self.phases: Dict[str, List[int]] = {}
+        self.closed = False
+        self.name = name
+        #: this thread's row of the context's Profile, or None
+        self.stream = stream
+
+    def book(self, phase: str, self_ns: int, tasks: int) -> None:
+        acc = self.phases.get(phase)
+        if acc is None:
+            acc = self.phases[phase] = [0, 0, 0]
+        acc[0] += self_ns
+        acc[1] += 1
+        acc[2] += tasks
+
+
+class PhaseClock:
+    """The span stack and phase books of one root span."""
+
+    def __init__(self, op: str, ident: int, traced: bool,
+                 profile: Any = None) -> None:
+        self.op = op
+        self.id = ident
+        #: a profiler session records: spans go into its trace too
+        self.traced = traced
+        #: the context's profiling.trace.Profile, or None
+        self.profile = profile
+        self._threads: Dict[int, _Thread] = {}
+        self._new_lock = threading.Lock()
+        self._t1 = float("inf")
+        self._root_anno = None
+        self.caller = threading.get_ident()
+        if traced:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
+            self._root_anno = self._annotation("parsec:op", op=op, id=ident)
+            self._root_anno.__enter__()
+        self.t0 = _now()
+
+    def _thread(self) -> _Thread:
+        """The calling thread's books (made on its first span)."""
+        ident = _get_ident()
+        with self._new_lock:
+            st = self._threads.get(ident)
+            if st is None:
+                name = threading.current_thread().name
+                if any(t.name == name for t in self._threads.values()):
+                    name = f"{name}#{ident}"
+                stream = None
+                if self.profile is not None:
+                    stream = self.profile.stream(
+                        PHASE_STREAM_TID + threading.get_native_id(),
+                        f"phases:{name}")
+                st = self._threads[ident] = _Thread(name, stream)
+        return st
+
+    def push(self, phase: str, **args: Any) -> None:
+        """Open a span of ``phase`` on the calling thread.  ``args``
+        go into the profiler's trace with the span."""
+        st = self._threads.get(_get_ident())
+        if st is None:
+            st = self._thread()
+        if st.closed:
+            return
+        anno = None
+        if self.traced and phase not in UNANNOTATED:
+            anno = self._annotation("parsec:" + phase, id=self.id, **args)
+            anno.__enter__()
+        t = _now()
+        # only the owner appends; close() cuts what it finds at _t1
+        st.stack.append([phase, t if t < self._t1 else self._t1, 0, anno])
+
+    def pop(self, phase: str, booked_as: Optional[str] = None,
+            tasks: int = 0) -> None:
+        """Close the calling thread's innermost open span of ``phase``
+        and book its self time under ``booked_as`` (default: its own
+        name).  Spans left open above it (an exception skipped their
+        end) close with it; an end with no begin is ignored."""
+        t = _now()
+        st = self._threads.get(_get_ident())
+        if st is None:
+            return
+        stack = st.stack
+        with st.lock:       # against close() booking the same span
+            if t > self._t1:
+                t = self._t1
+            if not stack:
+                return
+            if stack[-1][0] != phase:
+                return self._pop_through(st, phase, t)
+            _, start, covered, anno = stack.pop()
+            if not st.closed:   # else the root span closed over it
+                dur = t - start if t > start else 0
+                # _Thread.book, inline: this is the hot path
+                acc = st.phases.get(booked_as or phase)
+                if acc is None:
+                    acc = st.phases[booked_as or phase] = [0, 0, 0]
+                acc[0] += dur - covered
+                acc[1] += 1
+                acc[2] += tasks
+                if stack:
+                    stack[-1][2] += dur
+                if st.stream is not None:
+                    st.stream.span("phase:" + (booked_as or phase), start, t)
+        if anno is not None:
+            anno.__exit__(None, None, None)
+
+    def _pop_through(self, st: _Thread, phase: str, t: int) -> None:  # holds: st.lock
+        """The rare end whose begin is not on top: close what an
+        exception left open above it, each under its own name."""
+        stack = st.stack
+        at = len(stack) - 1
+        while at >= 0 and stack[at][0] != phase:
+            at -= 1
+        if at < 0:
+            return
+        while len(stack) > at:
+            name, start, covered, anno = stack.pop()
+            if anno is not None:
+                anno.__exit__(None, None, None)
+            if st.closed:
+                continue
+            dur = max(0, t - start)
+            st.book(name, dur - covered, 0)
+            if stack:
+                stack[-1][2] += dur
+            if st.stream is not None:
+                st.stream.span("phase:" + name, start, t)
+
+    def close(self) -> Dict[str, Any]:
+        """End the root span: cut every thread's open spans at now,
+        freeze the books and return the record."""
+        t1 = self._t1 = _now()
+        root = t1 - self.t0
+        by_thread: Dict[str, Any] = {}
+        total: Dict[str, List[int]] = {}
+        caller = None
+        for ident, st in list(self._threads.items()):
+            with st.lock:
+                st.closed = True
+                covered_above = 0
+                for name, start, covered, _anno in reversed(st.stack):
+                    dur = max(0, t1 - start)
+                    st.book(name, dur - covered - covered_above, 0)
+                    covered_above = dur
+                phases = {k: list(v) for k, v in st.phases.items()}
+            other = root - sum(v[0] for v in phases.values())
+            by_thread[st.name] = {
+                "phases": {k: _entry(v) for k, v in phases.items()},
+                "other_ns": other}
+            if ident == self.caller:
+                caller = st.name
+            phases["other"] = [other, 0, 0]
+            for k, v in phases.items():
+                acc = total.setdefault(k, [0, 0, 0])
+                for i in range(3):
+                    acc[i] += v[i]
+        if caller is None:      # the caller never entered a span
+            caller = threading.current_thread().name
+            by_thread[caller] = {"phases": {}, "other_ns": root}
+            total.setdefault("other", [0, 0, 0])[0] += root
+        if self._root_anno is not None:
+            self._root_anno.__exit__(None, None, None)
+        return {"op": self.op, "id": self.id, "t0_ns": self.t0,
+                "t1_ns": t1, "traced": self.traced,
+                "phases": {k: _entry(v) for k, v in total.items()},
+                "by_thread": by_thread, "caller_thread": caller}
+
+
+def _entry(acc: List[int]) -> Dict[str, int]:
+    out = {"self_ns": acc[0], "count": acc[1]}
+    if acc[2]:
+        out["tasks"] = acc[2]
+    return out
+
+
+@contextlib.contextmanager
+def root_span(context: Any, op: str, ident: int) -> Iterator[Optional[PhaseClock]]:
+    """The root span of one blocking request on ``context``.  Yields the
+    clock, or None when nobody could read one (no profiler session
+    recording and no ``Context(profile=True)``) or when the context is
+    already inside a root span."""
+    traced = session_recording()
+    if (not traced and context.profile is None) \
+            or context._phase_clock is not None:
+        yield None
+        return
+    from ..profiling.pins import TaskProfilerModule
+    clock = PhaseClock(op, ident, traced, profile=context.profile)
+    module = context._task_profiler
+    borrowed = module is None
+    if borrowed:    # a session with no profile=True: a module for the call
+        module = TaskProfilerModule(None, context=context)
+        module.enable()
+    module.clock = clock
+    context._phase_clock = clock
+    for dev in context.devices:
+        dev._phases = clock
+    try:
+        yield clock
+    finally:
+        for dev in context.devices:
+            dev._phases = None
+        context._phase_clock = None
+        module.clock = None
+        if borrowed:
+            module.disable()
+        _completed.append(clock.close())
+
+
+def format_report(record: Dict[str, Any]) -> str:
+    """The phase table of one record: per phase the self seconds summed
+    over threads, the span count, the share of threads x root span; then
+    the calling thread's own line."""
+    root = record["t1_ns"] - record["t0_ns"]
+    n = len(record["by_thread"])
+    lines = [f"{record['op']} #{record['id']}: root span {root / 1e9:.6f} s, "
+             f"{n} thread(s), "
+             f"{'profiler session' if record['traced'] else 'no session'}",
+             f"{'phase':<14}{'self s':>12}{'spans':>10}{'tasks':>8}"
+             f"{'share %':>9}"]
+    known = [p for p in PHASES if p in record["phases"]]
+    for name in known + sorted(set(record["phases"]) - set(known)):
+        e = record["phases"][name]
+        lines.append(f"{name:<14}{e['self_ns'] / 1e9:>12.6f}{e['count']:>10}"
+                     f"{e.get('tasks', ''):>8}"
+                     f"{100.0 * e['self_ns'] / (root * n or 1):>9.2f}")
+    mine = record["by_thread"][record["caller_thread"]]
+    lines.append(f"calling thread {record['caller_thread']}: other "
+                 f"{mine['other_ns'] / 1e9:.6f} s "
+                 f"({100.0 * mine['other_ns'] / (root or 1):.2f}% of the "
+                 f"root span)")
+    return "\n".join(lines)

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from ..collections.matrix import TiledMatrix
 from ..dsl import ptg
+from .blocking import run_blocking
 
 DGEQRF_JDF = """
 descA [ type="collection" ]
@@ -143,6 +144,5 @@ def dgeqrf(context, A: TiledMatrix, rank: int = 0, nb_ranks: int = 1) -> None:
     """Factor A = Q R in place: on return the upper triangle of A holds R
     (tiles strictly below the diagonal are zeroed); Q is not retained.
     Blocking: enqueue + wait."""
-    tp = dgeqrf_taskpool(A, rank=rank, nb_ranks=nb_ranks)
-    context.add_taskpool(tp)
-    context.wait()
+    run_blocking(context, "dgeqrf",
+                 [dgeqrf_taskpool(A, rank=rank, nb_ranks=nb_ranks)])

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..collections.matrix import TiledMatrix
 from ..dsl import ptg
+from .blocking import run_blocking
 
 DGETRF_JDF = """
 descA [ type="collection" ]
@@ -146,9 +147,8 @@ def dgetrf_nopiv(context, A: TiledMatrix, rank: int = 0,
                  nb_ranks: int = 1) -> None:
     """Factor A = L U in place (no pivoting): unit-lower L strictly below
     the diagonal, U on and above. Blocking: enqueue + wait."""
-    tp = dgetrf_nopiv_taskpool(A, rank=rank, nb_ranks=nb_ranks)
-    context.add_taskpool(tp)
-    context.wait()
+    run_blocking(context, "dgetrf_nopiv",
+                 [dgetrf_nopiv_taskpool(A, rank=rank, nb_ranks=nb_ranks)])
 
 
 def dgetrf(A: np.ndarray, nb: int = 256):

@@ -14,6 +14,7 @@ import numpy as np
 
 from ..collections.matrix import TiledMatrix
 from ..dsl import ptg
+from .blocking import run_blocking
 
 DPOTRF_L_JDF = """
 descA [ type="collection" ]
@@ -114,9 +115,8 @@ def dpotrf(context, A: TiledMatrix, rank: int = 0, nb_ranks: int = 1) -> None:
     """Run the Cholesky factorization of the SPD tiled matrix A in place
     (lower triangle holds L on return). Blocking: enqueue + wait."""
     assert A.mt == A.nt, "dpotrf needs a square tile grid"
-    tp = dpotrf_taskpool(A, rank=rank, nb_ranks=nb_ranks)
-    context.add_taskpool(tp)
-    context.wait()
+    run_blocking(context, "dpotrf",
+                 [dpotrf_taskpool(A, rank=rank, nb_ranks=nb_ranks)])
 
 
 def dpotrf_taskpool(A: TiledMatrix, rank: int = 0, nb_ranks: int = 1):

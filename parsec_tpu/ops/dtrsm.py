@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from ..collections.matrix import TiledMatrix
 from ..dsl import ptg
+from .blocking import run_blocking
 
 # forward substitution: Y(k) = L(k,k)^{-1} (B(k) - sum_{j<k} L(k,j) Y(j))
 FWD_JDF = """
@@ -229,6 +230,4 @@ def dposv(context, A: TiledMatrix, B: TiledMatrix,
     if params.get("stage_compile") and params.get("stage_compile_chain"):
         from ..stagec.chain import declare_chain
         declare_chain(context, pools)
-    for tp in pools:
-        context.add_taskpool(tp)
-        context.wait()
+    run_blocking(context, "dposv", pools)

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from ..collections.matrix import TiledMatrix
 from ..dsl import ptg
+from .blocking import run_blocking
 
 PDGEMM_JDF = """
 descA [ type="collection" ]
@@ -146,5 +147,4 @@ def pdgemm(context, A: TiledMatrix, B: TiledMatrix, C: TiledMatrix,
     tp = pdgemm_taskpool(A, B, C, alpha=alpha, beta=beta,
                          transa=transa, transb=transb,
                          rank=rank, nb_ranks=nb_ranks)
-    context.add_taskpool(tp)
-    context.wait()
+    run_blocking(context, "pdgemm", [tp])

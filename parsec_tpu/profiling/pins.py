@@ -88,17 +88,40 @@ def _active_decr() -> None:
     _active -= 1
 
 
+#: the begin/end pairs, the phase each books under (obs/phases.py) and
+#: the prefix of its trace events where it always wrote some
+_PAIRS = (
+    ("select", PinsEvent.SELECT_BEGIN, PinsEvent.SELECT_END, None),
+    ("prepare_input", PinsEvent.PREPARE_INPUT_BEGIN,
+     PinsEvent.PREPARE_INPUT_END, "prep:"),
+    ("release_deps", PinsEvent.RELEASE_DEPS_BEGIN,
+     PinsEvent.RELEASE_DEPS_END, None),
+    ("exec", PinsEvent.EXEC_BEGIN, PinsEvent.EXEC_END, "exec:"),
+    ("complete", PinsEvent.COMPLETE_EXEC_BEGIN, PinsEvent.COMPLETE_EXEC_END,
+     "complete:"),
+    ("schedule", PinsEvent.SCHEDULE_BEGIN, PinsEvent.SCHEDULE_END, None),
+)
+#: by event number: (phase, is the BEGIN of its pair, trace prefix)
+_SITE: List[Any] = [None] * _N_EVENTS
+for _phase, _begin, _end, _prefix in _PAIRS:
+    _SITE[_begin] = (_phase, True, _prefix)
+    _SITE[_end] = (_phase, False, _prefix)
+
+
 class TaskProfilerModule(PinsModule):
-    """Turns EXEC/SELECT/COMPLETE PINS events into trace events
-    (ref: pins/task_profiler)."""
+    """Turns the begin/end PINS pairs into trace events (EXEC,
+    PREPARE_INPUT, COMPLETE_EXEC; ref: pins/task_profiler) and, while a
+    root span is open (``clock``: an obs.phases.PhaseClock), into that
+    request's phase books: every pair pushes and pops the thread's span
+    stack there."""
 
     name = "task_profiler"
-    events = [PinsEvent.EXEC_BEGIN, PinsEvent.EXEC_END,
-              PinsEvent.PREPARE_INPUT_BEGIN, PinsEvent.PREPARE_INPUT_END,
-              PinsEvent.COMPLETE_EXEC_BEGIN, PinsEvent.COMPLETE_EXEC_END]
+    events = [ev for pair in _PAIRS for ev in pair[1:3]]
 
-    def __init__(self, profile, context: Any = None) -> None:
-        self.profile = profile  # profiling.trace.Profile
+    def __init__(self, profile=None, context: Any = None) -> None:
+        # profiling.trace.Profile, or None for a module that only feeds
+        # the phase clock (a profiler session with no profile=True)
+        self.profile = profile
         # PINS sites are process-global but profiles are per-rank: with
         # several in-process SPMD contexts, a context-bound module must
         # ignore the other ranks' events or every profile records every
@@ -108,74 +131,50 @@ class TaskProfilerModule(PinsModule):
         # on, the exec duration feeds the histogram from THIS module's
         # existing hook instead of a second PINS callback per task
         self.exec_timer: Any = None
+        # the open root span's phase clock (obs.phases.root_span sets
+        # and clears it); None between requests
+        self.clock: Any = None
 
     def callback(self, es: Any, event: PinsEvent, payload: Any) -> None:
         if self.context is not None and es.context is not self.context:
             return
+        phase, begin, prefix = _SITE[event]
+        clock = self.clock
+        if clock is not None:
+            if not begin:
+                # a select that returned no task was idle polling
+                clock.pop(phase, "idle_poll" if payload is None
+                          and phase == "select" else None)
+            elif not clock.traced or payload is None:
+                clock.push(phase)
+            elif phase == "schedule":
+                clock.push(phase, n=len(payload))
+            else:
+                clock.push(phase, cls=payload.task_class.name)
+        if self.profile is None or prefix is None:
+            return
         stream = self.profile.thread_stream(es)
-        name = payload.task_class.name if payload is not None and hasattr(payload, "task_class") else "runtime"
-        if event in (PinsEvent.EXEC_BEGIN,):
-            if self.exec_timer is not None:
-                self.exec_timer.begin(es.th_id)
-            info = {"task": payload.snprintf()} if payload is not None else None
+        key = prefix + (payload.task_class.name if payload is not None
+                        and hasattr(payload, "task_class") else "runtime")
+        timer = self.exec_timer if phase == "exec" else None
+        if not begin:
+            stream.end(key)
+            if timer is not None:
+                timer.end(es.th_id)
+            return
+        if timer is not None:
+            timer.begin(es.th_id)
+        info = None
+        if phase == "exec" and payload is not None:
+            info = {"task": payload.snprintf()}
             # a task class may pin extra span context (stagec/runtime:
             # a compiled stage's member list + the wire trace contexts
             # that fed it, so the merged timeline can attribute the
             # fused span to its cross-rank inputs)
-            extra = getattr(payload.task_class, "trace_info", None) \
-                if payload is not None else None
+            extra = getattr(payload.task_class, "trace_info", None)
             if extra:
-                info = {**(info or {}), **extra}
-            stream.begin("exec:" + name, info=info)
-        elif event in (PinsEvent.EXEC_END,):
-            stream.end("exec:" + name)
-            if self.exec_timer is not None:
-                self.exec_timer.end(es.th_id)
-        elif event == PinsEvent.PREPARE_INPUT_BEGIN:
-            stream.begin("prep:" + name)
-        elif event == PinsEvent.PREPARE_INPUT_END:
-            stream.end("prep:" + name)
-        elif event == PinsEvent.COMPLETE_EXEC_BEGIN:
-            stream.begin("complete:" + name)
-        elif event == PinsEvent.COMPLETE_EXEC_END:
-            stream.end("complete:" + name)
-
-
-class PrintStealsModule(PinsModule):
-    """Counts scheduler selects per thread (ref: pins/print_steals)."""
-
-    name = "print_steals"
-    events = [PinsEvent.SELECT_END]
-
-    def __init__(self) -> None:
-        self.counts: Dict[int, int] = {}
-
-    def callback(self, es: Any, event: PinsEvent, payload: Any) -> None:
-        if payload is not None:
-            self.counts[es.th_id] = self.counts.get(es.th_id, 0) + 1
-
-
-class AlperfModule(PinsModule):
-    """Algorithmic performance counters: tasks enabled/retired per class
-    (ref: pins/alperf)."""
-
-    name = "alperf"
-    events = [PinsEvent.COMPLETE_EXEC_END, PinsEvent.SCHEDULE_END]
-
-    def __init__(self) -> None:
-        self.retired: Dict[str, int] = {}
-        self.enabled: Dict[str, int] = {}
-        self._lock = threading.Lock()
-
-    def callback(self, es: Any, event: PinsEvent, payload: Any) -> None:
-        with self._lock:
-            if event == PinsEvent.COMPLETE_EXEC_END and payload is not None:
-                k = payload.task_class.name
-                self.retired[k] = self.retired.get(k, 0) + 1
-            elif event == PinsEvent.SCHEDULE_END and payload:
-                for t in payload:
-                    k = t.task_class.name
-                    self.enabled[k] = self.enabled.get(k, 0) + 1
+                info = {**info, **extra}
+        stream.begin(key, info=info)
 
 
 class IteratorsCheckerModule(PinsModule):
@@ -356,6 +355,6 @@ class HWCountersModule(PinsModule):
 # dotted path or entry point through the same repository
 from ..utils import mca as _mca  # noqa: E402
 
-for _cls in (TaskProfilerModule, PrintStealsModule, AlperfModule,
-             IteratorsCheckerModule, TaskTimeModule, HWCountersModule):
+for _cls in (TaskProfilerModule, IteratorsCheckerModule, TaskTimeModule,
+             HWCountersModule):
     _mca.register("pins", _cls.name, _cls)

@@ -160,6 +160,10 @@ class Context:
         self.profile: Optional[Profile] = None
         self._prof_prefix = None
         self._task_profiler = None
+        # the open root span's phase clock (obs/phases.py root_span sets
+        # and clears it); None keeps park / progress_engines on a
+        # one-attribute-check fast path
+        self._phase_clock = None
         self._forensics_dumped = False
         if profile or prof_prefix:
             self.profile = Profile(rank=rank)
@@ -651,13 +655,30 @@ class Context:
             self._work_cond.notify_all()
 
     def park(self, max_sleep: float) -> None:
+        clock = self._phase_clock
+        if clock is not None:
+            clock.push("parked")
         with self._work_cond:
             self._work_cond.wait(timeout=max_sleep)
+        if clock is not None:
+            clock.pop("parked")
 
     def progress_engines(self, es: ExecutionStream) -> int:
         """Idle-cycle progress of device managers + comm engine
         (the TPU analog of the CUDA manager/progress_stream polling and the
         funnelled comm thread; SURVEY.md §3.3-3.4)."""
+        clock = self._phase_clock
+        if clock is None:
+            return self._progress_engines(es)
+        # a worker with no task polls here: the pass's self time (less
+        # the manager work it runs) is idle polling
+        clock.push("idle_poll")
+        try:
+            return self._progress_engines(es)
+        finally:
+            clock.pop("idle_poll")
+
+    def _progress_engines(self, es: ExecutionStream) -> int:
         n = 0
         while True:
             try:

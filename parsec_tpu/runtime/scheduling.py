@@ -114,9 +114,11 @@ def schedule(es: ExecutionStream, tasks: List[Task], distance: int = 0) -> None:
                 f"scheduling task {t.snprintf()} in state {t.status}"
     PINS(es, PinsEvent.SCHEDULE_BEGIN, tasks)
     ctx.scheduler.schedule(es, tasks, distance)
-    PINS(es, PinsEvent.SCHEDULE_END, tasks)
     ctx.sde.inc(TASKS_ENABLED, len(tasks))
+    # inside the pair: waking the parked workers is part of what
+    # handing tasks over costs the releasing thread
     ctx.wake_workers(len(tasks))
+    PINS(es, PinsEvent.SCHEDULE_END, tasks)
 
 
 def schedule_keep_best(es: ExecutionStream, tasks: List[Task], distance: int = 0) -> None:
