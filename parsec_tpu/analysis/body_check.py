@@ -30,6 +30,12 @@ Finding codes (BDY2xx):
 - ``BDY205`` missing-write (warn): a device body never assigns one of
   its written (RW/WRITE) flow names — the staged-out "result" is the
   unmodified input.
+- ``BDY206`` unshared-programs (warn): a device body reads something
+  the program cache cannot name by value (a collection, a name the
+  JDF's prologue defines, an ``import`` of its own, ``eval`` / ``exec``
+  / ``globals``) — the class gets no ``cache_token``
+  (dsl/ptg/body_token.py) and EVERY taskpool traces, lowers and loads
+  its own copies of the class's stacked programs.
 
 Only accelerator bodies (``BODY [type=tpu]`` and friends) are checked:
 CPU bodies run on the host interpreter where all of this is legal.
@@ -42,6 +48,7 @@ import textwrap
 from typing import Callable, List, Optional, Sequence, Set, Tuple
 
 from ..dsl.ptg.ast import JDFFile, RangeExpr, TaskClassAST
+from ..dsl.ptg.body_token import body_reads
 from . import Finding
 
 #: attribute roots whose *call* in a traced body breaks tracing
@@ -187,11 +194,52 @@ def _aliased_tiles(tc: TaskClassAST) -> List[Tuple[str, str, str]]:
     return out
 
 
+def _prologue_names(jdf: JDFFile) -> Set[str]:
+    """Names the JDF's prologue binds to objects made anew for every
+    taskpool (its blocks run once per ``new()``): functions, classes,
+    assigned values, ``from m import f``.  ``import m`` binds a module,
+    which a token names by the attributes read through it."""
+    out: Set[str] = set()
+    for block in jdf.prologue:
+        try:
+            tree = pyast.parse(textwrap.dedent(block))
+        except SyntaxError:
+            continue
+        for node in tree.body:
+            if isinstance(node, (pyast.FunctionDef, pyast.AsyncFunctionDef,
+                                 pyast.ClassDef)):
+                out.add(node.name)
+            elif isinstance(node, pyast.ImportFrom):
+                out.update(a.asname or a.name for a in node.names)
+        out |= _assigned_names(tree)
+    return out
+
+
+def _unnameable_read(tree: pyast.AST, shadowed: Set[str],
+                     collections: Set[str], prologue: Set[str]
+                     ) -> Optional[str]:
+    """What in a device body keeps its class from a ``cache_token``, as
+    far as the source can tell (the values are only known at run time);
+    None when nothing does."""
+    reads = body_reads(tree)
+    if reads is None:
+        return "an import of its own or eval/exec/globals"
+    for nm in sorted(set(reads) - shadowed):
+        if nm in collections:
+            return f"the collection {nm!r}"
+        if nm in prologue:
+            return f"{nm!r}, which the prologue defines"
+    return None
+
+
 def check_jdf_bodies(jdf: JDFFile, name: Optional[str] = None
                      ) -> List[Finding]:
     """Lint every accelerator BODY of a parsed JDF."""
     name = name or jdf.name
     findings: List[Finding] = []
+    collections = {g.name for g in jdf.globals
+                   if g.properties.get("type", "").lower() == "collection"}
+    prologue = _prologue_names(jdf)
     for tc in jdf.task_classes:
         flow_names = [f.name for f in tc.flows if not f.is_ctl]
         written = [f.name for f in tc.flows
@@ -225,6 +273,17 @@ def check_jdf_bodies(jdf: JDFFile, name: Optional[str] = None
                     f"is built, every instance pays the per-task dyld "
                     f"dispatch", where, severity="warn"))
             _check_traced_source(tree, where, label, flow_names, findings)
+            why = _unnameable_read(
+                tree, set(flow_names) | {ld.name for ld in tc.locals},
+                collections, prologue)
+            if why is not None:
+                findings.append(Finding(
+                    "BDY206",
+                    f"{label}: reads {why} — the program cache cannot "
+                    f"name that by value, so the class gets no "
+                    f"cache_token and every taskpool traces, lowers and "
+                    f"loads its own copies of the class's stacked "
+                    f"programs", where, severity="warn"))
             if written and not (_assigned_names(tree) & set(written)):
                 findings.append(Finding(
                     "BDY205",

@@ -30,11 +30,17 @@ Two stacking modes (``device_batch_mode``):
   a *different batched algorithm* (e.g. blocked triangular solve), so
   results are only approximately equal to per-task execution.
 
-Batch sizes are bucketed to powers of two so the jitted-callable cache
-stays small; the cache lives ON the spec (so it dies with its taskpool)
-keyed by (bucket, static, shapes/dtypes, donate mask, mode) — or in the
-process-wide per-token cache for specs declaring taskpool independence
-(``cache_token``).
+Batch sizes are bucketed to powers of two so the set of programs stays
+small.  A program is keyed by (bucket, static, shapes/dtypes, donate
+mask, mode) and built ONCE PER PROCESS for every spec that can say what
+its ``call`` traces to (``cache_token``): a DTD kernel's token is the
+user function, a PTG body's is made from what the body reads
+(dsl/ptg/body_token.py) at its first stacked dispatch.  A fresh
+taskpool over the same bodies and tile shapes then dispatches programs
+an earlier one traced, lowered and loaded (``program_reuse`` in
+``dev.stats``).  Only a spec with no token -- a body reading a
+collection, a prologue helper, ``eval`` -- keeps its programs on itself,
+where they die with its taskpool.
 
 Mesh-sharded stacking (ISSUE 6): when the rank's device is a chip MESH
 (``device_mesh_shape``), a flush group whose size divides the chip
@@ -49,10 +55,13 @@ movement is XLA's job, not the wire's.
 from __future__ import annotations
 
 import re
-from typing import Any, Callable, Dict, Optional, Tuple
+import threading
+import weakref
+from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 __all__ = ["DeviceBatchSpec", "bucket_size", "segment_plan",
            "stacked_callable_key", "program_name", "KernelsNamedFor",
+           "settle", "downgrade",
            "build_stacked_callable", "cached_stacked_callable",
            "build_sharded_callable", "cached_sharded_callable",
            "cached_stage_callable"]
@@ -77,30 +86,40 @@ class DeviceBatchSpec:
         the first batched dispatch and the spec permanently falls back
         (``batchable = False``).
 
-    ``cache_token`` (optional): a stable hashable proving the traced
-    computation is taskpool-independent (e.g. the DTD user kernel:
-    ``call`` reassembles its args from the static key and calls only
-    that function).  When given, stacked callables are cached in the
-    process-wide cache keyed by the token, so a NEW taskpool inserting
-    the same kernel over the same shapes hits an already-compiled
-    callable (steady-state submission across runs).  Leave ``None``
-    when ``call`` closes over per-taskpool state (the PTG body env):
-    those cache on the spec and die with it.
+    ``cache_token`` (optional): a hashable naming, by value, everything
+    ``call`` traces to, with ``call`` reading nothing else (the DTD
+    user kernel: ``call`` reassembles its args from the static key and
+    calls only that function).  Two specs with equal tokens share their
+    programs process-wide, so a NEW taskpool over the same kernel and
+    shapes neither traces, lowers nor loads; a trace failure is
+    remembered with the token too.  With no token the programs cache on
+    the spec and die with it.
+
+    ``late_token`` (optional): ``() -> None | (cache_token, call)`` for
+    a spec that can only say what it reads once its taskpool is set up
+    (a PTG body: the taskpool's globals are final at the first task,
+    not when the class is built).  Asked once, at the spec's first
+    stacked dispatch (:func:`settle`); an answer replaces ``call`` with
+    one built from the token alone, so the shared program holds no
+    taskpool.
     """
 
     __slots__ = ("name", "extract", "call", "batchable", "cache",
-                 "cache_token", "mesh_ok")
+                 "cache_token", "late_token", "mesh_ok", "__weakref__")
 
     def __init__(self, name: str,
                  extract: Callable[[Any, Any], Optional[Tuple]],
                  call: Callable[[Tuple, Any], Tuple],
-                 cache_token: Any = None) -> None:
+                 cache_token: Any = None,
+                 late_token: Optional[Callable[[], Optional[Tuple]]] = None
+                 ) -> None:
         self.name = name
         self.extract = extract
         self.call = call
         self.batchable = True   # cleared on first trace failure
-        self.cache: Dict[Any, Any] = {}   # stacked-callable AOT cache
+        self.cache: Dict[Any, Any] = {}   # programs of a spec with no token
         self.cache_token = cache_token
+        self.late_token = late_token
         # cleared when the mesh-sharded stacking of THIS class fails to
         # trace/dispatch (the single-chip stacked path stays available)
         self.mesh_ok = True
@@ -206,6 +225,56 @@ def stacked_callable_key(n: int, nargs: int, static: Any,
 #: (taskpool-independent bodies): token -> key -> jitted callable
 _shared_cache: Dict[Any, Dict[Any, Any]] = {}
 
+#: tokens whose ``call`` failed to trace: every later spec of the token
+#: is downgraded without tracing again
+_untraceable: Set[Any] = set()
+
+#: serializes settling a spec and building a program: the managers of
+#: several devices dispatch one taskpool's specs concurrently, and each
+#: program is to be built (and each downgrade counted) once
+_lock = threading.Lock()
+
+
+def settle(spec: DeviceBatchSpec) -> bool:
+    """Ask a spec's ``late_token``, once, at its first stacked dispatch.
+    False when that downgraded the spec: an earlier spec of the same
+    token failed to trace, and this one goes per-task without trying."""
+    with _lock:
+        late = spec.late_token
+        if late is None:
+            return True     # another device's manager settled it
+        named = late()
+        if named is not None:
+            spec.cache_token, spec.call = named
+        known_bad = spec.cache_token in _untraceable
+        if known_bad:
+            spec.batchable = False
+        spec.late_token = None      # last: readers take no lock
+        return not known_bad
+
+
+def downgrade(spec: DeviceBatchSpec) -> None:
+    """``spec.call`` failed to trace or dispatch stacked: the spec, and
+    every later spec of its token, dispatch per-task from here on."""
+    spec.batchable = False
+    spec.cache.clear()
+    if spec.cache_token is not None:
+        _untraceable.add(spec.cache_token)
+        _shared_cache.pop(spec.cache_token, None)
+
+
+def _cached(spec: DeviceBatchSpec, key: Tuple,
+            build: Callable[[], "_Program"]) -> "_Program":
+    cache = (_shared_cache.setdefault(spec.cache_token, {})
+             if spec.cache_token is not None else spec.cache)
+    fn = cache.get(key)
+    if fn is None:
+        with _lock:
+            fn = cache.get(key)
+            if fn is None:
+                fn = cache[key] = build()
+    return fn
+
 #: process-wide stage-callable cache (stagec/, ISSUE 12), living
 #: alongside the bucket cache above: token -> key -> fused jitted
 #: callable (or the stagec failure sentinel).  The token embeds the
@@ -232,17 +301,12 @@ def cached_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
                             static: Any, shapes: Tuple, mode: str,
                             donate: Tuple[bool, ...] = ()) -> Callable:
     """The AOT-cached stacked callable for this signature: per-token
-    process-wide when the spec declares taskpool independence (a new
-    taskpool over the same kernel/shapes skips tracing AND compiling),
-    else per-spec (dies with the taskpool)."""
-    key = stacked_callable_key(n, nargs, static, shapes, donate, mode)
-    cache = (_shared_cache.setdefault(spec.cache_token, {})
-             if spec.cache_token is not None else spec.cache)
-    fn = cache.get(key)
-    if fn is None:
-        fn = build_stacked_callable(spec, n, nargs, static, mode, donate)
-        cache[key] = fn
-    return fn
+    process-wide when the spec has a token (a new taskpool over the
+    same kernel/shapes skips tracing, lowering AND loading), else
+    per-spec (dies with the taskpool)."""
+    return _cached(
+        spec, stacked_callable_key(n, nargs, static, shapes, donate, mode),
+        lambda: build_stacked_callable(spec, n, nargs, static, mode, donate))
 
 
 def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
@@ -285,7 +349,7 @@ def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
                            for i in range(n))
     name = program_name(spec.name, n)
     return _Program(jax.jit(_named(stacked, name),
-                            donate_argnums=donate_argnums), name)
+                            donate_argnums=donate_argnums), name, spec)
 
 
 def cached_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
@@ -298,15 +362,10 @@ def cached_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     device in the same process) compiles its own entry, and a fresh
     context rebuilding the SAME mesh over the same chips hits the
     token-cached callable."""
-    key = ("mesh", mesh, n, nargs, static, shapes, mode)
-    cache = (_shared_cache.setdefault(spec.cache_token, {})
-             if spec.cache_token is not None else spec.cache)
-    fn = cache.get(key)
-    if fn is None:
-        fn = build_sharded_callable(spec, n, nargs, static, shapes,
-                                    mode, mesh)
-        cache[key] = fn
-    return fn
+    return _cached(
+        spec, ("mesh", mesh, n, nargs, static, shapes, mode),
+        lambda: build_sharded_callable(spec, n, nargs, static, shapes,
+                                       mode, mesh))
 
 
 def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
@@ -365,28 +424,38 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
 
     fn = jax.jit(_named(program, name), in_shardings=(in_sh,) * nargs,
                  out_shardings=(in_sh,) * n_out)
-    return _Program(fn, name, n_out, in_sh)
+    return _Program(fn, name, spec, n_out, in_sh)
 
 
 class _Program:
     """A jitted dispatch plus what the device module needs to know about
     it (jit objects reject attribute assignment, hence the wrapper): its
     trace name, whether a device has called it yet (the first call
-    traces, lowers and loads: ``first_call_ns``), and for a shard_map
-    dispatch the metadata to assemble inputs / slice outputs."""
+    traces, lowers and loads: ``first_call_ns``; once per device per
+    process for a program cached by token), which spec built it, and
+    for a shard_map dispatch the metadata to assemble inputs / slice
+    outputs."""
 
-    __slots__ = ("fn", "name", "n_out", "sharding", "_called_on")
+    __slots__ = ("fn", "name", "n_out", "sharding", "_called_on",
+                 "_builder")
 
-    def __init__(self, fn: Callable, name: str, n_out: int = 0,
-                 sharding: Any = None) -> None:
+    def __init__(self, fn: Callable, name: str, spec: DeviceBatchSpec,
+                 n_out: int = 0, sharding: Any = None) -> None:
         self.fn = fn
         self.name = name
         self.n_out = n_out
         self.sharding = sharding
         self._called_on: set = set()
+        self._builder = weakref.ref(spec)   # never the spec: it holds
+        # its taskpool
 
     def __call__(self, *args):
         return self.fn(*args)
+
+    def reused_by(self, spec: DeviceBatchSpec) -> bool:
+        """True when another spec (an earlier taskpool's) built this
+        program: ``program_reuse``."""
+        return self._builder() is not spec
 
     def first_call_on(self, device: str) -> bool:
         """True the first time the device named ``device`` asks: each

@@ -162,6 +162,9 @@ class JaxDevice(Device):
                       # the part of dispatch_ns spent in each program's
                       # first call on this device (trace + lower + load)
                       "first_call_ns": 0, "first_calls": 0,
+                      # stacked or sharded dispatches whose program an
+                      # earlier taskpool built (cached by token)
+                      "program_reuse": 0,
                       # the part of stage_in_bytes pulled from a copy
                       # on another chip
                       "stage_in_peer_bytes": 0,
@@ -481,7 +484,7 @@ class JaxDevice(Device):
         shapes, dtypes, donate mask), stack each group into power-of-two
         buckets, fall back per-task for singletons / shape-divergent /
         unbatchable tasks.  Returns the number of tasks submitted."""
-        from .batching import bucket_size
+        from .batching import bucket_size, settle
         groups: Dict[Any, List[Tuple]] = {}
         order: List[Any] = []   # dispatch groups in arrival order
         n = 0
@@ -527,6 +530,12 @@ class JaxDevice(Device):
             spec, static, shapes, donate = key
             g = groups[key]
             try:
+                if len(g) >= 2 and spec.late_token is not None \
+                        and not settle(spec):
+                    # the spec's first stacked dispatch named its
+                    # programs, and an earlier taskpool's trace of them
+                    # failed: given up again, without tracing
+                    self.stats["batch_downgrades"] += 1
                 # re-check batchable each bucket: a trace failure in the
                 # first chunk must not re-trace/re-fail the rest
                 while len(g) >= 2 and spec.batchable:
@@ -587,7 +596,7 @@ class JaxDevice(Device):
         steady-state submission is a cache hit.  Any trace/dispatch
         failure (untraceable body, backend quirk) permanently downgrades
         the spec to per-task dispatch — semantics are never at risk."""
-        from .batching import cached_stacked_callable
+        from .batching import cached_stacked_callable, downgrade
         n = len(chunk)
         nargs = len(chunk[0][3])
         shapes = tuple((tuple(a.shape), str(a.dtype)) for a in chunk[0][3])
@@ -631,11 +640,7 @@ class JaxDevice(Device):
                 if clock is not None:
                     clock.pop(span)
                 self.stats["batch_downgrades"] += 1
-                spec.batchable = False
-                spec.cache.clear()
-                if spec.cache_token is not None:
-                    from .batching import _shared_cache
-                    _shared_cache.pop(spec.cache_token, None)
+                downgrade(spec)
                 plog.warning("batched dispatch of %s disabled (%s: %s); "
                              "falling back to per-task", spec.name,
                              type(exc).__name__, exc)
@@ -650,6 +655,8 @@ class JaxDevice(Device):
         if first:
             self.stats["first_call_ns"] += dt
             self.stats["first_calls"] += 1
+        if fn.reused_by(spec):
+            self.stats["program_reuse"] += 1
         self.stats["batches"] += 1
         self.stats["batched_tasks"] += n
         self._note_profile(es, chunk[0][0].task_class.name, dt / 1e3 / n, n)
@@ -1345,6 +1352,8 @@ class JaxMeshDevice(JaxDevice):
         if first:
             self.stats["first_call_ns"] += dt
             self.stats["first_calls"] += 1
+        if fn.reused_by(spec):
+            self.stats["program_reuse"] += 1
         self.stats["batches"] += 1
         self.stats["batched_tasks"] += n
         self.stats["mesh_dispatches"] += 1

@@ -666,8 +666,20 @@ class PTGTaskClass(TaskClass):
             exec(code, env)
             return tuple(env[nm] for i, nm in written if i in out_present)
 
-        return DeviceBatchSpec(f"{self.name}[{body.device_type}]",
-                               extract, call)
+        spec_name = f"{self.name}[{body.device_type}]"
+
+        def late_token():
+            # asked at the first stacked dispatch, when the taskpool's
+            # globals are final (ops.*_taskpool sets ``ops`` after
+            # ``new()``): what the body reads, by value, and a ``call``
+            # built from that alone -- or None, and ``call`` above
+            # keeps the programs with this taskpool
+            from .body_token import body_token
+            return body_token(spec_name, body.code, code, nonctl, written,
+                              refd, self.tp.rank, self.tp.global_env)
+
+        return DeviceBatchSpec(spec_name, extract, call,
+                               late_token=late_token)
 
 
 def _detached_clone(copy: DataCopy) -> DataCopy:

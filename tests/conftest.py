@@ -78,3 +78,13 @@ def ctx4():
     c = parsec_tpu.init(nb_cores=4)
     yield c
     c.fini()
+
+
+@pytest.fixture
+def no_programs(monkeypatch):
+    """A process that has built no stacked program yet: the device
+    module's process-wide program cache (devices/batching.py), emptied
+    for one test."""
+    from parsec_tpu.devices import batching
+    monkeypatch.setattr(batching, "_shared_cache", {})
+    monkeypatch.setattr(batching, "_untraceable", set())

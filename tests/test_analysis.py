@@ -360,6 +360,50 @@ def test_golden_missing_write():
     assert "BDY205" in codes(fs), fs
 
 
+GOLDEN_UNSHARED = """
+%(prologue)s
+c [ type="collection" ]
+NB [ type="int" ]
+A(k)
+k = 0 .. NB
+: c( k )
+RW X <- c( k )
+     -> c( k )
+BODY [type=tpu]
+%(body)s
+END
+"""
+
+
+@pytest.mark.parametrize("body,prologue,why", [
+    ("X = helper(X)", 'extern "C" %{\ndef helper(x):\n    return x\n%}',
+     "'helper', which the prologue defines"),
+    ("X = X * SCALE", 'extern "C" %{\nSCALE = [2.0]\n%}',
+     "'SCALE', which the prologue defines"),
+    ("X = sqrt(X)", 'extern "C" %{\nfrom jax.numpy import sqrt\n%}',
+     "'sqrt', which the prologue defines"),
+    ("X = X + c.mb", "", "the collection 'c'"),
+    ("X = eval('X')", "", "eval/exec/globals"),
+    ("import jax\nX = jax.numpy.abs(X)", "", "an import of its own"),
+    # what a token can name: scalars, modules by attribute, task locals
+    ("X = jnp.abs(X) * NB + k", "", None),
+    ("X = m.sqrt(2.0) * X", 'extern "C" %{\nimport math as m\n%}', None),
+    ("helper = X\nX = helper + 1", "", None),
+])
+def test_golden_unshared_programs(body, prologue, why):
+    jdf = ptg.compile_jdf(GOLDEN_UNSHARED % {"body": body,
+                                             "prologue": prologue},
+                          name="golden").jdf
+    found = [f for f in body_check.check_jdf_bodies(jdf)
+             if f.code == "BDY206"]
+    if why is None:
+        assert not found, found
+    else:
+        f, = found
+        assert why in f.message and "every taskpool" in f.message
+        assert f.severity == "warn"
+
+
 def test_check_function_dtd():
     def bad_kernel(a, b):
         import time

@@ -53,6 +53,16 @@ def one_device_ctx():
 
 
 @pytest.fixture
+def one_worker_ctx():
+    """One worker: a class's ready tasks reach the manager together, so
+    two runs of one DAG dispatch the same buckets."""
+    with params.cmdline_override("device_tpu_max", "1"):
+        c = parsec_tpu.init(nb_cores=1)
+    yield c
+    c.fini()
+
+
+@pytest.fixture
 def session(tmp_path):
     """A recording JAX profiler session; yields the directory."""
     import jax
@@ -256,25 +266,29 @@ def test_sharded_program_carries_class_name():
     np.testing.assert_array_equal(np.asarray(out), 2 * np.ones((4, 4, 4)))
 
 
-def _program_names(tp):
-    names = set()
+def _programs(tp):
+    """name -> program, of every stacked program ``tp`` dispatched: a
+    body that can say what it reads keeps them under its token."""
+    progs = {}
     for tc in tp.task_classes:
         for chore in tc.incarnations:
             spec = getattr(chore, "batch_spec", None)
-            if spec is not None:
-                names |= {prog.name for prog in spec.cache.values()}
-    return names
+            if spec is not None and spec.cache_token is not None:
+                assert not spec.cache
+                progs.update((prog.name, prog) for prog in
+                             batching._shared_cache[spec.cache_token].values())
+    return progs
 
 
-def test_names_equal_across_fresh_taskpools(one_device_ctx):
+def test_names_equal_across_fresh_taskpools(no_programs, one_worker_ctx):
     from parsec_tpu.ops import linalg
     seen = []
     for _ in range(2):
         tp = ops.dpotrf_taskpool(_matrix())
-        one_device_ctx.add_taskpool(tp)
-        one_device_ctx.wait()
-        seen.append(_program_names(tp))
-    assert seen[0] == seen[1] and seen[0]
+        one_worker_ctx.add_taskpool(tp)
+        one_worker_ctx.wait()
+        seen.append(_programs(tp))
+    assert seen[0] == seen[1] and seen[0]   # the very same programs
     assert all("_x" in n for n in seen[0])
     assert {n.split("_x")[0] for n in seen[0]} <= {"TRSM", "SYRK", "GEMM"}
     # a task dispatched alone ran its kernel under the class's name:
@@ -313,22 +327,29 @@ def test_kernels_named_for_a_class():
 # ---------------------------------------------------------------- #
 # (e) the always-on counters                                       #
 # ---------------------------------------------------------------- #
-def test_first_calls_move_on_a_fresh_taskpool(one_device_ctx):
-    dev, = _accel(one_device_ctx)
+def test_first_calls_move_once_per_process(no_programs, one_worker_ctx):
+    """(Was test_first_calls_move_on_a_fresh_taskpool: every taskpool
+    rebuilt its programs.)"""
+    dev, = _accel(one_worker_ctx)
     before = dict(dev.stats)
-    ops.dpotrf(one_device_ctx, _matrix())
+    ops.dpotrf(one_worker_ctx, _matrix())
     mid = dict(dev.stats)
-    ops.dpotrf(one_device_ctx, _matrix())
+    ops.dpotrf(one_worker_ctx, _matrix())
     after = dict(dev.stats)
-    for a, b in ((before, mid), (mid, after)):
-        calls = b["first_calls"] - a["first_calls"]
-        assert calls >= 1
-        assert b["first_call_ns"] > a["first_call_ns"]
-        # a part of dispatch_ns, never more
-        assert b["first_call_ns"] - a["first_call_ns"] \
-            <= b["dispatch_ns"] - a["dispatch_ns"]
-        # one program per (class, bucket): far fewer than tasks
-        assert calls < b["tasks"] - a["tasks"]
+    calls = mid["first_calls"] - before["first_calls"]
+    assert calls >= 1
+    assert mid["first_call_ns"] > before["first_call_ns"]
+    # a part of dispatch_ns, never more
+    assert mid["first_call_ns"] - before["first_call_ns"] \
+        <= mid["dispatch_ns"] - before["dispatch_ns"]
+    # one program per (class, bucket): far fewer than tasks
+    assert calls < mid["tasks"] - before["tasks"]
+    assert mid["program_reuse"] == before["program_reuse"]
+    # the second taskpool's stacked dispatches all found their program
+    assert after["first_calls"] == mid["first_calls"]
+    assert after["first_call_ns"] == mid["first_call_ns"]
+    assert after["program_reuse"] - mid["program_reuse"] \
+        == after["batches"] - mid["batches"] >= 1
     assert after["stage_in_bytes"] > 0
     assert after["stage_in_peer_bytes"] == 0
     assert phases.completed() == []     # counters need no session
