@@ -372,15 +372,16 @@ def leg_dtd(cfg):
             x, a = unpack_args(task)
             x *= a
 
-        boot = tp.tile_of_array(np.ones((nb, nb), np.float32))
-        tp.insert_task(scale, (boot, INOUT), (1.0, VALUE))
-        tp.add_chore(scale, "tpu", lambda x, a: x * a)
+        # the class and its chore ahead of the inserts: no task can
+        # reach a worker without its accelerator incarnation
+        tc = tp.create_task_class("scale", 1, scale)
+        tp.add_chore(tc, "tpu", lambda x, a: x * a)
         tiles = [tp.tile_of_array(np.full((nb, nb), i + 1, np.float32))
                  for i in range(burst)]
         t0 = time.perf_counter()
         for a in (2.0, 10.0):
             for t in tiles:
-                tp.insert_task(scale, (t, INOUT), (a, VALUE))
+                tp.insert_task_with_task_class(tc, (t, INOUT), (a, VALUE))
         tp.data_flush_all()
         tp.wait()
         wall = time.perf_counter() - t0
@@ -388,11 +389,9 @@ def leg_dtd(cfg):
             f"tasks={stats_sum(devs, 'tasks')} "
             f"batches={stats_sum(devs, 'batches')} "
             f"batched_tasks={stats_sum(devs, 'batched_tasks')}")
-        # the boot insert lands on the host or, when add_chore wins
-        # the race with its scheduling, on the accelerator
-        require(stats_sum(devs, "tasks") in (2 * burst, 2 * burst + 1),
+        require(stats_sum(devs, "tasks") == 2 * burst,
                 f"dtd: {stats_sum(devs, 'tasks')} tasks on accelerator "
-                f"devices, expected {2 * burst} (+1 boot)")
+                f"devices, expected {2 * burst}")
         require(stats_sum(devs, "batches") > 0,
                 "dtd: batched dispatch never engaged")
         require_no_downgrade(devs, "dtd")

@@ -61,6 +61,7 @@ from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 __all__ = ["DeviceBatchSpec", "bucket_size", "segment_plan",
            "stacked_callable_key", "program_name", "KernelsNamedFor",
+           "kernel_named_for",
            "settle", "downgrade",
            "build_stacked_callable", "cached_stacked_callable",
            "build_sharded_callable", "cached_sharded_callable",
@@ -177,12 +178,39 @@ def _named(fn: Callable, name: str) -> Callable:
 _class_kernels: Dict[Tuple[str, Any], Any] = {}
 
 
+def kernel_named_for(name: str, obj: Any) -> Any:
+    """``obj`` if it is not a plainly jitted kernel (``ops.potrf``, ...),
+    else its clone that runs as ``jit_<name>``.  The clones are built
+    once per process per (name, kernel), as the kernels themselves
+    are."""
+    info = getattr(obj, "_jit_info", None)     # jax 0.9.0 PjitFunction
+    if info is None or not hasattr(obj, "__wrapped__"):
+        return obj
+    clone = _class_kernels.get((name, obj))
+    if clone is None:
+        import functools
+
+        import jax
+        fun = obj.__wrapped__
+
+        @functools.wraps(fun)
+        def kernel(*args, **kwargs):
+            return fun(*args, **kwargs)
+
+        clone = _class_kernels[(name, obj)] = jax.jit(
+            _named(kernel, name),
+            static_argnums=info.static_argnums,
+            static_argnames=info.static_argnames,
+            donate_argnums=info.donate_argnums,
+            donate_argnames=info.donate_argnames)
+    return clone
+
+
 class KernelsNamedFor:
     """A module as a per-task device body sees it: every plainly jitted
     kernel it holds (``ops.potrf``, ...) comes back as a clone named for
-    the body's task class, so a task dispatched alone runs
-    ``jit_<CLASS>`` and not ``jit_potrf``.  The clones are built once
-    per process per (class, kernel), as the kernels themselves are;
+    the body's task class (:func:`kernel_named_for`), so a task
+    dispatched alone runs ``jit_<CLASS>`` and not ``jit_potrf``;
     anything else the module holds passes through."""
 
     __slots__ = ("_module", "_name")
@@ -192,28 +220,7 @@ class KernelsNamedFor:
         self._name = program_name(cls, 1)
 
     def __getattr__(self, attr: str) -> Any:
-        obj = getattr(self._module, attr)
-        info = getattr(obj, "_jit_info", None)     # jax 0.9.0 PjitFunction
-        if info is None or not hasattr(obj, "__wrapped__"):
-            return obj
-        clone = _class_kernels.get((self._name, obj))
-        if clone is None:
-            import functools
-
-            import jax
-            fun = obj.__wrapped__
-
-            @functools.wraps(fun)
-            def kernel(*args, **kwargs):
-                return fun(*args, **kwargs)
-
-            clone = _class_kernels[(self._name, obj)] = jax.jit(
-                _named(kernel, self._name),
-                static_argnums=info.static_argnums,
-                static_argnames=info.static_argnames,
-                donate_argnums=info.donate_argnums,
-                donate_argnames=info.donate_argnames)
-        return clone
+        return kernel_named_for(self._name, getattr(self._module, attr))
 
 
 def stacked_callable_key(n: int, nargs: int, static: Any,

@@ -3,15 +3,19 @@
 Reference behavior: a sequential task-insertion API that discovers the DAG at
 runtime from data access modes (IN/OUT/INOUT + AFFINITY/DONT_TRACK), with
 per-tile last-user tracking (WAR/WAW chaining, read-after-read fan-out),
-sliding-window backpressure (window 8000 / threshold 4000), per-taskpool
-registries of task classes and tiles, NEW-tile support, accelerator chores
-via ``add_chore``, and explicit data flush back home
+sliding-window backpressure (window 8000 / threshold 4000: over the
+window the inserting thread is held, working, until the pool is back
+under the threshold), per-taskpool registries of task classes and tiles,
+NEW-tile support, accelerator chores via ``add_chore`` (on a class
+created ahead of its tasks, or on a body already inserted), and explicit
+data flush back home
 (ref: parsec/interfaces/dtd/insert_function.c, insert_function.h:284-425,
 overlap_strategies.c:1-356, parsec_dtd_data_flush.c:1-397; call stack
 SURVEY.md §3.5).
 
 Public surface mirrors the reference:
-``DTDTaskpool.insert_task(fn, args...)``, ``tile_of(collection, key)``,
+``DTDTaskpool.insert_task(fn, args...)``, ``create_task_class`` +
+``insert_task_with_task_class``, ``tile_of(collection, key)``,
 ``tile_new(...)``, ``data_flush/data_flush_all``, ``add_chore``, ``wait``.
 """
 from __future__ import annotations
@@ -24,10 +28,12 @@ import numpy as np
 
 from ...core.hashtable import HashTable
 from ...profiling.grapher import grapher
+from ...profiling.pins import PINS, PinsEvent
 from ...data.data import (Coherency, Data, DataCopy, FlowAccess,
                           data_new_with_payload)
 from ...data.datatype import dtt_of_array
-from ...runtime.scheduling import schedule, schedule_keep_best, task_progress
+from ...runtime.scheduling import (_Backoff, schedule, schedule_keep_best,
+                                   task_progress)
 from ...runtime.taskpool import (Chore, Flow, HookReturn, Task, TaskClass,
                                  Taskpool)
 from ...runtime.termdet import termdet_new
@@ -190,6 +196,21 @@ def _dtd_prepare_input(es, task: Task) -> HookReturn:
     return HookReturn.DONE
 
 
+def _dtd_flush_prepare_input(es, task: Task) -> HookReturn:
+    """prepare_input of the flush class: a flush task takes its tile
+    INOUT on the host, so THIS is where the tile's newest copy is
+    waited for on the chip and pulled home; on a phase clock that is
+    the ``dtd_flush`` span."""
+    clock = es.context._phase_clock
+    if clock is None:
+        return _dtd_prepare_input(es, task)
+    clock.push("dtd_flush")
+    try:
+        return _dtd_prepare_input(es, task)
+    finally:
+        clock.pop("dtd_flush")
+
+
 def _dtd_release_deps(es, task: Task, action_mask: int) -> List[Task]:
     """ref: dtd_release_dep_fct (insert_function.c:1603) — mark written
     copies, wake satisfied successors."""
@@ -211,7 +232,7 @@ def _dtd_release_deps(es, task: Task, action_mask: int) -> List[Task]:
         if s.dep_satisfied():
             ready.append(s.task)
     tp: DTDTaskpool = task.taskpool
-    tp._on_task_done()
+    tp._on_task_done(len(ready))
     return ready
 
 
@@ -227,8 +248,16 @@ class DTDTaskpool(Taskpool):
         self._task_classes: Dict[Any, DTDTaskClass] = {}
         self._tiles = HashTable()
         self._coll_names: Dict[str, int] = {}
+        # the window's books, under _out_lock: tasks inserted and not
+        # completed; those of them whose dependencies are met (queued,
+        # running or on a device: what can still complete with no
+        # further insert); whether the inserting thread sleeps on
+        # _out_cond for the pool to come down to the threshold
         self._outstanding = 0
+        self._in_flight = 0
+        self._held = False
         self._out_lock = threading.Lock()
+        self._out_cond = threading.Condition(self._out_lock)
         self._inserted = 0
         # keep-alive action until wait() (so an empty pool doesn't terminate)
         self.tdm = termdet_new(params.get("termdet") if params.get("termdet") != "fourcounter" else "local", self)
@@ -304,41 +333,75 @@ class DTDTaskpool(Taskpool):
     # ------------------------------------------------------------------ #
     # task classes + chores                                              #
     # ------------------------------------------------------------------ #
-    def _task_class_of(self, body: Callable, nb_flows: int,
-                       name: Optional[str]) -> DTDTaskClass:
-        key = body
-        tc = self._task_classes.get(key)
-        if tc is None:
-            assert len(self._task_classes) < self.MAX_TASK_CLASSES, \
-                "too many DTD task classes (ref limit 25)"
-            flows = [Flow(f"flow{i}", FlowAccess.NONE, i) for i in range(nb_flows)]
-            tc = DTDTaskClass(name or getattr(body, "__name__", "dtd_task"),
-                              len(self._task_classes), nb_flows, body, flows)
-            self._task_classes[key] = tc
-            self.task_classes.append(tc)
-        assert tc.nb_flows == nb_flows, \
-            f"task class {tc.name} re-inserted with different flow count"
+    def _new_task_class(self, key: Any, name: str, nb_flows: int,
+                        body: Callable) -> DTDTaskClass:
+        """A class of ``nb_flows`` tracked arguments, found again under
+        ``key``: the body of a body-first class, or None for the class
+        itself."""
+        assert len(self._task_classes) < self.MAX_TASK_CLASSES, \
+            "too many DTD task classes (ref limit 25)"
+        flows = [Flow(f"flow{i}", FlowAccess.NONE, i) for i in range(nb_flows)]
+        tc = DTDTaskClass(name, len(self._task_classes), nb_flows, body, flows)
+        self._task_classes[tc if key is None else key] = tc
+        self.task_classes.append(tc)
         return tc
 
-    def add_chore(self, body: Callable, device_type: str, fn: Any) -> None:
-        """ref: parsec_dtd_task_class_add_chore (insert_function.c:2432).
-        ``fn`` for device_type "tpu" is a jax callable taking one argument
-        per inserted parameter in insertion order — device arrays for tiles,
-        raw Python values for VALUE params (same order as unpack_args); it
-        returns arrays for the written flows, in order."""
+    def create_task_class(self, name: str, nb_flows: int,
+                          body: Callable) -> DTDTaskClass:
+        """ref: parsec_dtd_create_task_class — a class ahead of its
+        tasks: ``name``, the count of tracked (tile) arguments its tasks
+        take, and the host body.  ``add_chore(tc, ...)`` then gives it
+        its accelerator incarnations BEFORE ``insert_task_with_task_class``
+        inserts the first task, so no task of the class can reach a
+        worker without them (a body-first class gets its chore only
+        after its first insert, which races it).  Two classes may share
+        a body."""
+        return self._new_task_class(None, name, nb_flows, body)
+
+    def _task_class_of(self, body: Any, nb_flows: int,
+                       name: Optional[str]) -> DTDTaskClass:
+        """The class of an insert: ``body`` is a class of this taskpool
+        (class-first), or a host body whose class its first insert
+        makes."""
         tc = self._task_classes.get(body)
-        assert tc is not None, "add_chore before first insert_task of this body"
+        if tc is None:
+            assert not isinstance(body, DTDTaskClass), \
+                f"task class {body.name} belongs to another taskpool"
+            tc = self._new_task_class(
+                body, name or getattr(body, "__name__", "dtd_task"),
+                nb_flows, body)
+        assert tc.nb_flows == nb_flows, \
+            f"task class {tc.name} inserted with {nb_flows} tracked " \
+            f"arguments, it has {tc.nb_flows} flows"
+        return tc
+
+    def add_chore(self, body: Any, device_type: str, fn: Any) -> None:
+        """ref: parsec_dtd_task_class_add_chore (insert_function.c:2432).
+        ``body`` is a class from ``create_task_class``, or a host body
+        already inserted once.  ``fn`` for device_type "tpu" is a jax
+        callable taking one argument per inserted parameter in insertion
+        order — device arrays for tiles, raw Python values for VALUE
+        params (same order as unpack_args); it returns arrays for the
+        written flows, in order."""
+        tc = self._task_classes.get(body)
+        assert tc is not None, \
+            "add_chore before create_task_class or the first insert_task " \
+            "of this body"
+        from ...devices.batching import (DeviceBatchSpec, kernel_named_for,
+                                         program_name)
+        # a task dispatched alone runs under its class's name too
+        # (jit_<CLASS>, as its stacked programs are jit_<CLASS>_x<n>)
+        alone = kernel_named_for(program_name(tc.name, 1), fn)
 
         def wrapped(task: Task, arrays: List[Any]) -> Any:
             args = [arrays[p.flow_index] if p.tile is not None else p.value
                     for p in task.user
                     if p.tile is not None or (p.mode & VALUE)]
-            return fn(*args)
+            return alone(*args)
 
         # batched-dispatch recipe (devices/batching.py): tile args are
         # the batch axis, VALUE params are static (part of the group
         # key, so only tasks passing EQUAL values stack together)
-        from ...devices.batching import DeviceBatchSpec
 
         def extract(task: Task, arrays: List[Any]):
             bargs: List[Any] = []
@@ -404,7 +467,14 @@ class DTDTaskpool(Taskpool):
             return tracked[0].tile.rank
         return 0
 
-    def insert_task(self, body: Callable, *args, name: Optional[str] = None,
+    def insert_task_with_task_class(self, tc: DTDTaskClass, *args,
+                                    priority: int = 0) -> Optional[Task]:
+        """ref: parsec_dtd_insert_task_with_task_class — insert a task of
+        a class made by ``create_task_class``; ``args`` as for
+        ``insert_task``."""
+        return self.insert_task(tc, *args, priority=priority)
+
+    def insert_task(self, body: Any, *args, name: Optional[str] = None,
                     priority: int = 0, _internal: bool = False) -> Optional[Task]:
         """ref: parsec_dtd_insert_task (insert_function.h:284, impl :3506).
 
@@ -422,6 +492,19 @@ class DTDTaskpool(Taskpool):
             return None
         if not _internal:
             self._backpressure()
+        clock = self.context._phase_clock
+        if clock is None:
+            return self._insert(body, args, name, priority)
+        # argument parsing, class lookup, last-user chaining; a
+        # ``schedule`` inside books under its own name
+        clock.push("dtd_insert")
+        try:
+            return self._insert(body, args, name, priority)
+        finally:
+            clock.pop("dtd_insert")
+
+    def _insert(self, body: Any, args: Sequence[Any], name: Optional[str],
+                priority: int) -> Optional[Task]:
         # parse the vararg list (ref: __parsec_dtd_taskpool_create_task :3219)
         parsed: List[_Param] = []
         flow_count = 0
@@ -610,28 +693,85 @@ class DTDTaskpool(Taskpool):
         ctx = self.context
         assert ctx is not None, "insert_task before context.add_taskpool"
         es = ctx.execution_streams[0]
+        with self._out_lock:
+            self._in_flight += 1
         schedule(es, [task])
 
-    def _on_task_done(self) -> None:
+    @staticmethod
+    def _next_task(es) -> Optional[Task]:
+        """The next task for a thread that helps on stream ``es`` (the
+        inserter held by the window, the caller in ``wait``): the bypass
+        slot's, else the scheduler's, selected between the worker loop's
+        PINS pair (``select`` / ``idle_poll`` on a phase clock)."""
+        task = es.next_task
+        es.next_task = None
+        if task is None:
+            PINS(es, PinsEvent.SELECT_BEGIN, None)
+            task = es.context.scheduler.select(es)
+            PINS(es, PinsEvent.SELECT_END, task)
+        return task
+
+    def _on_task_done(self, n_ready: int = 0) -> None:
+        """A task completed and made ``n_ready`` successors ready."""
         with self._out_lock:
             self._outstanding -= 1
+            self._in_flight += n_ready - 1
+            if self._held and self._outstanding <= self.threshold_size:
+                self._out_cond.notify_all()
 
     def _backpressure(self) -> None:
         """ref: parsec_dtd_block_if_threshold_reached (insert_function.c:3215)
-        — over the window, the inserting thread helps execute."""
+        — over the window, the inserting thread does not go back to its
+        caller until the pool is at or under the threshold: it runs what
+        is ready, drives the engines, and otherwise sleeps until
+        completions bring the pool down (a sleep, not a spin: a spin
+        would keep the interpreter lock from the threads doing the
+        work).  It goes back at once when a task error is pending, and
+        when nothing inserted so far can complete without an insert
+        (or a message) still to come."""
         if self._outstanding <= self.window_size:
             return
         ctx = self.context
         es = ctx.execution_streams[0]
-        while self._outstanding > self.threshold_size:
-            task = es.next_task
-            es.next_task = None
-            if task is None:
-                task = ctx.scheduler.select(es)
-            if task is not None:
-                task_progress(es, task)
-            elif ctx.progress_engines(es) == 0:
-                break  # nothing runnable; don't deadlock the inserter
+        clock = ctx._phase_clock
+        if clock is not None:
+            clock.push("dtd_window")
+        ran = misses = 0
+        task = None
+        try:
+            while self._outstanding > self.threshold_size \
+                    and not ctx._task_errors:
+                task = self._next_task(es)
+                if task is not None:
+                    task_progress(es, task)
+                    ran += 1
+                    misses = 0
+                elif ctx.progress_engines(es):
+                    misses = 0
+                elif self._sleep_until_threshold(
+                        min(1e-5 * (1 << min(misses, 8)),
+                            _Backoff.MAX_SLEEP)):   # the idle backoff's
+                    misses += 1
+                else:
+                    break   # nothing in flight: don't deadlock the inserter
+        except Exception as exc:    # a body blew up on this thread:
+            ctx.record_task_error(exc, task)    # wait() raises it
+        finally:
+            if clock is not None:
+                clock.pop("dtd_window", tasks=ran)
+
+    def _sleep_until_threshold(self, timeout: float) -> bool:
+        """Sleep until completions bring the pool to the threshold, or
+        ``timeout`` (the engines and the error list want a look).  False,
+        without sleeping, when no inserted task is in flight."""
+        with self._out_lock:
+            if self._in_flight <= 0:
+                return False
+            if self._outstanding > self.threshold_size:
+                self._held = True
+                self._out_cond.wait(timeout)
+                self._held = False
+        return True
 
     # ------------------------------------------------------------------ #
     # flush + wait                                                       #
@@ -643,9 +783,12 @@ class DTDTaskpool(Taskpool):
         25-class limit). The dedup marker is set at INSERTION time so every
         SPMD rank makes the same decision (an execution-time flag would only
         flip on the home rank and diverge the insertion streams)."""
+        if _dtd_flush_body not in self._task_classes:
+            self._new_task_class(
+                _dtd_flush_body, "dtd_flush", 1, _dtd_flush_body
+            ).prepare_input = _dtd_flush_prepare_input
         self.insert_task(_dtd_flush_body, (tile, INOUT | AFFINITY),
-                         (tile, VALUE | REF), name="dtd_flush",
-                         _internal=True)
+                         (tile, VALUE | REF), _internal=True)
         tile.flushed_at_seq = tile.writers_seq
 
     def data_flush_all(self) -> None:
@@ -675,13 +818,9 @@ class DTDTaskpool(Taskpool):
         ctx = self.context
         ctx.start()
         es = ctx.execution_streams[0]
-        from ...runtime.scheduling import _Backoff
         backoff = _Backoff()
         while not self.completed and not ctx._task_errors:
-            task = es.next_task
-            es.next_task = None
-            if task is None:
-                task = ctx.scheduler.select(es)
+            task = self._next_task(es)
             try:
                 if task is not None:
                     task_progress(es, task)
@@ -696,7 +835,8 @@ class DTDTaskpool(Taskpool):
 
 
 def _dtd_flush_body(es, task: Task) -> None:
-    """Shared flush task body: pull the newest copy back to the host."""
+    """Shared flush task body: the newest copy is back on the host (the
+    task's prepare_input pulled it, ``_dtd_flush_prepare_input``)."""
     tile: DTDTile = next(p.value for p in task.user if p.tile is None)
     tile.data.sync_to_host(es.context.devices)
     tile.flushed = True

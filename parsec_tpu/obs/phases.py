@@ -4,7 +4,8 @@ One :class:`PhaseClock` per root span (one ``ops.dpotrf`` /
 ``ops.dgeqrf`` / ... call).  Every instrumentation site that has a
 begin and an end — the PINS pairs of ``runtime/scheduling.py`` through
 ``TaskProfilerModule``, the device module's manager / stage-in /
-dispatch / epilog sites, ``Context.park`` and ``progress_engines`` —
+dispatch / epilog sites, ``Context.park`` and ``progress_engines``, the
+DTD front end's insert / window / flush sites —
 pushes and pops a per-thread stack here, and the clock books each
 span's SELF time (duration less what its child spans cover) under the
 span's phase name.  What a thread spent outside every span between the
@@ -37,7 +38,8 @@ __all__ = ["PHASES", "PhaseClock", "root_span", "session_recording",
 #: every phase a site books under, in the order the report prints them
 PHASES = ("select", "idle_poll", "parked", "prepare_input", "exec",
           "schedule", "complete", "release_deps", "manager", "stage_in",
-          "dispatch", "first_call", "epilog", "other")
+          "dispatch", "first_call", "epilog", "dtd_insert", "dtd_window",
+          "dtd_flush", "other")
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident

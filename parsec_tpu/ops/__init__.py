@@ -7,6 +7,7 @@ from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt,
                      tsqrt, tsqrt_r, unmqr)
 from . import dpotrf as dpotrf_module
 from .dpotrf import dpotrf, dpotrf_factory, dpotrf_taskpool, make_spd
+from .dpotrf_dtd import dpotrf_dtd
 from .dgeqrf import dgeqrf, dgeqrf_factory, dgeqrf_taskpool
 from .inverse import dgesv, dgetrs, dlauum, dpotri, dtrtri
 from .dgetrf import (dgetrf, dgetrf_factory, dgetrf_nopiv, dgetrf_nopiv_taskpool,
@@ -22,6 +23,7 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "geqrt", "geqrt_r", "unmqr", "tsqrt", "tsqrt_r", "tsmqr",
            "getrf_nopiv", "trsm_lower_unit", "trsm_upper_right",
            "dpotrf", "dpotrf_factory", "dpotrf_taskpool", "make_spd",
+           "dpotrf_dtd",
            "dgeqrf", "dgeqrf_factory", "dgeqrf_taskpool",
            "dgetrf", "dgetrf_nopiv", "dgetrf_nopiv_taskpool", "dgetrf_factory",
            "dtrtri", "dlauum", "dpotri", "dgetrs", "dgesv",

@@ -1,7 +1,8 @@
-"""The blocking half of every ``ops`` entry point: enqueue + wait."""
+"""The blocking half of every ``ops`` entry point: enqueue + wait, or
+for a DTD entry point enqueue + start + insert + flush + wait."""
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 from ..obs.phases import root_span
 
@@ -17,3 +18,20 @@ def run_blocking(context: Any, op: str, taskpools: Sequence[Any]) -> None:
         for tp in taskpools:
             context.add_taskpool(tp)
             context.wait()
+
+
+def run_inserting(context: Any, op: str, tp: Any,
+                  insert: Callable[[Any], None]) -> None:
+    """Run the DTD taskpool ``tp`` on ``context`` under one root span
+    named ``op``, in the order of DPLASMA's ``testing_*_dtd`` drivers:
+    the taskpool added and the context started BEFORE the first insert
+    (so tasks run while later ones are inserted), ``insert(tp)`` making
+    every ``insert_task`` call, then the flush of every tile back home,
+    the taskpool's wait and the context's."""
+    with root_span(context, op, tp.taskpool_id):
+        context.add_taskpool(tp)
+        context.start()
+        insert(tp)
+        tp.data_flush_all()
+        tp.wait()
+        context.wait()
