@@ -31,7 +31,11 @@ Two stacking modes (``device_batch_mode``):
   results are only approximately equal to per-task execution.
 
 Batch sizes are bucketed to powers of two so the set of programs stays
-small.  A program is keyed by (bucket, static, shapes/dtypes, donate
+small: 2, 4, 8, ... up to ``device_batch_max`` (16).  On a single rank
+a bucket is ONE call; only a context of more than one rank carves it
+into ``device_flush_segments`` sub-calls (:func:`segment_plan`), whose
+early outputs let dependency sends start under the later segments.  A
+program is keyed by (bucket, static, shapes/dtypes, donate
 mask, mode) and built ONCE PER PROCESS for every spec that can say what
 its ``call`` traces to (``cache_token``): a DTD kernel's token is the
 user function, a PTG body's is made from what the body reads
@@ -137,8 +141,10 @@ def bucket_size(navail: int, batch_max: int) -> int:
 
 
 def segment_plan(n: int, requested: int) -> int:
-    """Segments a flush group of ``n`` tasks splits into (ISSUE 7
-    segmented flush): the largest power of two <= min(requested, n // 2),
+    """Segments a flush group of ``n`` tasks splits into where the
+    device module segments at all, that is in a context of more than
+    one rank (ISSUE 7 segmented flush; on one rank ``_dispatch_batch``
+    never asks): the largest power of two <= min(requested, n // 2),
     so every segment keeps >= 2 tasks (amortization survives) and the
     per-segment sizes are themselves powers of two sharing the stacked-
     callable cache with ordinary buckets.  1 = whole-batch flush.
@@ -149,7 +155,9 @@ def segment_plan(n: int, requested: int) -> int:
     *when* each task's outputs materialize.  A segment's outputs become
     ready as soon as ITS sub-call finishes, so dependency sends (the
     D2H + wire time the T3 overlap story hides) start while the later
-    segments are still executing instead of at the batch boundary."""
+    segments are still executing instead of at the batch boundary.
+    The price is the host's fixed cost of each extra call, which only
+    a send to overlap can pay for."""
     if requested <= 1 or n < 4:
         return 1
     s, limit = 1, min(requested, n // 2)

@@ -174,9 +174,10 @@ class JaxDevice(Device):
                       # prove it stayed on the fast path asserts zero):
                       # stacked -> per-task, donated -> undonated retry
                       "batch_downgrades": 0, "donate_retries": 0,
-                      # segmented flush (ISSUE 7): flush groups that were
-                      # carved into pipelined sub-calls, and the total
-                      # sub-calls dispatched for them
+                      # segmented flush (ISSUE 7; across ranks only):
+                      # flush groups that were carved into pipelined
+                      # sub-calls, and the total sub-calls dispatched
+                      # for them
                       "segmented_flushes": 0, "flush_segments": 0}
         # eager completion (async dispatch IS completion; XLA orders the
         # dataflow) with a bounded in-flight window
@@ -192,8 +193,9 @@ class JaxDevice(Device):
         self.batch_mode = str(params.get("device_batch_mode"))
         self.prefetch_depth = int(params.get("device_prefetch_depth"))
         self.donate = bool(params.get("device_donate"))
-        # segmented flush (ISSUE 7): carve a flush group into pipelined
-        # jitted sub-calls so early segments' outputs retire (and their
+        # segmented flush (ISSUE 7), read only when the context spans
+        # more than one rank: carve a flush group into pipelined jitted
+        # sub-calls so early segments' outputs retire (and their
         # dependency sends start) while later segments still execute
         self.flush_segments = int(params.get("device_flush_segments"))
         # copies staged early by the prefetcher: id(copy) -> version;
@@ -560,19 +562,26 @@ class JaxDevice(Device):
 
     def _dispatch_batch(self, es, spec, static, donate,
                         chunk: List[Tuple]) -> None:
-        """Dispatch one flush group — as ONE stacked call, or (segmented
-        flush, ISSUE 7) as ``device_flush_segments`` pipelined stacked
-        sub-calls.  Sub-calls queue back to back on the async dispatch
-        stream, but each segment's outputs materialize when ITS
-        executable finishes, so the epilog's dependency release for the
-        first segment (eager sends, mesh-local offers, D2H for the
-        wire) overlaps the later segments' execution instead of waiting
-        for the batch boundary.  In ``unroll`` mode segmentation is
+        """Dispatch one flush group: as ONE stacked call on a single
+        rank, and across ranks (segmented flush, ISSUE 7) as up to
+        ``device_flush_segments`` pipelined stacked sub-calls.
+
+        Segmentation exists to overlap dependency SENDS: sub-calls queue
+        back to back on the async dispatch stream, but each segment's
+        outputs materialize when ITS executable finishes, so the
+        epilog's release for the first segment (eager sends, D2H for
+        the wire) overlaps the later segments' execution instead of
+        waiting for the batch boundary.  A context of one rank makes no
+        send: the chip runs the sub-calls in the same order either way,
+        and every extra call costs the manager its fixed host time, so
+        the group goes out whole.  In ``unroll`` mode segmentation is
         bit-exact vs the whole-batch dispatch (identical per-example
         subgraphs, just grouped differently)."""
-        from .batching import segment_plan
         n = len(chunk)
-        segs = segment_plan(n, self.flush_segments)
+        segs = 1
+        if es.context.nb_ranks > 1:
+            from .batching import segment_plan
+            segs = segment_plan(n, self.flush_segments)
         if segs <= 1:
             return self._dispatch_stacked(es, spec, static, donate, chunk)
         self.stats["segmented_flushes"] += 1

@@ -451,11 +451,13 @@ def register_core_params() -> None:
                    "queued tasks while the current batch executes "
                    "(0 = no async prefetch)")
     params.reg_int("device_flush_segments", 4,
-                   "carve each batched flush group into up to this many "
-                   "pipelined jitted sub-calls so a segment's written "
-                   "tiles retire (and their dependency sends start) "
-                   "while the rest of the batch is still executing "
-                   "(<=1 = whole-batch flush, the pre-overlap behavior; "
+                   "across ranks only (a context of one rank makes no "
+                   "send and flushes every group as ONE stacked call, "
+                   "whatever this says): carve each batched flush group "
+                   "into up to this many pipelined jitted sub-calls so "
+                   "a segment's written tiles retire (and their "
+                   "dependency sends start) while the rest of the batch "
+                   "is still executing (<=1 = whole-batch flush; "
                    "segments never shrink below 2 tasks)")
     params.reg_bool("stage_compile", False,
                     "whole-stage DAG->XLA compilation (stagec/, ISSUE "

@@ -88,3 +88,20 @@ def no_programs(monkeypatch):
     from parsec_tpu.devices import batching
     monkeypatch.setattr(batching, "_shared_cache", {})
     monkeypatch.setattr(batching, "_untraceable", set())
+
+
+@pytest.fixture
+def call_sizes(monkeypatch):
+    """Tasks per stacked call, in dispatch order, of every device of
+    the process (``batches`` and ``batched_tasks`` give their count and
+    sum; this gives each)."""
+    from parsec_tpu.devices.tpu import JaxDevice
+    sizes = []
+    stacked = JaxDevice._dispatch_stacked
+
+    def recording(self, es, spec, static, donate, chunk):
+        sizes.append(len(chunk))
+        return stacked(self, es, spec, static, donate, chunk)
+
+    monkeypatch.setattr(JaxDevice, "_dispatch_stacked", recording)
+    return sizes

@@ -342,55 +342,76 @@ def test_per_codec_compress_ratio_gauges_in_exposition():
     assert val("parsec_comm_compress_ratio_r1_qbf16") == 1.0
 
 
-def test_overlap_gauges_in_exposition():
+@pytest.mark.parametrize("nb_ranks", [1, 2])
+def test_overlap_gauges_in_exposition(nb_ranks):
     """ISSUE 7 acceptance: the live OVERLAP_FRACTION / EXPOSED_COMM_US
     gauges and the prefetch/segment counters must surface in the
     Prometheus exposition during a dpotrf run — the overlap pipeline's
     health is measurable while it runs, not only in the offline
-    critpath report."""
+    critpath report.  The segment counters move only where a flush is
+    segmented: in a context of more than one rank."""
+    from conftest import spmd
     from parsec_tpu.collections import TwoDimBlockCyclic
     from parsec_tpu.comm import LocalFabric, RemoteDepEngine
     from parsec_tpu.ops import dpotrf_taskpool, make_spd
     from parsec_tpu.utils.params import params
 
+    M = make_spd(256)
+
+    def rank_fn(rank, fab):
+        ctx = parsec_tpu.Context(nb_cores=2,
+                                 comm=RemoteDepEngine(fab.engine(rank)))
+        try:
+            A = TwoDimBlockCyclic(256, 256, 32, 32, dtype=np.float32,
+                                  P=nb_ranks, Q=1, nodes=nb_ranks,
+                                  rank=rank)
+            A.name = "descA"
+            A.from_numpy(M.copy())
+            ctx.add_taskpool(dpotrf_taskpool(A, rank=rank,
+                                             nb_ranks=nb_ranks))
+            ctx.wait()
+            return ctx.obs.render_prometheus(labels={"rank": str(rank)})
+        finally:
+            ctx.fini()
+
     with params.cmdline_override("metrics", "1"), \
          params.cmdline_override("device_tpu_max", "1"), \
          params.cmdline_override("device_flush_segments", "4"):
-        fab = LocalFabric(1)
-        eng = RemoteDepEngine(fab.engine(0))
-        ctx = parsec_tpu.Context(nb_cores=2, comm=eng)
-        try:
-            M = make_spd(256)
-            A = TwoDimBlockCyclic(256, 256, 32, 32,
-                                  dtype=np.float32).from_numpy(M)
-            ctx.add_taskpool(dpotrf_taskpool(A))
-            ctx.wait()
-            text = ctx.obs.render_prometheus(labels={"rank": "0"})
-        finally:
-            ctx.fini()
-    samples = parse_exposition(text)
+        texts, _fab = spmd(nb_ranks, rank_fn,
+                           fabric=LocalFabric(nb_ranks))
+    # every rank's device counters: which rank's ready sets reach four
+    # same-class tasks depends on the run
+    by_rank = [parse_exposition(t) for t in texts]
+    samples = by_rank[0]
 
     def val(name):
         got = [v for (n, _l), v in samples.items() if n == name]
         assert got, (name, sorted(n for (n, _l) in samples))
         return got[0]
 
+    def device_counter(suffix):
+        got = [v for smp in by_rank for (n, _l), v in smp.items()
+               if n.startswith("parsec_device_") and n.endswith(suffix)]
+        assert len(got) >= nb_ranks, f"{suffix} is not exposed"
+        return max(got)
+
     frac = val("parsec_obs_overlap_fraction")
     assert 0.0 <= frac <= 1.0
     assert val("parsec_obs_exposed_comm_us") >= 0.0
-    # the segment counters prove the pipelined flush path really ran
-    segd = [v for (n, _l), v in samples.items()
-            if n.startswith("parsec_device_")
-            and n.endswith("segmented_flushes")]
-    segs = [v for (n, _l), v in samples.items()
-            if n.startswith("parsec_device_")
-            and n.endswith("flush_segments")]
-    assert segd and max(segd) > 0.0, "dpotrf run never segmented a flush"
-    assert segs and max(segs) >= 2 * max(segd)
+    segd = device_counter("segmented_flushes")
+    segs = device_counter("flush_segments")
+    if nb_ranks == 1:
+        # no send to overlap: every flush group went out whole
+        assert segd == 0.0 and segs == 0.0
+    else:
+        # the segment counters prove the pipelined flush path really ran
+        assert segd > 0.0, "a two-rank dpotrf never segmented a flush"
+        assert segs >= 2 * segd
     # prefetched-GET outcomes are distinct gauges (a single-rank run
     # never prefetches — the live >0 case rides test_overlap_pipeline)
     for suffix in ("gets", "hits", "misses", "cancels"):
-        assert val(f"parsec_comm_prefetch_{suffix}") == 0.0
+        got = val(f"parsec_comm_prefetch_{suffix}")
+        assert got == 0.0 if nb_ranks == 1 else got >= 0.0
 
 
 def test_flow_and_clock_gauges_in_exposition():

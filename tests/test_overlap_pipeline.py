@@ -24,35 +24,99 @@ def _tpu_devs(ctx):
 
 
 # --------------------------------------------------------------------- #
-# segmented flush: bit-exact differential + counters + fallback         #
+# one stacked call per flush group on one rank; segmented flush across  #
+# ranks: bit-exact differentials + counters + fallback                  #
 # --------------------------------------------------------------------- #
-def _run_dpotrf(segments: int):
-    """One classic-runtime dpotrf (POTRF/TRSM/SYRK/GEMM classes) with
-    the given device_flush_segments; returns (L, segment stats)."""
-    M = make_spd(256)
-    with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_flush_segments", str(segments)):
+SEGMENT_STATS = ("segmented_flushes", "flush_segments", "batches",
+                 "batched_tasks")
+
+
+def _on_ranks(nb_ranks, body):
+    """``body(ctx, rank)`` in a context of ``nb_ranks`` in-process ranks
+    (one plain context, or one per thread over a LocalFabric); the
+    per-rank results."""
+    if nb_ranks == 1:
         ctx = parsec_tpu.Context(nb_cores=2)
         try:
-            A = TwoDimBlockCyclic(256, 256, 32, 32,
-                                  dtype=np.float32).from_numpy(M)
-            ctx.add_taskpool(dpotrf_taskpool(A))
-            ctx.wait()
-            devs = _tpu_devs(ctx)
-            st = {k: sum(d.stats[k] for d in devs)
-                  for k in ("segmented_flushes", "flush_segments",
-                            "batches", "batched_tasks")}
-            return A.to_numpy().copy(), st
+            return [body(ctx, 0)]
         finally:
             ctx.fini()
 
+    def rank_fn(rank, fabric):
+        ctx = parsec_tpu.Context(nb_cores=2,
+                                 comm=RemoteDepEngine(fabric.engine(rank)))
+        try:
+            return body(ctx, rank)
+        finally:
+            ctx.fini()
+
+    return spmd(nb_ranks, rank_fn)[0]
+
+
+def _segment_stats(ctx):
+    devs = _tpu_devs(ctx)
+    return {k: sum(d.stats[k] for d in devs) for k in SEGMENT_STATS}
+
+
+def _sum_stats(per_rank):
+    return {k: sum(st[k] for st in per_rank) for k in SEGMENT_STATS}
+
+
+def _run_dpotrf(segments: int, nb_ranks: int = 1, batch_max: int = 16):
+    """One classic-runtime dpotrf (POTRF/TRSM/SYRK/GEMM classes, N=256,
+    NB=32) over ``nb_ranks`` in-process ranks with the given
+    device_flush_segments; returns (L, segment stats summed over the
+    ranks)."""
+    n, nb = 256, 32
+    M = make_spd(n)
+
+    def body(ctx, rank):
+        A = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32, P=nb_ranks,
+                              Q=1, nodes=nb_ranks, rank=rank)
+        A.name = "descA"
+        A.from_numpy(M.copy())
+        ctx.add_taskpool(dpotrf_taskpool(A, rank=rank, nb_ranks=nb_ranks))
+        ctx.wait()
+        owned = {c: np.asarray(A.data_of(*c).sync_to_host().payload)
+                 for c in A.tiles() if A.rank_of(*c) == rank}
+        return owned, _segment_stats(ctx)
+
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", str(batch_max)), \
+         params.cmdline_override("device_flush_segments", str(segments)):
+        results = _on_ranks(nb_ranks, body)
+    L = np.zeros((n, n), np.float32)
+    for owned, _st in results:
+        for (tm, tk), t in owned.items():
+            L[tm * nb:tm * nb + t.shape[0],
+              tk * nb:tk * nb + t.shape[1]] = t
+    return L, _sum_stats([st for _owned, st in results])
+
+
+def test_single_rank_dpotrf_flushes_each_group_whole(call_sizes):
+    """One rank makes no dependency send, so nothing is segmented
+    whatever device_flush_segments says: a flush group is ONE stacked
+    call of up to device_batch_max tasks (segments cap a call at 4),
+    bit-identical to per-task dispatch."""
+    L, st = _run_dpotrf(4)
+    sizes = list(call_sizes)
+    assert st["segmented_flushes"] == 0 and st["flush_segments"] == 0
+    assert st["batches"] == len(sizes)
+    assert st["batched_tasks"] == sum(sizes)
+    assert max(sizes) >= 8, sizes
+    assert set(sizes) <= {2, 4, 8, 16}, sizes
+    L_each, st_each = _run_dpotrf(4, batch_max=1)
+    assert st_each["batches"] == 0
+    assert np.array_equal(L, L_each), \
+        "a stacked call is not bit-identical to per-task dispatch"
+
 
 def test_segmented_flush_bit_exact_dpotrf():
-    """Acceptance: segmented flush is BIT-EXACT vs whole-batch unroll
-    dispatch for the cholesky/trsm/syrk/gemm classes, and the segment
-    counters prove the pipelined path really ran."""
-    L_whole, st_whole = _run_dpotrf(1)
-    L_seg, st_seg = _run_dpotrf(4)
+    """Acceptance: across ranks the segmented flush is BIT-EXACT vs
+    whole-batch unroll dispatch for the cholesky/trsm/syrk/gemm classes,
+    and the segment counters prove the pipelined path really ran."""
+    L_whole, st_whole = _run_dpotrf(1, nb_ranks=2)
+    L_seg, st_seg = _run_dpotrf(4, nb_ranks=2)
     assert st_whole["segmented_flushes"] == 0
     assert st_whole["flush_segments"] == 0
     assert st_seg["segmented_flushes"] > 0
@@ -63,60 +127,91 @@ def test_segmented_flush_bit_exact_dpotrf():
         "segmented flush is not bit-exact vs whole-batch dispatch"
 
 
-def _run_dtd_burst(segments: int, kern, burst=32, nb=48):
+def test_two_rank_dpotrf_caps_a_call_at_the_segment(call_sizes):
+    """Two ranks dispatch as before the single-rank rule: a bucket of n
+    tasks goes out as min(4, n // 2) sub-calls, so no call holds more
+    than device_batch_max / device_flush_segments = 4 tasks."""
+    _L, st = _run_dpotrf(4, nb_ranks=2)
+    assert st["segmented_flushes"] > 0
+    assert max(call_sizes) <= 4, call_sizes
+    assert st["batches"] == len(call_sizes)
+
+
+def _run_dtd_burst(segments: int, kern, burst=32, nb=48, nb_ranks=1,
+                   batch_max=16):
+    """A burst of independent same-class tasks on rank 0's device (a
+    keyless tile's home is rank 0; every rank inserts the same stream,
+    SPMD); returns (rank 0's outputs, rank 0's segment stats)."""
+    def body(ctx, rank):
+        tp = dtd.taskpool_new()
+        ctx.add_taskpool(tp)
+
+        def host(es, task):   # host fallback
+            c, a, b = dtd.unpack_args(task)
+            c -= a @ b.T
+
+        tc = tp.create_task_class("GEMM", 3, host)
+        tp.add_chore(tc, "tpu", kern)
+        rng = np.random.RandomState(7)
+        tiles = [[tp.tile_of_array(rng.rand(nb, nb).astype(np.float32))
+                  for _ in range(3)] for _ in range(burst)]
+        for c, a, b in tiles:
+            tp.insert_task_with_task_class(tc, (c, INOUT), (a, INPUT),
+                                           (b, INPUT))
+        tp.wait()
+        out = [np.asarray(c.data.sync_to_host().payload)
+               for c, _a, _b in tiles]
+        return out, _segment_stats(ctx)
+
     with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", str(batch_max)), \
          params.cmdline_override("device_flush_segments", str(segments)):
-        ctx = parsec_tpu.init(nb_cores=2)
-        try:
-            tp = dtd.taskpool_new()
-            ctx.add_taskpool(tp)
+        return _on_ranks(nb_ranks, body)[0]
 
-            def body(es, task):   # host fallback
-                c, a, b = dtd.unpack_args(task)
-                c -= a @ b.T
 
-            boot = tp.tile_of_array(np.zeros((nb, nb), np.float32))
-            tp.insert_task(body, (boot, INOUT), (boot, INPUT),
-                           (boot, INPUT))
-            tp.add_chore(body, "tpu", kern)
-            rng = np.random.RandomState(7)
-            tiles = [[tp.tile_of_array(rng.rand(nb, nb).astype(np.float32))
-                      for _ in range(3)] for _ in range(burst)]
-            for c, a, b in tiles:
-                tp.insert_task(body, (c, INOUT), (a, INPUT), (b, INPUT))
-            tp.wait()
-            devs = _tpu_devs(ctx)
-            st = {k: sum(d.stats[k] for d in devs)
-                  for k in ("segmented_flushes", "flush_segments",
-                            "batches")}
-            out = [np.asarray(c.data.sync_to_host().payload)
-                   for c, _a, _b in tiles]
-            return out, st
-        finally:
-            ctx.fini()
+def _gemm_kern():
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda c, a, b:
+                   c - jnp.dot(a, b.T, preferred_element_type=jnp.float32))
+
+
+def test_single_rank_dtd_burst_flushes_each_group_whole(call_sizes):
+    """A 32-task DTD burst on one rank: stacked calls of 16, none
+    segmented, bit-identical to per-task dispatch."""
+    kern = _gemm_kern()
+    out, st = _run_dtd_burst(4, kern)
+    sizes = list(call_sizes)
+    assert st["segmented_flushes"] == 0 and st["flush_segments"] == 0
+    assert 16 in sizes, sizes
+    assert st["batches"] == len(sizes)
+    assert st["batched_tasks"] == sum(sizes)
+    out_each, st_each = _run_dtd_burst(4, kern, batch_max=1)
+    assert st_each["batches"] == 0
+    assert all(np.array_equal(a, b) for a, b in zip(out, out_each))
 
 
 def test_segmented_flush_bit_exact_dtd_burst():
-    import jax
-    import jax.numpy as jnp
-    kern = jax.jit(lambda c, a, b:
-                   c - jnp.dot(a, b.T,
-                               preferred_element_type=jnp.float32))
-    out_whole, st_whole = _run_dtd_burst(1, kern)
-    out_seg, st_seg = _run_dtd_burst(4, kern)
+    kern = _gemm_kern()
+    out_whole, st_whole = _run_dtd_burst(1, kern, nb_ranks=2)
+    out_seg, st_seg = _run_dtd_burst(4, kern, nb_ranks=2)
     assert st_seg["segmented_flushes"] > 0 >= st_whole["segmented_flushes"]
+    assert st_seg["batches"] > st_whole["batches"]
     assert all(np.array_equal(a, b) for a, b in zip(out_whole, out_seg))
 
 
-def test_segmented_flush_untraceable_falls_back_per_task():
-    """A trace failure inside the FIRST segment must downgrade the class
-    and finish the whole group per-task — same transparent fallback as
-    the whole-batch path, results unchanged."""
+@pytest.mark.parametrize("nb_ranks", [1, 2])
+def test_segmented_flush_untraceable_falls_back_per_task(nb_ranks):
+    """A trace failure inside the FIRST call of a group (across ranks:
+    its first segment) must downgrade the class and finish the whole
+    group per-task — same transparent fallback either way, results
+    unchanged."""
     def kern(c, a, b):   # np.asarray on a tracer raises under jit
         return c - np.asarray(a) @ np.asarray(b).T
 
-    out, st = _run_dtd_burst(4, kern, burst=16)
+    out, st = _run_dtd_burst(4, kern, burst=16, nb_ranks=nb_ranks)
     assert st["batches"] == 0, "untraceable body must not batch"
+    assert st["segmented_flushes"] == nb_ranks - 1
     rng = np.random.RandomState(7)
     tiles = [[rng.rand(48, 48).astype(np.float32) for _ in range(3)]
              for _ in range(16)]
