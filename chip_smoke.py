@@ -42,7 +42,8 @@ import numpy as np  # noqa: E402
 #: and fails the latter, so a silently lowered precision cannot pass.
 DPOTRF_TOL = 1e-4
 #: max|R^T R - A^T A| / max|A^T A| (Q is discarded by design, so the
-#: normal-equations identity is the factor check, as bench_geqrf).
+#: normal-equations identity is the factor check, as in perfbench's
+#: dgeqrf cells).
 #: Householder QR in f32 at N=4096 lands near 1e-6 (error ~ sqrt(N) eps
 #: against entries ~N/4); bf16 inputs near 4e-3.  Same 1e-4 divide.
 DGEQRF_TOL = 1e-4

@@ -4,8 +4,9 @@ Admission control, weighted-fair scheduling, per-tenant quotas and SLO
 attribution in front of the untouched runtime.  Nothing here is
 constructed unless a :class:`SessionServer` is — with the ``serve``
 knob unset the runtime, schedulers and wire format are bit-for-bit
-those of a pre-serve build (the capture-identity differential in
-bench.py proves it).
+those of a pre-serve build (tests/test_serve.py holds it:
+``test_serve_knob_unset_is_inert`` and, on the wire's bytes,
+``test_wire_capture_serve_bit_identity``).
 """
 from .client import ServeClient, ServeTimeout
 from .fairness import TenantFairness

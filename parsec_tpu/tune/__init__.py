@@ -18,7 +18,9 @@ monitor's window ticks and adapts three knob families at runtime —
 
 Everything lives behind the ``tune_auto`` MCA param: unset constructs
 no controller, starts no subscription, and is bit-for-bit inert on the
-wire (proven by the frame-capture identity differential in bench.py).
+wire (tests/test_tune_controller.py holds it:
+``test_tune_auto_unset_constructs_no_controller`` and, on the wire's
+bytes, ``test_wire_capture_tune_bit_identity``).
 Every adaptation emits a ``tune:*`` instant annotation on the health
 trace stream plus the ``PARSEC::TUNE::*`` gauges.
 """

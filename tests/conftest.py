@@ -64,6 +64,27 @@ def spmd(nb_ranks, fn, timeout=120, fabric=None):
     return spmd_threads(nb_ranks, fn, timeout=timeout, fabric=fabric)
 
 
+def spmd_tcp(nb_ranks, fn, overrides=None):
+    """Run fn(rank, engine) on one thread per rank, each rank on its own
+    loopback ``TCPCommEngine``, with the MCA ``overrides`` in force from
+    engine construction to the last rank's return; the per-rank
+    results.  The rank function finalizes what it builds on the engine
+    (``Context.fini``)."""
+    import concurrent.futures as cf
+    from contextlib import ExitStack
+
+    from parsec_tpu.comm.tcp import TCPCommEngine, free_ports
+    from parsec_tpu.utils.params import params
+
+    eps = [("127.0.0.1", p) for p in free_ports(nb_ranks)]
+    with ExitStack() as st:
+        for k, v in (overrides or {}).items():
+            st.enter_context(params.cmdline_override(k, v))
+        with cf.ThreadPoolExecutor(nb_ranks) as ex:
+            return list(ex.map(
+                lambda r: fn(r, TCPCommEngine(r, eps)), range(nb_ranks)))
+
+
 @pytest.fixture
 def ctx():
     import parsec_tpu

@@ -81,7 +81,10 @@ def test_offsets_near_zero_on_shared_clock():
             "clock sampler produced no estimate"
         assert abs(off0) < 10_000, off0
         assert abs(off1) < 10_000, off1
-        assert e0.clock_offsets_us() == {1: off0}
+        # the sampler keeps folding pongs into the estimate: a second
+        # read names the same peer, not necessarily the same value
+        offs = e0.clock_offsets_us()
+        assert set(offs) == {1} and abs(offs[1]) < 10_000, offs
     finally:
         e0.fini()
         e1.fini()

@@ -38,9 +38,10 @@ def test_dgeqrf_rtr_identity(ctx, m, n, nb):
 
 def test_dgeqrf_residual_gate(ctx):
     """The dgeqrf RESIDUAL gate (ISSUE 12 satellite): the second
-    workload holds a strict relative residual bound at a bench-like
-    sizing, mirroring bench.py's BENCH_MODE=geqrf check — the absolute
-    tolerances above pass long after relative accuracy rots."""
+    workload holds a strict relative residual bound (the number
+    perfbench's dgeqrf cells check on the chip, at a CPU size) — the
+    absolute tolerances above pass long after relative accuracy
+    rots."""
     n, nb = 256, 64
     rng = np.random.RandomState(7)
     M = rng.rand(n, n).astype(np.float32)

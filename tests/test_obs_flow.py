@@ -3,6 +3,7 @@ data-plane messages, Chrome-trace flow pairs shared between sender and
 receiver, mixed-version/knob-unset wire bit-identity, the failure
 forensics dump, and stage-task spans carrying member contexts.
 """
+import functools
 import json
 
 import numpy as np
@@ -153,8 +154,8 @@ def test_flow_off_is_inert():
 def test_tcp_mixed_version_peer_negotiates_down():
     """Over real TCP, a peer whose HELLO never advertised "tr" (knob
     unset there) receives UNstamped payloads even though the sender has
-    flow tracing armed — the byte-level twin rides the bench capture
-    differential (bench_trace_capture_identity)."""
+    flow tracing armed — the byte-level twin is the capture
+    differential (wire_capture.capture_identity)."""
     import time
     from parsec_tpu.comm.tcp import TCPCommEngine, free_ports
 
@@ -195,22 +196,22 @@ def test_tcp_mixed_version_peer_negotiates_down():
 def test_wire_capture_bit_identity():
     """The PR 14-pattern differential on the WIRE bytes themselves:
     the scripted deterministic exchange is byte-identical across two
-    knob-unset runs AND toward a mixed-version peer (bench's capture
-    harness — the same leg the dryrun gate asserts)."""
-    import bench
+    knob-unset runs AND toward a mixed-version peer (the capture
+    fixture, tests/wire_capture.py)."""
+    from wire_capture import capture_identity
 
-    out = bench.bench_trace_capture_identity()
+    out = capture_identity()
     assert out["trace_frames_captured"] > 0
     assert out["trace_unset_bit_identical"]
     assert out["trace_mixed_version_bit_identical"]
 
 
-def test_dpotrf_flow_edges_stitch_across_ranks():
-    """End to end on the in-process fabric: a 2-rank dpotrf under
-    ``obs_flow`` produces matched cross-rank edges in BOTH directions
-    with non-negative lag (same clock)."""
-    from parsec_tpu.obs import load_flow_events, merge_trace_docs, \
-        stitch_flows
+@functools.lru_cache(maxsize=None)
+def _traced_two_rank_dpotrf():
+    """One 2-rank dpotrf under ``obs_flow`` on the in-process fabric;
+    the two rank traces merged onto one timeline (both tests below read
+    the same run)."""
+    from parsec_tpu.obs import merge_trace_docs
 
     n, nb, ranks = 128, 32, 2
     M = make_spd(n, dtype=np.float32)
@@ -232,13 +233,46 @@ def test_dpotrf_flow_edges_stitch_across_ranks():
             finally:
                 ctx.fini()
         docs, _fab = spmd(ranks, rank_fn)
+    return merge_trace_docs(docs)
+
+
+def test_dpotrf_flow_edges_stitch_across_ranks():
+    """End to end on the in-process fabric: a 2-rank dpotrf under
+    ``obs_flow`` produces matched cross-rank edges in BOTH directions
+    with non-negative lag (same clock)."""
+    from parsec_tpu.obs import load_flow_events, stitch_flows
+
     edges, unmatched = stitch_flows(
-        load_flow_events(merge_trace_docs(docs)))
+        load_flow_events(_traced_two_rank_dpotrf()))
     cross = [e for e in edges if e["src"] != e["dst"]]
     dirs = {(e["src"], e["dst"]) for e in cross}
     assert unmatched == 0
     assert (0, 1) in dirs and (1, 0) in dirs
     assert all(e["lag_us"] >= 0 for e in cross)
+
+
+def test_dpotrf_cross_rank_report_crosses_the_wire():
+    """The offline report over the same run (``tools/obs_report.py``):
+    every flow half stitched, an edge per direction, none with recv
+    before send, a critical path that crosses the wire and visits both
+    ranks, un-hidden comm attributed to a NAMED link on every rank."""
+    from parsec_tpu.obs import analyze
+
+    cr = analyze([_traced_two_rank_dpotrf()])["cross_rank"]
+    links = cr["edges_per_link"]
+    assert links.get("R0->R1", 0) >= 1 and links.get("R1->R0", 0) >= 1, \
+        links
+    assert cr["flow_edges"] == sum(links.values())
+    assert cr["unmatched_flows"] == 0
+    assert cr["negative_lag_edges"] == 0 and cr["min_lag_us"] >= 0, cr
+    dcp = cr["critical_path"]
+    assert dcp["cross_edges"] >= 1, dcp
+    assert set(dcp["ranks_visited"]) == {0, 1}, dcp
+    for r in (0, 1):
+        table = cr["per_link_exposed_us"].get(r) or {}
+        assert table and set(table) <= {"R0->R1", "R1->R0"}, \
+            (r, cr["per_link_exposed_us"])
+        assert max(table.values()) > 0
 
 
 def test_forensics_dump_on_rank_failure(tmp_path):

@@ -363,12 +363,12 @@ def test_tcp_live_negotiation_and_mixed_version_down():
 
 
 def test_wire_capture_live_bit_identity():
-    """The frame-level differential (dryrun gate leg): toward a peer
-    that never advertised "lv", an obs_live sender's data frames are
-    BIT-IDENTICAL to the knob-unset run."""
-    import bench
+    """The frame-level differential (tests/wire_capture.py, leg D):
+    toward a peer that never advertised "lv", an obs_live sender's data
+    frames are BIT-IDENTICAL to the knob-unset run."""
+    from wire_capture import capture_identity
 
-    out = bench.bench_trace_capture_identity()
+    out = capture_identity()
     assert out["trace_frames_captured"] > 0
     assert out["live_mixed_version_bit_identical"]
 

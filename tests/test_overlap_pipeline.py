@@ -125,6 +125,9 @@ def test_segmented_flush_bit_exact_dpotrf():
     assert st_seg["batches"] > st_whole["batches"]  # more, smaller calls
     assert np.array_equal(L_whole, L_seg), \
         "segmented flush is not bit-exact vs whole-batch dispatch"
+    Lt = np.tril(L_seg).astype(np.float64)
+    M = make_spd(256)
+    assert np.abs(Lt @ Lt.T - M).max() / np.abs(M).max() < 1e-5
 
 
 def test_two_rank_dpotrf_caps_a_call_at_the_segment(call_sizes):

@@ -486,47 +486,38 @@ def test_wave_pdgemm_ragged():
     assert np.abs(C.to_numpy().astype(np.float64) - ref).max() / n < 1e-6
 
 
-def test_synth_pools_parity_and_subset_coords():
-    """On-device pool synthesis (zero-H2D staging, bench/demo path):
-    the vectorized whole-pool builder (bench.synth_spd_pool_fn) must
-    produce exactly the per-tile _synth_lower values in build_pools'
-    layout, both granularities must agree, and a SUBSET coordinate set
-    (e.g. a lower-uplo pool) must not clobber row 0 with dropped
-    scatter writes (the pos-default bug class)."""
-    import os
-    import sys
-
+def test_synth_pools_layout_matches_build_pools():
+    """On-device pool synthesis (``WaveRunner.synth_pools``, no H2D
+    staging): the per-tile and the whole-pool granularity must produce
+    the same pools, in build_pools' layout (same pool walk, same
+    scratch pools), and a factorization run on them must be the one run
+    on host-staged pools."""
     import jax
     import jax.numpy as jnp
 
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _synth_lower, synth_spd_pool_fn
-
     n, nb = 128, 32
-    nt = n // nb
-    key = jax.random.PRNGKey(23)
-    A = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32)
+    M = make_spd(n).astype(np.float32)
+    A = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32).from_numpy(M)
     w = wave(dpotrf_taskpool(A))
-    pool_fn = synth_spd_pool_fn(key, nt, nb, n, jnp.float32)
+    Md = jnp.asarray(M)
 
     def tile_fn(_name, c):
-        low = _synth_lower(key, nt, nb, n, jnp.float32)
-        return low[c] if c[0] >= c[1] else jnp.zeros((nb, nb),
-                                                     jnp.float32)
+        return Md[c[0] * nb:(c[0] + 1) * nb, c[1] * nb:(c[1] + 1) * nb]
 
-    by_pool = w.synth_pools(pool_fn=pool_fn)
+    def pool_fn(_name, coords):
+        idx = np.asarray(coords, np.int32)
+        return Md.reshape(n // nb, nb, n // nb, nb).transpose(
+            0, 2, 1, 3)[idx[:, 0], idx[:, 1]]
+
+    staged = w.build_pools()
     by_tile = w.synth_pools(tile_fn)
-    assert len(by_pool) == len(by_tile) == len(w.build_pools())
-    for a, b in zip(by_pool, by_tile):
+    by_pool = w.synth_pools(pool_fn=pool_fn)
+    assert len(by_tile) == len(by_pool) == len(staged)
+    for a, b, c in zip(by_tile, by_pool, staged):
+        assert a.shape == b.shape == c.shape and a.dtype == c.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    # subset coords: lower triangle only — absent uppers must be
-    # DROPPED, not scattered onto row 0
-    coords = [(m, k) for m in range(nt) for k in range(m + 1)]
-    sub = np.asarray(jax.jit(lambda: pool_fn("descA", coords))())
-    low = {c: np.asarray(v) for c, v in
-           jax.jit(lambda: _synth_lower(key, nt, nb, n,
-                                        jnp.float32))().items()}
-    for i, c in enumerate(coords):
-        np.testing.assert_array_equal(sub[i], low[c])
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    out_synth = jax.block_until_ready(w.execute(by_pool))
+    out_staged = jax.block_until_ready(w.execute(staged))
+    for a, b in zip(out_synth, out_staged):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -14,6 +14,8 @@ Three layers under test:
   quantize; pools that declare ``wire_lossless`` (checkpoint-reshard
   redistribution) never do.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,7 @@ from parsec_tpu.parallel.mesh import (ErrorFeedback, reduced_precision_sum,
                                       two_level_allreduce)
 from parsec_tpu.utils.params import params
 
+from conftest import spmd_tcp
 from test_comm_multirank import spmd
 from test_wave_dist import _gather_owned
 
@@ -293,6 +296,76 @@ def test_wave_reduce_dtype_dpotrf_within_bound(nb_ranks=4):
     ref = np.linalg.cholesky(M)
     resid = np.abs(L - ref).max() / np.abs(ref).max()
     assert resid < 1e-2, resid   # lossy but bounded (measured ~1e-3)
+
+
+# --------------------------------------------------------------------- #
+# comm_quantize end to end: a 2-rank classic-runtime dpotrf over real   #
+# loopback TCP, per wire codec (counts and bounds, no clock)            #
+# --------------------------------------------------------------------- #
+@functools.lru_cache(maxsize=None)
+def _tcp_dpotrf(codec, n=256, nb=64, chunk_bytes=8192):
+    """Factor, residual and per-rank wire counters of a 2-rank dpotrf
+    whose 16 KB tiles ride the chunked lane under ``codec`` ("" =
+    unset).  Cached: the lossless leg is every codec case's baseline."""
+    ranks = 2
+    M = make_spd(n, dtype=np.float32)
+
+    def rank_fn(r, ce):
+        ctx = parsec_tpu.Context(nb_cores=1, comm=RemoteDepEngine(ce))
+        try:
+            coll = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32,
+                                     P=ranks, Q=1, nodes=ranks, rank=r)
+            coll.name = "descA"
+            coll.from_numpy(M.copy())
+            ctx.add_taskpool(dpotrf_taskpool(coll, rank=r, nb_ranks=ranks))
+            ctx.wait()
+            stats = {k: ce.wire_stats[k]
+                     for k in ("chunk_bytes_sent", "bufs_quantized",
+                               "bytes_prequant", "bytes_postquant")}
+            stats["ratio"] = (ce.codec_ratio(1 - r, "q" + codec)
+                              if codec else 1.0)
+            return stats, _gather_owned(coll, rank=r)
+        finally:
+            ctx.fini()
+
+    results = spmd_tcp(ranks, rank_fn, {
+        "comm_chunk_bytes": str(chunk_bytes), "comm_quantize": codec,
+        "comm_mesh_local": "0"})   # payloads must ride the wire
+    L = np.zeros((n, n), np.float32)
+    for _st, owned in results:
+        for (m, k), t in owned.items():
+            L[m * nb:(m + 1) * nb, k * nb:(k + 1) * nb] = t
+    Lt = np.tril(L).astype(np.float64)
+    resid = float(np.abs(Lt @ Lt.T - M).max() / np.abs(M).max())
+    return L, resid, [st for st, _o in results]
+
+
+def test_comm_quantize_unset_dpotrf_is_bit_identical_and_lossless():
+    """Knob-unset differential: two lossless runs land BIT-IDENTICAL
+    tiles, move their tiles on the chunked lane, and quantize nothing."""
+    L_a, resid, stats = _tcp_dpotrf("")
+    L_b, _resid, _stats = _tcp_dpotrf.__wrapped__("")   # a second run
+    assert np.array_equal(L_a, L_b)
+    assert resid < 1e-5
+    assert sum(s["chunk_bytes_sent"] for s in stats) > 0
+    assert all(s["bufs_quantized"] == 0 for s in stats), stats
+
+
+@pytest.mark.parametrize("codec,bound", [("bf16", 1e-3), ("int8", 5e-3)])
+def test_comm_quantize_dpotrf_fewer_bytes_within_declared_bound(codec,
+                                                                bound):
+    """Each codec engages on every link (buffers quantized, ratio > 1),
+    moves STRICTLY fewer payload bytes than the lossless leg, and keeps
+    the residual inside its DECLARED bound (measured 5e-5 / 3e-4)."""
+    _L0, _r0, base = _tcp_dpotrf("")
+    _L, resid, stats = _tcp_dpotrf(codec)
+    assert all(s["bufs_quantized"] > 0 for s in stats), stats
+    assert all(s["ratio"] > 1.0 for s in stats), stats
+    assert all(s["bytes_postquant"] < s["bytes_prequant"] for s in stats)
+    sent = sum(s["chunk_bytes_sent"] for s in stats)
+    sent0 = sum(s["chunk_bytes_sent"] for s in base)
+    assert 0 < sent < sent0, (sent, sent0)
+    assert resid <= bound, (codec, resid)
 
 
 # --------------------------------------------------------------------- #

@@ -398,6 +398,18 @@ def test_serve_knob_unset_is_inert(ctx):
     assert ctx.obs.live is None
 
 
+def test_wire_capture_serve_bit_identity():
+    """The frame-level differential (tests/wire_capture.py, leg F):
+    toward a peer that never advertised "sv", a ``serve`` sender with a
+    tenant map armed stamps no tenant and sends no serve control frame:
+    its data frames are BIT-IDENTICAL to the knob-unset run."""
+    from wire_capture import capture_identity
+
+    out = capture_identity()
+    assert out["trace_frames_captured"] > 0
+    assert out["serve_mixed_version_bit_identical"]
+
+
 def test_serve_knob_implies_live_monitor():
     with params.cmdline_override("serve", "1"):
         c = parsec_tpu.init(nb_cores=2)

@@ -1229,6 +1229,18 @@ def test_stagec_xrank_mixed_version_negotiates_down():
     assert_ulp_close(L1, L0)
 
 
+def test_wire_capture_xstage_bit_identity():
+    """The frame-level differential (tests/wire_capture.py, leg H):
+    toward a peer that never advertised "xs", a sender with the
+    capability set puts no digest or boundary control frame on the
+    wire: its data frames are BIT-IDENTICAL to the knob-unset run."""
+    from wire_capture import capture_identity
+
+    out = capture_identity()
+    assert out["trace_frames_captured"] > 0
+    assert out["xstage_mixed_version_bit_identical"]
+
+
 def test_stagec_xrank_knob_unset_keeps_activation_path():
     """Knob-unset inertness: with only ``stage_compile`` on, no engine
     advertises "xs" (the capability defaults from the

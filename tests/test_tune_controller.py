@@ -10,6 +10,7 @@ import parsec_tpu
 from parsec_tpu.tune import (CODEC_COST, CODEC_LADDER, Controller,
                              register_tune_gauges)
 from parsec_tpu.utils.params import params
+from test_comm_wire import _engines
 
 
 # ---------------------------------------------------------------------- #
@@ -259,16 +260,101 @@ def test_tune_auto_set_constructs_controller_and_gauges():
 
 
 def test_wire_capture_tune_bit_identity():
-    """The frame-level differential (dryrun gate leg E): toward a peer
-    that never advertised "tn", a tune_auto sender's data frames are
-    BIT-IDENTICAL to the knob-unset run — and the unset legs carry no
-    tuning bytes at all."""
-    import bench
+    """The frame-level differential (tests/wire_capture.py, leg E):
+    toward a peer that never advertised "tn", a tune_auto sender's data
+    frames are BIT-IDENTICAL to the knob-unset run — and the unset legs
+    carry no tuning bytes at all."""
+    from wire_capture import capture_identity
 
-    out = bench.bench_trace_capture_identity()
+    out = capture_identity()
     assert out["trace_frames_captured"] > 0
     assert out["trace_unset_bit_identical"]
     assert out["tune_mixed_version_bit_identical"]
+
+
+# ---------------------------------------------------------------------- #
+# the actuators are real: TCP engines renegotiate, the trace keeps it    #
+# ---------------------------------------------------------------------- #
+def test_controller_actuates_real_tcp_link_and_annotates_merged_trace():
+    """The same synthetic windows against a REAL loopback TCP pair that
+    negotiated "tn" and a real LiveHealth stream: a slow send bandwidth
+    installs the codec on this end (tx); a hot inbound link sends a
+    K_TUNE frame and the PEER's encoder climbs (rx); a marked buffer
+    then travels quantized; sparse occupancy halves batch_max.  Every
+    move is a ``tune:*`` INSTANT that survives the offset-corrected
+    merge of the two rank timelines."""
+    import json
+    import time
+
+    from parsec_tpu.comm import wire
+    from parsec_tpu.obs import merge_trace_docs
+    from parsec_tpu.obs.live import LiveHealth
+    from parsec_tpu.obs.spans import HEALTH_STREAM_TID
+    from parsec_tpu.profiling.trace import Profile
+
+    e0, e1 = _engines(2, tune_auto=True, chunk_bytes=1 << 14)
+    try:
+        deadline = time.time() + 10
+        while time.time() < deadline and not (e0.tune_to(1)
+                                              and e1.tune_to(0)):
+            time.sleep(0.005)
+        assert e0.tune_to(1) and e1.tune_to(0), '"tn" never negotiated'
+        p0, p1 = Profile(rank=0), Profile(rank=1)
+        live = LiveHealth(0, stream=p0.stream(HEALTH_STREAM_TID, "health"))
+        dev = FakeDevice(batch_max=16)
+        ctl = Controller(0, live, engine=e0, devices=(dev,),
+                         residual_budget=1e-1, hysteresis=1)
+        assert e0.active_quant_codec(1) is None
+        assert e1.active_quant_codec(0) is None
+        # tx: this end's own send bandwidth under the floor
+        ctl.on_window(slow_bw_digest(0))
+        assert e0.active_quant_codec(1) == "qbf16"
+        # rx: the inbound link is hot -> ask the sender over the wire
+        for w in range(1, 8):
+            ctl.on_window(hot_link_digest(w))
+            if ctl.counts["codec_moves"] >= 2:
+                break
+        deadline = time.time() + 10
+        while time.time() < deadline \
+                and e1.active_quant_codec(0) is None:
+            time.sleep(0.005)
+        assert e1.active_quant_codec(0) == "qbf16"
+        # the renegotiated codec carries a marked bulk float buffer
+        got = []
+        e0.tag_register(700, lambda src, p: got.append(p))
+        arr = np.random.RandomState(5).rand(1 << 15)
+        e1.send_am(0, 700, {"arr": arr, "_qz_ok": True})
+        deadline = time.time() + 30
+        while time.time() < deadline and not got:
+            if not e0.progress():
+                time.sleep(0.0005)
+        np.testing.assert_array_equal(np.asarray(got[0]["arr"]),
+                                      wire.qdq_array(arr, "qbf16"))
+        assert e1.wire_stats["bufs_quantized"] == 1
+        assert e0.rx_quant_ratio(1)[0] == arr.nbytes
+        # device family: sparse occupancy halves batch_max
+        for w in range(8, 12):
+            dev.window(batches=10, tasks=20, ns=200_000, n=20)
+            ctl.on_window({"window": w, "links": {}, "bw": {},
+                           "fired": ()})
+        assert dev.batch_max < 16
+        assert ctl.counts["codec_moves"] >= 2
+        assert ctl.counts["device_moves"] >= 1
+    finally:
+        e0.fini()
+        e1.fini()
+    d0, d1 = p0.to_chrome_trace(), p1.to_chrome_trace()
+    d1.setdefault("metadata", {})["clock_offsets_us"] = json.dumps(
+        {"0": -1500.0})   # rank 1's clock reads 1.5 ms ahead
+    merged = merge_trace_docs([d0, d1])
+    annos = [e for e in merged["traceEvents"]
+             if e.get("ph") == "i" and e.get("tid") == HEALTH_STREAM_TID
+             and str(e.get("name", "")).startswith("tune:")]
+    names = [e["name"] for e in annos]
+    assert names.count("tune:codec") == ctl.counts["codec_moves"]
+    assert names.count("tune:device") == ctl.counts["device_moves"]
+    assert {e["args"]["dir"] for e in annos
+            if e["name"] == "tune:codec"} == {"tx", "rx"}
 
 
 # ---------------------------------------------------------------------- #

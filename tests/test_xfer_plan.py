@@ -24,6 +24,7 @@ from parsec_tpu.collections.redistribute import redistribute
 from parsec_tpu.comm import RemoteDepEngine
 from parsec_tpu.utils.params import params
 from parsec_tpu.xfer import build_plan, run_redistribution
+from conftest import spmd_tcp
 from test_comm_multirank import spmd
 
 
@@ -211,6 +212,86 @@ def test_run_redistribution_bumps_round_gauge():
     for rounds, stats in results:
         assert rounds >= 1
         assert stats["redist_rounds"] == rounds
+
+
+# --------------------------------------------------------------------- #
+# the same reshard three ways over real TCP engines: per-tile storm,    #
+# planned rounds, planned + device plane (bytes and counts, no clock)   #
+# --------------------------------------------------------------------- #
+def _tcp_reshard(knobs, attach_plane=False, n=64, tile=8, ranks=4):
+    """A whole-matrix P x 1 -> 1 x Q reshard of an n x n f64 matrix
+    over loopback TCP engines under ``knobs``; (assembled target, host
+    wire bytes summed over ranks, per-rank pools, per-rank
+    ``dplane_stats``)."""
+    src_np = np.random.RandomState(19).rand(n, n)
+    barrier = threading.Barrier(ranks)
+
+    def rank_fn(r, ce):
+        ctx = parsec_tpu.Context(nb_cores=1, comm=RemoteDepEngine(ce),
+                                 enable_tpu=False)
+        try:
+            if attach_plane:
+                from parsec_tpu.comm.xfer import DeviceDataPlane
+                DeviceDataPlane(ce).exchange(timeout=60.0)
+            Y = _grid(n, n, tile, tile, P=ranks, Q=1, nodes=ranks,
+                      rank=r).from_numpy(src_np)
+            T = _grid(n, n, tile, tile, P=1, Q=ranks, nodes=ranks,
+                      rank=r).from_numpy(np.zeros((n, n)))
+            barrier.wait(60)
+            b0 = ce.fabric.bytes_count
+            tp = redistribute(Y, T, n, n, context=ctx)
+            barrier.wait(60)   # both directions fully flushed
+            wire_bytes = ce.fabric.bytes_count - b0
+            owned = {c: np.array(T.tile(*c)) for c in T.local_tiles()}
+            return wire_bytes, tp, dict(ce.dplane_stats), owned
+        finally:
+            ctx.fini()
+
+    results = spmd_tcp(ranks, rank_fn, knobs)
+    got = np.zeros((n, n))
+    for _b, _tp, _dp, owned in results:
+        for (m, k), t in owned.items():
+            got[m * tile:(m + 1) * tile, k * tile:(k + 1) * tile] = t
+    return (got, src_np, sum(r[0] for r in results),
+            [r[1] for r in results], [r[2] for r in results])
+
+
+def test_tcp_reshard_planned_and_dplane_shed_host_wire_bytes():
+    """Every leg lands the BIT-IDENTICAL matrix; the planned rounds
+    move strictly fewer host-TCP bytes than the per-tile GET storm, in
+    fewer rounds and transfers than tile moves; with ``xfer_dplane``
+    the whole cross-rank payload leaves the session wire and what is
+    left on it (descriptors, acks) is below the planned leg's."""
+    n, tile, ranks = 64, 8, 4
+    plan = build_plan(_grid(n, n, tile, tile, P=ranks, Q=1, nodes=ranks),
+                      _grid(n, n, tile, tile, P=1, Q=ranks, nodes=ranks))
+    moves = plan.tile_moves
+    storm, src, storm_b, _tps, storm_dp = _tcp_reshard({})
+    planned, _src, planned_b, tps, _dp = _tcp_reshard(
+        {"xfer_collective_redist": "1"})
+    dplane, _src, dplane_b, _tps, dp = _tcp_reshard(
+        {"xfer_collective_redist": "1", "xfer_dplane": "1",
+         "xfer_backend": "loopback"}, attach_plane=True)
+    for got in (storm, planned, dplane):
+        np.testing.assert_array_equal(got, src)
+    assert 0 < planned_b < storm_b, (planned_b, storm_b)
+    assert all(0 < tp.redist_rounds < moves for tp in tps)
+    assert all(0 < tp.redist_transfers < moves for tp in tps)
+    assert sum(d["dplane_xfers"] for d in storm_dp) == 0
+    assert sum(d["dplane_xfers"] for d in dp) > 0
+    assert sum(d["dplane_bytes"] for d in dp) == moves * tile * tile * 8
+    assert 0 < dplane_b < planned_b, (dplane_b, planned_b)
+
+
+def test_wire_capture_dplane_bit_identity():
+    """The frame-level differential (tests/wire_capture.py, leg G):
+    toward a peer that never advertised "dp", an ``xfer_dplane``
+    sender's data frames are BIT-IDENTICAL to the knob-unset run."""
+    from wire_capture import capture_identity
+
+    out = capture_identity()
+    assert out["trace_frames_captured"] > 0
+    assert out["dplane_mixed_version_bit_identical"]
 
 
 # --------------------------------------------------------------------- #
