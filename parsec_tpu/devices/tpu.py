@@ -28,7 +28,7 @@ import contextlib
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.lists import Dequeue
 from ..data.data import Coherency, Data, DataCopy, FlowAccess
@@ -42,9 +42,11 @@ _log = plog.device_stream
 #: declared lock discipline, enforced by the concurrency lint
 #: (parsec_tpu/analysis/lock_check.py): HBM accounting + both LRU lists
 #: belong to the memory lock (any worker stages in / prefetches while
-#: the manager evicts); the in-flight/window records belong to the
-#: manager lock (one manager at a time — the CAS-owner acquire in
-#: ``progress``; helpers on that path carry ``# holds:`` annotations)
+#: the manager evicts); the in-flight/window records (one per device
+#: CALL) and the running count of the tasks the window's calls hold
+#: belong to the manager lock (one manager at a time — the CAS-owner
+#: acquire in ``progress``; helpers on that path carry ``# holds:``
+#: annotations)
 _GUARDED_BY = {
     "JaxDevice.mem_used": "_mem_lock",
     "JaxDevice.mem_highwater": "_mem_lock",
@@ -52,6 +54,7 @@ _GUARDED_BY = {
     "JaxDevice._lru_owned": "_mem_lock",
     "JaxDevice._inflight": "_manager_lock",
     "JaxDevice._window": "_manager_lock",
+    "JaxDevice._window_tasks": "_manager_lock",
     "JaxDevice._eager_done": "_manager_lock",
 }
 
@@ -65,22 +68,6 @@ def _arr_device(arr: Any):
     except (AttributeError, TypeError):
         pass
     return None
-
-
-def _array_ready(arr: Any) -> bool:
-    """True when the backing buffer is materialized (event-query analog).
-    A DONATED buffer (device_donate: a successor batched call consumed
-    it) counts as ready — donation happens at the consumer's dispatch,
-    which XLA orders after this producer."""
-    try:
-        if arr.is_deleted():
-            return True
-    except AttributeError:
-        pass
-    try:
-        return arr.is_ready()
-    except AttributeError:
-        return True  # host/numpy arrays are always ready
 
 
 class _Xfer:
@@ -112,14 +99,33 @@ _NO_XFER = contextlib.nullcontext()
 
 
 class _InFlight:
-    __slots__ = ("task", "outputs", "out_flows", "es_hint", "est", "t0",
+    """The record of ONE device call of n >= 1 tasks: a stacked or
+    mesh-sharded call files its whole chunk, a task dispatched alone a
+    record of one.  Everything after the dispatch (the window, the
+    wait, the retire, the epilog, the hand-over of what became ready)
+    happens once per record.
+
+    ``outs`` is the call's flat result, grouped by output slot as the
+    stacked programs return it: ``outs[k * n + i]`` is output ``k`` of
+    ``tasks[i]``, written to flow ``out_flows[i][k]`` (a DTD task
+    carries its own access modes, so the written flows are each
+    task's).  ``waits`` groups the outputs by the executable that makes
+    them: the members of a group become ready together, so one live
+    member answers for the group (a stacked call is one group; a lone
+    task's eager kernels are a group each; a sharded call has one per
+    chip)."""
+
+    __slots__ = ("tasks", "outs", "out_flows", "waits", "est", "t0",
                  "last_poll", "done_est")
 
-    def __init__(self, task: Task, outputs: List[Any], out_flows: List[int], est: float) -> None:
-        self.task = task
-        self.outputs = outputs
+    def __init__(self, tasks: List[Task], outs: Sequence[Any],
+                 out_flows: List[List[int]], est: float,
+                 waits: Optional[List[Sequence[Any]]] = None) -> None:
+        self.tasks = tasks
+        self.outs = outs
         self.out_flows = out_flows
-        self.est = est
+        self.waits = [outs] if waits is None else waits
+        self.est = est      # summed over the call's tasks
         # submission timestamp: with telemetry on, [t0, completion
         # estimate] feeds the live overlap gauge's COMPUTE channel as
         # the device-busy interval (obs/spans.OverlapTracker; exec PINS
@@ -133,6 +139,23 @@ class _InFlight:
         self.t0 = time.monotonic_ns()
         self.last_poll = self.t0
         self.done_est = 0
+
+    def live(self):
+        """One output per wait group that still has a buffer to ask.  A
+        DONATED buffer (device_donate: a successor's call consumed it)
+        is that successor's record's to wait for — donation happens at
+        the consumer's dispatch, which XLA orders after this producer —
+        and a host array is always ready."""
+        for group in self.waits:
+            for a in group:
+                if hasattr(a, "block_until_ready") and not a.is_deleted():
+                    yield a
+                    break
+
+    def ready(self) -> bool:
+        """Is the call's work materialized (the event-query analog)?
+        Asked once per wait group."""
+        return all(a.is_ready() for a in self.live())
 
 
 class JaxDevice(Device):
@@ -178,12 +201,16 @@ class JaxDevice(Device):
                       # flush groups that were carved into pipelined
                       # sub-calls, and the total sub-calls dispatched
                       # for them
-                      "segmented_flushes": 0, "flush_segments": 0}
+                      "segmented_flushes": 0, "flush_segments": 0,
+                      # call records retired: tasks / retired_calls is
+                      # the tasks a record held
+                      "retired_calls": 0}
         # eager completion (async dispatch IS completion; XLA orders the
         # dataflow) with a bounded in-flight window
         self.eager_complete = bool(params.get("tpu_eager_complete"))
         self.eager_window = int(params.get("tpu_eager_window"))
         self._window: List[_InFlight] = []
+        self._window_tasks = 0      # tasks the window's calls hold
         self._eager_done: List[_InFlight] = []
         # batched dispatch + async stage-in prefetch (the task-stream
         # pipeline; ISSUE 5): same-class ready tasks accumulate in
@@ -271,20 +298,21 @@ class JaxDevice(Device):
                 drained.append(item)
             if drained:
                 n += self._dispatch_ready(es, drained)
-            # poll phase: complete ready in-flight tasks
+            # poll phase: complete the in-flight calls that are ready
             if self._eager_done:
                 done, self._eager_done = self._eager_done, []
                 for rec in done:
                     self._epilog(es, rec)
-                    n += 1
+                    n += len(rec.tasks)
             now = time.monotonic_ns()
             if self._window:
                 # retire finished window entries so device_load drains on
                 # idle devices and async errors surface during the run
                 still_w = []
                 for rec in self._window:
-                    if all(_array_ready(a) for a in rec.outputs):
+                    if rec.ready():
                         rec.done_est = (rec.last_poll + now) // 2
+                        self._window_tasks -= len(rec.tasks)
                         self._retire(rec, es)
                     else:
                         rec.last_poll = now
@@ -293,7 +321,7 @@ class JaxDevice(Device):
             still: List[_InFlight] = []
             done = []
             for rec in self._inflight:
-                if all(_array_ready(a) for a in rec.outputs):
+                if rec.ready():
                     rec.done_est = (rec.last_poll + now) // 2
                     done.append(rec)
                 else:
@@ -302,7 +330,7 @@ class JaxDevice(Device):
             self._inflight = still
             for rec in done:
                 self._epilog(es, rec)
-                n += 1
+                n += len(rec.tasks)
             return n
         finally:
             if clock is not None:
@@ -455,12 +483,16 @@ class JaxDevice(Device):
         assert len(outputs) == len(out_flows), (
             f"{tc.name} tpu body returned {len(outputs)} arrays for "
             f"{len(out_flows)} written flows")
-        self._finish_submit(es, task, est, list(outputs), out_flows)
+        # a record of one; the body's eager kernels are an executable
+        # each, so each output is waited for
+        self._finish_submit(es, _InFlight(
+            [task], outputs, [out_flows], est, [(a,) for a in outputs]))
 
-    def _finish_submit(self, es, task: Task, est: float,  # holds: self._manager_lock
-                       outputs: List[Any], out_flows: List[int]) -> None:
-        rec = _InFlight(task, outputs, out_flows, est)
-        self.stats["tasks"] += 1
+    def _finish_submit(self, es, rec: _InFlight) -> None:  # holds: self._manager_lock
+        """File the record of one dispatched call, whatever it holds:
+        once per call, never per task."""
+        n = len(rec.tasks)
+        self.stats["tasks"] += n
         if self.eager_complete:
             # TPU-native completion model: jax dispatch is async and XLA's
             # execution queue already orders consumers after producers, so
@@ -468,11 +500,18 @@ class JaxDevice(Device):
             # chain their jit calls on the in-flight arrays. Host-side
             # reads still block on conversion (device->host sync point).
             # A bounded window keeps the queue from running unboundedly
-            # ahead (ref: the CUDA module bounds in-flight per stream).
+            # ahead (ref: the CUDA module bounds in-flight per stream):
+            # it holds calls and bounds the TASKS they hold.
             self._window.append(rec)
-            if len(self._window) > self.eager_window:
-                # backpressure: block on the oldest submission
-                self._retire(self._window.pop(0), es)
+            self._window_tasks += n
+            while self._window_tasks > self.eager_window \
+                    and len(self._window) > 1:
+                # backpressure: block on the oldest call (never on the
+                # one just filed: a call larger than the window waits
+                # for nothing but its predecessors)
+                old = self._window.pop(0)
+                self._window_tasks -= len(old.tasks)
+                self._retire(old, es)
             self._eager_done.append(rec)
         else:
             self._inflight.append(rec)
@@ -508,7 +547,10 @@ class JaxDevice(Device):
                     continue
                 bargs, flow_idx, static = ext
                 donate = tuple(bool(donate_ok.get(fi)) for fi in flow_idx)
-                shapes = tuple((tuple(a.shape), str(a.dtype)) for a in bargs)
+                # what is true of the call is computed once for the
+                # call: the key carries the shapes as they are (both
+                # members hash), down to the program cache
+                shapes = tuple((a.shape, a.dtype) for a in bargs)
                 key = (spec, static, shapes, donate)
                 if key not in groups:
                     groups[key] = []
@@ -543,7 +585,8 @@ class JaxDevice(Device):
                 while len(g) >= 2 and spec.batchable:
                     b = bucket_size(len(g), self.batch_max)
                     chunk, g = g[:b], g[b:]
-                    self._dispatch_batch(es, spec, static, donate, chunk)
+                    self._dispatch_batch(es, spec, static, shapes, donate,
+                                         chunk)
                     n += b
                 while g:   # singleton / post-downgrade remainder
                     task, est, inputs, _ = g.pop(0)
@@ -560,7 +603,7 @@ class JaxDevice(Device):
                 raise
         return n
 
-    def _dispatch_batch(self, es, spec, static, donate,
+    def _dispatch_batch(self, es, spec, static, shapes, donate,
                         chunk: List[Tuple]) -> None:
         """Dispatch one flush group: as ONE stacked call on a single
         rank, and across ranks (segmented flush, ISSUE 7) as up to
@@ -583,7 +626,8 @@ class JaxDevice(Device):
             from .batching import segment_plan
             segs = segment_plan(n, self.flush_segments)
         if segs <= 1:
-            return self._dispatch_stacked(es, spec, static, donate, chunk)
+            return self._dispatch_stacked(es, spec, static, shapes, donate,
+                                          chunk)
         self.stats["segmented_flushes"] += 1
         size = n // segs
         for i in range(0, n, size):
@@ -595,20 +639,21 @@ class JaxDevice(Device):
                     self._submit_prepared(es, task, est, inputs)
                 return
             self.stats["flush_segments"] += 1
-            self._dispatch_stacked(es, spec, static, donate,
+            self._dispatch_stacked(es, spec, static, shapes, donate,
                                    chunk[i:i + size])
 
-    def _dispatch_stacked(self, es, spec, static, donate,
+    def _dispatch_stacked(self, es, spec, static, shapes, donate,
                           chunk: List[Tuple]) -> None:
-        """ONE stacked jitted call for ``chunk``; the lowered callable is
-        AOT-cached on the spec per (bucket, static, shapes, donate) so
-        steady-state submission is a cache hit.  Any trace/dispatch
-        failure (untraceable body, backend quirk) permanently downgrades
-        the spec to per-task dispatch — semantics are never at risk."""
+        """ONE stacked jitted call for ``chunk``, filed as ONE record;
+        the lowered callable is AOT-cached on the spec per (bucket,
+        static, shapes, donate) so steady-state submission is a cache
+        hit (``shapes`` is the group key's, not derived again).  Any
+        trace/dispatch failure (untraceable body, backend quirk)
+        permanently downgrades the spec to per-task dispatch —
+        semantics are never at risk."""
         from .batching import cached_stacked_callable, downgrade
         n = len(chunk)
-        nargs = len(chunk[0][3])
-        shapes = tuple((tuple(a.shape), str(a.dtype)) for a in chunk[0][3])
+        nargs = len(shapes)
         flat = [entry[3][j] for j in range(nargs) for entry in chunk]
         if any(donate) and len({id(x) for x in flat}) != len(flat):
             # the same buffer appears at two argument slots (a task
@@ -671,14 +716,21 @@ class JaxDevice(Device):
         self._note_profile(es, chunk[0][0].task_class.name, dt / 1e3 / n, n)
         if any(donate):
             self.stats["donated"] += sum(donate) * n
-        n_out = len(outs) // n if n else 0
-        for i, (task, est, inputs, _) in enumerate(chunk):
-            outputs = [outs[k * n + i] for k in range(n_out)]
-            out_flows = self._out_flows(task)
-            assert len(outputs) == len(out_flows), (
-                f"{task.task_class.name} batched body returned "
-                f"{len(outputs)} arrays for {len(out_flows)} written flows")
-            self._finish_submit(es, task, est, outputs, out_flows)
+        self._finish_submit(es, self._record(chunk, outs, "batched"))
+
+    def _record(self, chunk: List[Tuple], outs: Sequence[Any], how: str,
+                waits: Optional[List[Sequence[Any]]] = None) -> _InFlight:
+        """The record of one call of ``chunk``'s tasks that returned
+        ``outs`` (flat, grouped by output slot)."""
+        tasks = [entry[0] for entry in chunk]
+        out_flows = [self._out_flows(task) for task in tasks]
+        n_out = len(outs) // len(tasks)
+        for task, flows in zip(tasks, out_flows):
+            assert len(flows) == n_out, (
+                f"{task.task_class.name} {how} body returned {n_out} "
+                f"arrays for {len(flows)} written flows")
+        return _InFlight(tasks, outs, out_flows,
+                         sum(entry[1] for entry in chunk), waits)
 
     # ------------------------------------------------------------------ #
     # async stage-in prefetch: overlap the NEXT batch's H2D with the     #
@@ -877,13 +929,14 @@ class JaxDevice(Device):
         self._lru_touch(copy, owned=True)
 
     def drain(self, context=None) -> None:
-        """Retire every remaining window entry (called at wait()-exit:
-        the DAGs are complete, and the records would otherwise pin the
-        final tasks' object graphs — taskpool, collections, copies —
-        until some future taskpool's progress happens to run). Async
-        kernel failures in these trailing entries are RECORDED on the
-        context so the caller's raise_pending_error surfaces them
-        instead of a silently-successful wait().
+        """Retire every remaining window entry, call by call (called
+        at wait()-exit: the DAGs are complete, and the records would
+        otherwise pin their tasks' object graphs — taskpool,
+        collections, copies — until some future taskpool's progress
+        happens to run). Async kernel failures in these trailing calls
+        are RECORDED on the context, once per call, so the caller's
+        raise_pending_error surfaces them instead of a
+        silently-successful wait().
 
         Undispatched ``pending`` entries are DISCARDED: they can only
         exist here when the DAG aborted mid-accumulation (batched
@@ -906,34 +959,36 @@ class JaxDevice(Device):
             for rec in self._window:
                 self._retire(rec, context=context)
             self._window = []
+            self._window_tasks = 0
             self._prefetched.clear()
         finally:
             self._manager_lock.release()
 
     def _retire(self, rec: _InFlight, es=None, context=None) -> None:
-        """Release a window entry: drop its load contribution and surface
-        any async kernel error — against the task that DISPATCHED it
-        (es or context present: recorded as a task error; teardown:
+        """Release one call's window entry: drop its load contribution
+        (the sum over its tasks), wait for it ONCE (an executable's
+        outputs become ready together) and surface any async kernel
+        error once — against a task of the call that DISPATCHED it (es
+        or context present: recorded as a task error; teardown:
         logged)."""
         clock = self._phases
         if clock is not None:   # may wait for the kernel: backpressure
-            clock.push("epilog", cls=rec.task.task_class.name)
+            clock.push("epilog", cls=rec.tasks[0].task_class.name,
+                       n=len(rec.tasks))
         self.load_sub(rec.est)
+        self.stats["retired_calls"] += 1
         try:
-            for a in rec.outputs:
-                if a is None or not hasattr(a, "block_until_ready"):
-                    continue
-                if getattr(a, "is_deleted", lambda: False)():
-                    continue  # donated to a successor batched call
+            for a in rec.live():
                 a.block_until_ready()
         except Exception as exc:
             ctx = context if context is not None else \
                 (es.context if es is not None else None)
             if ctx is not None:
-                ctx.record_task_error(exc, rec.task)
+                ctx.record_task_error(exc, rec.tasks[0])
             else:
-                plog.warning("async kernel of %s failed at drain: %s",
-                             rec.task.snprintf(), exc)
+                plog.warning("async kernel of %s (a call of %d) failed "
+                             "at drain: %s", rec.tasks[0].snprintf(),
+                             len(rec.tasks), exc)
         obs = self._obs
         if obs is not None and obs.tracker is not None and es is not None:
             # the device-busy interval for the live overlap gauge:
@@ -948,44 +1003,69 @@ class JaxDevice(Device):
             clock.pop("epilog")
 
     def _epilog(self, es, rec: _InFlight) -> None:
-        """ref: parsec_cuda_kernel_epilog (device_cuda_module.c:2365-2430)."""
-        from ..runtime.scheduling import complete_execution
-        task = rec.task
+        """The epilog of one call (ref: parsec_cuda_kernel_epilog,
+        device_cuda_module.c:2365-2430): ONE pass over its tasks
+        installs the written copies and releases the readers, one
+        ``_account`` takes the summed delta; then the tasks complete in
+        dispatch order and what they made ready is handed to the
+        scheduler once."""
+        from ..runtime.scheduling import complete_executions
+        tasks = rec.tasks
+        n = len(tasks)
         clock = self._phases
         if clock is not None:
-            clock.push("epilog", cls=task.task_class.name)
+            clock.push("epilog", cls=tasks[0].task_class.name, n=n)
         if not self.eager_complete:
-            # non-eager: the poll loop just observed every output ready —
+            # non-eager: the poll loop just observed the call ready —
             # note the device-busy interval (eager mode notes at window
             # retire instead, where readiness is actually observed)
             obs = self._obs
             if obs is not None and obs.tracker is not None:
                 obs.tracker.note("compute", rec.t0,
                                  rec.done_est or time.monotonic_ns())
-        for arr, fidx in zip(rec.outputs, rec.out_flows):
-            ref = task.data[fidx]
-            data = ref.data_in.data if ref.data_in is not None else None
-            if data is not None:
-                copy = data.get_copy(self.device_index)
-                old = getattr(copy.payload, "nbytes", 0)
-                copy.payload = arr
-                self._account(getattr(arr, "nbytes", 0) - old)
-                data.version_bump(self.device_index)
-                ref.data_out = copy
-            else:
-                ref.data_in.payload = arr
-                ref.data_in.version += 1
-        for flow in task.task_class.flows:
-            if task.access_of(flow) == FlowAccess.READ and not flow.ctl:
-                ref = task.data[flow.flow_index]
-                if ref.data_in is not None and ref.data_in.data is not None:
-                    ref.data_in.data.release_reader(self.device_index)
+        outs = rec.outs
+        index = self.device_index
+        # the outputs of one slot share shape and dtype across the
+        # call's tasks, and mostly replace a payload of the same:
+        # nbytes (a Python property on a jax array) is read once per
+        # slot, and of a replaced payload only when it differs
+        slots = [(getattr(a, "shape", None), getattr(a, "dtype", None),
+                  getattr(a, "nbytes", 0)) for a in outs[::n]]
+        delta = 0
+        for i, task in enumerate(tasks):
+            for k, fidx in enumerate(rec.out_flows[i]):
+                ref = task.data[fidx]
+                data = ref.data_in.data if ref.data_in is not None else None
+                if data is not None:
+                    copy = data.get_copy(index)
+                    old = copy.payload
+                    copy.payload = outs[k * n + i]
+                    shape, dtype, nbytes = slots[k]
+                    if getattr(old, "shape", None) != shape \
+                            or getattr(old, "dtype", None) != dtype:
+                        delta += nbytes - getattr(old, "nbytes", 0)
+                    data.version_bump(index)
+                    ref.data_out = copy
+                else:
+                    ref.data_in.payload = outs[k * n + i]
+                    ref.data_in.version += 1
+            for flow in task.task_class.flows:
+                if task.access_of(flow) == FlowAccess.READ and not flow.ctl:
+                    ref = task.data[flow.flow_index]
+                    if ref.data_in is not None \
+                            and ref.data_in.data is not None:
+                        ref.data_in.data.release_reader(index)
+        if delta:
+            self._account(delta)
         if not self.eager_complete:
-            self.load_sub(rec.est)  # eager mode releases at window exit
-        self.executed_tasks += 1
+            # eager mode releases at window exit; here the epilog is
+            # the record's retirement
+            self.load_sub(rec.est)
+            self.stats["retired_calls"] += 1
+        self.executed_tasks += n
         if clock is not None:
             clock.pop("epilog")
-        complete_execution(es, task)
+        complete_executions(es, tasks)
 
     # ------------------------------------------------------------------ #
     # memory management: accounting arena + LRU eviction                 #
@@ -1107,6 +1187,7 @@ class JaxDevice(Device):
         for rec in self._window:
             self._retire(rec)  # teardown: must finalize every device
         self._window.clear()
+        self._window_tasks = 0
         self._prefetched.clear()
 
 
@@ -1279,14 +1360,15 @@ class JaxMeshDevice(JaxDevice):
     # ------------------------------------------------------------------ #
     # sharded batched dispatch                                           #
     # ------------------------------------------------------------------ #
-    def _dispatch_batch(self, es, spec, static, donate,
+    def _dispatch_batch(self, es, spec, static, shapes, donate,
                         chunk: List[Tuple]) -> None:
         n = len(chunk)
         k = len(self.chips)
         if spec.mesh_ok and spec.batchable and k > 1 and n >= k \
                 and n % k == 0:
             try:
-                return self._dispatch_sharded(es, spec, static, chunk)
+                return self._dispatch_sharded(es, spec, static, shapes,
+                                              chunk)
             except _MeshDispatchFailed as exc:
                 self.stats["mesh_downgrades"] += 1
                 spec.mesh_ok = False
@@ -1299,9 +1381,9 @@ class JaxMeshDevice(JaxDevice):
         target = self._stage_target(chunk[0][0])
         chunk = [(t, e, inp, tuple(self._move(a, target) for a in ba))
                  for (t, e, inp, ba) in chunk]
-        super()._dispatch_batch(es, spec, static, donate, chunk)
+        super()._dispatch_batch(es, spec, static, shapes, donate, chunk)
 
-    def _dispatch_sharded(self, es, spec, static,
+    def _dispatch_sharded(self, es, spec, static, shapes,
                           chunk: List[Tuple]) -> None:
         """ONE shard_map-compiled jitted call for ``chunk``, spread
         across the mesh: slot-blocks of n/k tasks per chip, tasks
@@ -1313,9 +1395,7 @@ class JaxMeshDevice(JaxDevice):
         from .batching import cached_sharded_callable
         n, k = len(chunk), len(self.chips)
         per = n // k
-        nargs = len(chunk[0][3])
-        shapes = tuple((tuple(a.shape), str(a.dtype))
-                       for a in chunk[0][3])
+        nargs = len(shapes)
         # phase 1 — fallible: trace/assemble/dispatch. Nothing has been
         # submitted yet, so a failure here retries on the fallback path.
         clock, span = self._phases, None
@@ -1383,16 +1463,12 @@ class JaxMeshDevice(JaxDevice):
             self._unbind_kerns[(per, n_out)] = unbind
         rows_of = [unbind(*[shards[o][c].data for o in range(n_out)])
                    for c in range(k)]   # rows_of[c][o*per + r]
-        for s in range(n):
-            task, est, inputs, _ba = chunk[order[s]]
-            c, r = divmod(s, per)
-            outputs = [rows_of[c][o * per + r] for o in range(n_out)]
-            out_flows = self._out_flows(task)
-            assert len(outputs) == len(out_flows), (
-                f"{task.task_class.name} mesh-batched body returned "
-                f"{len(outputs)} arrays for {len(out_flows)} written "
-                f"flows")
-            self._finish_submit(es, task, est, outputs, out_flows)
+        # ONE record for the call, its tasks in slot order; each chip's
+        # rows come from that chip's unbind call and are ready together
+        outs = [rows_of[c][o * per + r] for o in range(n_out)
+                for c in range(k) for r in range(per)]
+        self._finish_submit(es, self._record(
+            [chunk[i] for i in order], outs, "mesh-batched", rows_of))
 
     def drain(self, context=None) -> None:
         super().drain(context)

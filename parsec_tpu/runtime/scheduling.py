@@ -165,8 +165,10 @@ def execute(es: ExecutionStream, task: Task) -> HookReturn:
         PINS(es, PinsEvent.EXEC_END, task)
 
 
-def complete_execution(es: ExecutionStream, task: Task) -> None:
-    """ref: __parsec_complete_execution (scheduling.c:439-468)."""
+def _complete(es: ExecutionStream, task: Task) -> List[Task]:
+    """Everything of a task's completion up to the hand-over: opens its
+    COMPLETE_EXEC pair (the caller closes it) and returns the tasks its
+    ``release_deps`` made ready."""
     tc = task.task_class
     task.status = TaskStatus.COMPLETE
     PINS(es, PinsEvent.COMPLETE_EXEC_BEGIN, task)
@@ -187,9 +189,30 @@ def complete_execution(es: ExecutionStream, task: Task) -> None:
     if tc.release_task is not None:
         tc.release_task(es, task)
     tp.task_completed()
+    return ready
+
+
+def complete_execution(es: ExecutionStream, task: Task) -> None:
+    """ref: __parsec_complete_execution (scheduling.c:439-468)."""
+    ready = _complete(es, task)
     if ready:
         schedule_keep_best(es, list(ready))
     PINS(es, PinsEvent.COMPLETE_EXEC_END, task)
+
+
+def complete_executions(es: ExecutionStream, tasks: List[Task]) -> None:
+    """The batch form, for the tasks of ONE device call: each completes
+    as in :func:`complete_execution`, in order, with its own PINS
+    events, but what they made ready is gathered and handed over by
+    one ``schedule_keep_best``: one priority stamp, one pass through
+    the scheduler's lock, one wake-up of the parked workers."""
+    gathered: List[Task] = []
+    for task in tasks:
+        ready = _complete(es, task)
+        if ready:
+            gathered.extend(ready)
+        PINS(es, PinsEvent.COMPLETE_EXEC_END, task)
+    schedule_keep_best(es, gathered)
 
 
 def task_progress(es: ExecutionStream, task: Task, distance: int = 0) -> None:

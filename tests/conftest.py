@@ -120,9 +120,9 @@ def call_sizes(monkeypatch):
     sizes = []
     stacked = JaxDevice._dispatch_stacked
 
-    def recording(self, es, spec, static, donate, chunk):
+    def recording(self, es, spec, static, shapes, donate, chunk):
         sizes.append(len(chunk))
-        return stacked(self, es, spec, static, donate, chunk)
+        return stacked(self, es, spec, static, shapes, donate, chunk)
 
     monkeypatch.setattr(JaxDevice, "_dispatch_stacked", recording)
     return sizes

@@ -9,6 +9,7 @@ import pytest
 import parsec_tpu
 from parsec_tpu import dtd
 from parsec_tpu.dsl.dtd import INOUT, INPUT, VALUE, unpack_args
+from parsec_tpu.profiling.pins import PinsEvent, PinsModule
 
 
 @pytest.fixture
@@ -338,30 +339,59 @@ class _StubTask:
         return "STUB(0)"
 
 
-def test_drain_records_async_error_on_context(jctx):
+def _file_call(dev, n, make_array):
+    """File, by hand, the window record of one device call of ``n``
+    tasks whose outputs are ``make_array()`` each."""
     from parsec_tpu.devices.tpu import _InFlight
-    dev = _jax_devices(jctx)[0]
-    rec = _InFlight(_StubTask(), [_FailingArray()], [0], 1.0)
+    tasks = [_StubTask() for _ in range(n)]
+    rec = _InFlight(tasks, [make_array() for _ in range(n)],
+                    [[0]] * n, float(n))
     dev._window.append(rec)
+    dev._window_tasks += n
+    return rec
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_drain_records_async_error_on_context(jctx, n):
+    """A failing call of n tasks is waited for ONCE and leaves ONE task
+    error, against a task of the call."""
+    dev = _jax_devices(jctx)[0]
+    _file_call(dev, n, _FailingArray)
+    retired0 = dev.stats["retired_calls"]
     load0 = dev.device_load
     dev.drain(jctx)
-    assert dev._window == []
+    assert dev._window == [] and dev._window_tasks == 0
+    assert dev.stats["retired_calls"] == retired0 + 1
     assert dev.device_load <= load0   # load contribution dropped
-    assert jctx._task_errors, "drain swallowed the async kernel failure"
+    assert len(jctx._task_errors) == 1, \
+        "drain swallowed the async kernel failure, or counted it per task"
     with pytest.raises(RuntimeError, match="task body failed"):
         jctx.raise_pending_error()
     jctx._task_errors.clear()   # let fini() tear down cleanly
 
 
-def test_drain_without_context_logs_not_raises(jctx):
+@pytest.mark.parametrize("n", [1, 4])
+def test_drain_without_context_logs_not_raises(jctx, n):
     """Teardown drain (no context): the failure must be logged, never
     propagated out of fini/drain."""
-    from parsec_tpu.devices.tpu import _InFlight
     dev = _jax_devices(jctx)[0]
-    dev._window.append(_InFlight(_StubTask(), [_FailingArray()], [0], 1.0))
+    _file_call(dev, n, _FailingArray)
     dev.drain()   # must not raise
-    assert dev._window == []
+    assert dev._window == [] and dev._window_tasks == 0
     assert not jctx._task_errors
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_fini_retires_failing_call_quietly(n):
+    """``fini`` retires what the window still holds, call by call, and
+    a failing call is logged there, not raised."""
+    ctx = parsec_tpu.init(nb_cores=2, enable_tpu=True)
+    dev = _jax_devices(ctx)[0]
+    _file_call(dev, n, _FailingArray)
+    retired0 = dev.stats["retired_calls"]
+    ctx.fini()   # must not raise
+    assert dev._window == [] and dev._window_tasks == 0
+    assert dev.stats["retired_calls"] == retired0 + 1
 
 
 def test_drain_discards_aborted_pending(jctx):
@@ -376,23 +406,343 @@ def test_drain_discards_aborted_pending(jctx):
     assert not jctx._task_errors
 
 
-def test_window_poll_treats_donated_buffer_as_ready(jctx):
-    """A window entry whose output was donated to a successor batched
-    call (buffer deleted) must retire cleanly instead of erroring."""
-    from parsec_tpu.devices.tpu import _InFlight, _array_ready
+class _Donated:
+    def is_deleted(self):
+        return True
 
-    class _Donated:
-        def is_deleted(self):
-            return True
+    def is_ready(self):   # pragma: no cover - must not be reached
+        raise RuntimeError("polled a deleted buffer")
 
-        def is_ready(self):   # pragma: no cover - must not be reached
-            raise RuntimeError("polled a deleted buffer")
+    def block_until_ready(self):   # pragma: no cover - ditto
+        raise RuntimeError("blocked on a deleted buffer")
 
-        def block_until_ready(self):   # pragma: no cover - ditto
-            raise RuntimeError("blocked on a deleted buffer")
 
-    assert _array_ready(_Donated())
+@pytest.mark.parametrize("n", [1, 4])
+def test_window_poll_treats_donated_buffer_as_ready(jctx, n):
+    """A window entry whose outputs were donated to a successor batched
+    call (buffers deleted) must retire cleanly instead of erroring:
+    the successor's record covers them."""
     dev = _jax_devices(jctx)[0]
-    dev._window.append(_InFlight(_StubTask(), [_Donated()], [0], 1.0))
+    rec = _file_call(dev, n, _Donated)
+    assert rec.ready() and list(rec.live()) == []
     dev.drain(jctx)
     assert not jctx._task_errors
+
+
+def test_call_waits_on_first_output_not_donated_onward(jctx):
+    """One wait per call, on an output that still has a buffer: with
+    the first output donated, the second answers for the executable."""
+    waited = []
+
+    class _Live:
+        def is_deleted(self):
+            return False
+
+        def is_ready(self):
+            return True
+
+        def block_until_ready(self):
+            waited.append(self)
+
+    outs = [_Donated(), _Live(), _Live(), _Live()]
+    dev = _jax_devices(jctx)[0]
+    rec = _file_call(dev, 4, iter(outs).__next__)
+    assert rec.ready()
+    dev.drain(jctx)
+    assert waited == [outs[1]]
+    assert not jctx._task_errors
+
+
+# --------------------------------------------------------------------- #
+# one record per device call (ISSUE 29): a stacked call of n tasks is   #
+# filed, waited on, retired and completed once                          #
+# --------------------------------------------------------------------- #
+def _one_core_burst(ctx, burst, nb=16, chain=False):
+    """``burst`` independent GEMM tasks of one class with a device
+    chore, inserted before the single worker (the caller, in wait())
+    runs any: they accumulate in the device's queue and flush in
+    groups of device_batch_max.  With ``chain`` each is followed by a
+    host task NEXT on its tile.  Returns (taskpool, c tiles); see
+    ``_assert_burst_result``."""
+    import jax
+    import jax.numpy as jnp
+    tp = dtd.taskpool_new()
+    ctx.add_taskpool(tp)
+
+    def host(es, task):
+        c, a, b = unpack_args(task)
+        c -= a @ b.T
+
+    def bump(es, task):
+        (c,) = unpack_args(task)
+        c += 1.0
+
+    tc = tp.create_task_class("GEMM", 3, host)
+    tp.add_chore(tc, "tpu", jax.jit(
+        lambda c, a, b: c - jnp.dot(a, b.T,
+                                    preferred_element_type=jnp.float32)))
+    nxt = tp.create_task_class("NEXT", 1, bump)
+    rng = np.random.RandomState(5)
+    tiles = [[tp.tile_of_array(rng.rand(nb, nb).astype(np.float32))
+              for _ in range(3)] for _ in range(burst)]
+    for c, a, b in tiles:
+        tp.insert_task_with_task_class(tc, (c, INOUT), (a, INPUT),
+                                       (b, INPUT))
+    if chain:
+        for c, _a, _b in tiles:
+            tp.insert_task_with_task_class(nxt, (c, INOUT))
+    return tp, [c for c, _a, _b in tiles]
+
+
+def _assert_burst_result(cs, nb=16, chain=False):
+    """Every c tile of ``_one_core_burst`` holds ITS task's result."""
+    rng = np.random.RandomState(5)
+    for c in cs:
+        c0, a, b = (rng.rand(nb, nb).astype(np.float32) for _ in range(3))
+        got = np.asarray(c.data.sync_to_host().payload)
+        np.testing.assert_allclose(got, c0 - a @ b.T + (1.0 if chain else 0.0),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_stacked_call_retires_as_one_record():
+    """A stacked call of 8 tasks: ONE record retired, one ``epilog``
+    span from ``_epilog`` and one from ``_retire``, and
+    every task still has its own ``complete`` span."""
+    from parsec_tpu.obs import phases
+    from parsec_tpu.utils.params import params
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", "8"):
+        ctx = parsec_tpu.init(nb_cores=1, profile=True)
+    try:
+        dev = _jax_devices(ctx)[0]
+        with phases.root_span(ctx, "burst", 29):
+            tp, cs = _one_core_burst(ctx, 8)
+            tp.wait()
+            ctx.wait()      # its exit drains the window
+        rec = phases.completed()[-1]
+        assert rec["op"] == "burst" and rec["id"] == 29
+        assert dev.stats["tasks"] == 8
+        assert dev.stats["batches"] == 1 and dev.stats["batched_tasks"] == 8
+        assert dev.stats["retired_calls"] == 1
+        assert rec["phases"]["epilog"]["count"] == 2
+        assert rec["phases"]["complete"]["count"] == 8
+        assert rec["phases"]["release_deps"]["count"] == 8
+        assert dev._window == [] and dev._window_tasks == 0
+        _assert_burst_result(cs)
+    finally:
+        ctx.fini()
+
+
+@pytest.mark.parametrize("batch_max,want_calls", [(4, 4), (16, 1)])
+def test_window_bounds_tasks_in_flight(monkeypatch, batch_max, want_calls):
+    """``tpu_eager_window`` bounds TASKS in flight with calls as the
+    entries: at window 4 with calls of 4 the oldest call is waited for
+    as the next is filed (never more than two calls' tasks in flight,
+    one call's once filed), and a single call larger than the window
+    waits for nothing: the window is never emptied under it."""
+    from parsec_tpu.devices.tpu import JaxDevice, _InFlight
+    from parsec_tpu.utils.params import params
+    # only backpressure (and the drain at wait()'s exit) retires
+    monkeypatch.setattr(_InFlight, "ready", lambda self: False)
+    after_filing, at_retire = [], []
+    filed, retire = JaxDevice._finish_submit, JaxDevice._retire
+
+    def filing(self, es, rec):
+        filed(self, es, rec)
+        after_filing.append((self._window_tasks, len(self._window)))
+
+    def retiring(self, rec, es=None, context=None):
+        # by backpressure (es given) the record has left the count
+        at_retire.append((self._window_tasks + len(rec.tasks)
+                          if es is not None else self._window_tasks,
+                          es is not None))
+        retire(self, rec, es, context)
+
+    monkeypatch.setattr(JaxDevice, "_finish_submit", filing)
+    monkeypatch.setattr(JaxDevice, "_retire", retiring)
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", str(batch_max)), \
+         params.cmdline_override("tpu_eager_window", "4"):
+        ctx = parsec_tpu.init(nb_cores=1)
+    try:
+        dev = _jax_devices(ctx)[0]
+        tp, cs = _one_core_burst(ctx, 16)
+        tp.wait()
+        ctx.wait()      # its exit drains the window
+        assert dev.stats["tasks"] == 16
+        assert dev.stats["batches"] == want_calls
+        assert dev.stats["retired_calls"] == want_calls
+        assert dev._window == [] and dev._window_tasks == 0
+        if batch_max == 4:
+            assert after_filing == [(4, 1)] * 4
+            # three retired by backpressure, the last by the drain
+            assert at_retire == [(8, True)] * 3 + [(4, False)]
+        else:
+            assert after_filing == [(16, 1)]
+            assert at_retire == [(16, False)]
+    finally:
+        ctx.fini()
+
+
+def test_stacked_call_async_failure_is_one_task_error(monkeypatch):
+    """A stacked call whose executable fails after the dispatch: ONE
+    task error, against a task of the call, and the DAG aborts."""
+    from parsec_tpu.devices.tpu import JaxDevice
+    from parsec_tpu.utils.params import params
+    record = JaxDevice._record
+    calls = []
+
+    def failing(self, chunk, outs, how, waits=None):
+        rec = record(self, chunk, outs, how, waits)
+        rec.waits = [[_FailingArray() for _ in rec.tasks]]
+        calls.append(rec)
+        return rec
+
+    monkeypatch.setattr(JaxDevice, "_record", failing)
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", "8"):
+        ctx = parsec_tpu.init(nb_cores=1)
+    try:
+        tp, cs = _one_core_burst(ctx, 8)
+        with pytest.raises(RuntimeError, match="task body failed") as err:
+            tp.wait()
+            ctx.wait()
+        assert len(calls) == 1 and len(calls[0].tasks) == 8
+        assert len(ctx._task_errors) == 1
+        assert "injected async kernel failure" in str(err.value.__cause__)
+        assert _jax_devices(ctx)[0].stats["retired_calls"] == 1
+        ctx._task_errors.clear()
+    finally:
+        ctx.fini()
+
+
+@pytest.mark.parametrize("batch_max", [1, 8])
+def test_non_eager_mode_takes_the_same_record(batch_max):
+    """``tpu_eager_complete`` off: the call's record waits in
+    ``_inflight`` until its outputs are ready and its epilog is its
+    retirement; lone tasks (batch_max 1) and a stacked call alike."""
+    from parsec_tpu.utils.params import params
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", str(batch_max)), \
+         params.cmdline_override("tpu_eager_complete", "0"):
+        ctx = parsec_tpu.init(nb_cores=1)
+    try:
+        dev = _jax_devices(ctx)[0]
+        assert not dev.eager_complete
+        tp, cs = _one_core_burst(ctx, 8, chain=True)
+        tp.wait()
+        ctx.wait()
+        assert dev.stats["tasks"] == 8
+        assert dev.stats["batches"] == (1 if batch_max == 8 else 0)
+        assert dev.stats["retired_calls"] == (1 if batch_max == 8 else 8)
+        assert dev._inflight == [] and dev._window == []
+        assert dev.device_load == 0.0
+        _assert_burst_result(cs, chain=True)
+    finally:
+        ctx.fini()
+
+
+class _Recorder(PinsModule):
+    """The PINS ``events`` in order, from every thread, as (event,
+    payload) entries of ``log``."""
+
+    name = "test_recorder"
+
+    def __init__(self, events, log=None):
+        self.events = list(events)
+        self.log = [] if log is None else log
+
+    def callback(self, es, event, payload):
+        self.log.append((event, payload))
+
+
+def test_ready_tasks_of_one_call_reach_the_scheduler_once():
+    """The successors of a call's 8 tasks are handed over by ONE
+    ``schedule_keep_best``: one SCHEDULE_BEGIN event (the best of them
+    may stay on the releasing thread), after the last of the call's
+    COMPLETE_EXEC_END events."""
+    from parsec_tpu.utils.params import params
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", "8"):
+        ctx = parsec_tpu.init(nb_cores=1)
+    mod = _Recorder([PinsEvent.SCHEDULE_BEGIN, PinsEvent.COMPLETE_EXEC_END])
+    try:
+        tp, cs = _one_core_burst(ctx, 8, chain=True)
+        mod.enable()
+        tp.wait()
+        _assert_burst_result(cs, chain=True)
+    finally:
+        mod.disable()
+        ctx.fini()
+    name = lambda t: t.task_class.name   # noqa: E731
+    handovers = [(i, p) for i, (ev, p) in enumerate(mod.log)
+                 if ev == PinsEvent.SCHEDULE_BEGIN
+                 and any(name(t) == "NEXT" for t in p)]
+    assert len(handovers) == 1, [len(p) for _i, p in handovers]
+    at, tasks = handovers[0]
+    assert all(name(t) == "NEXT" for t in tasks) and len(tasks) in (7, 8)
+    done = [i for i, (ev, p) in enumerate(mod.log)
+            if ev == PinsEvent.COMPLETE_EXEC_END and name(p) == "GEMM"]
+    assert len(done) == 8 and max(done) < at
+
+
+def test_two_ranks_each_segment_is_its_own_record(monkeypatch):
+    """Across ranks a flush group of 16 goes out as four sub-calls, each
+    its own record, and the first segment's successors reach the
+    scheduler before the last segment is retired (its sends can start
+    while later segments still run)."""
+    from conftest import spmd
+    from parsec_tpu.comm import RemoteDepEngine
+    from parsec_tpu.devices.tpu import JaxDevice
+    from parsec_tpu.utils.params import params
+    log = []
+    filed, retire = JaxDevice._finish_submit, JaxDevice._retire
+
+    def filing(self, es, rec):
+        log.append(("file", [id(t) for t in rec.tasks]))
+        filed(self, es, rec)
+
+    def retiring(self, rec, es=None, context=None):
+        log.append(("retire", [id(t) for t in rec.tasks]))
+        retire(self, rec, es, context)
+
+    monkeypatch.setattr(JaxDevice, "_finish_submit", filing)
+    monkeypatch.setattr(JaxDevice, "_retire", retiring)
+    mod = _Recorder([PinsEvent.SCHEDULE_BEGIN], log)
+
+    def rank_fn(rank, fabric):
+        ctx = parsec_tpu.Context(nb_cores=1,
+                                 comm=RemoteDepEngine(fabric.engine(rank)))
+        try:
+            tp, cs = _one_core_burst(ctx, 16, chain=True)
+            tp.wait()
+            ctx.wait()      # its exit drains the window
+            devs = _jax_devices(ctx)
+            return {k: sum(d.stats[k] for d in devs)
+                    for k in ("tasks", "batches", "flush_segments",
+                              "segmented_flushes", "retired_calls")}
+        finally:
+            ctx.fini()
+
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("device_batch_max", "16"), \
+         params.cmdline_override("device_flush_segments", "4"):
+        mod.enable()
+        try:
+            stats = spmd(2, rank_fn)[0]
+        finally:
+            mod.disable()
+    st = stats[0]     # keyless tiles live on rank 0: it ran them all
+    assert st["tasks"] == 16 and st["segmented_flushes"] == 1
+    assert st["flush_segments"] == 4 and st["batches"] == 4
+    assert st["retired_calls"] == 4
+    files = [e for e in log if e[0] == "file"]
+    assert [len(ids) for _k, ids in files] == [4, 4, 4, 4]
+    first_next = next(i for i, (what, p) in enumerate(log)
+                      if what == PinsEvent.SCHEDULE_BEGIN
+                      and any(t.task_class.name == "NEXT" for t in p))
+    last_retired = next(i for i, e in enumerate(log)
+                        if e == ("retire", files[-1][1]))
+    assert first_next < last_retired
+
+

@@ -26,10 +26,13 @@ COUNTERS = ("first_calls", "program_reuse", "batches", "batch_downgrades")
 
 @contextlib.contextmanager
 def _one_device():
-    """The one accelerator of a fresh context.  One worker, so a class's
-    ready tasks reach the manager together: the buckets, and with them
-    the counts below, are the same from run to run."""
-    with params.cmdline_override("device_tpu_max", "1"):
+    """The one accelerator of a fresh context.  One worker and the
+    DSL's static priorities (the dynamic ones are fed by measured
+    dispatch times, and a loaded host reorders the classes), so a
+    class's ready tasks reach the manager together: the buckets, and
+    with them the counts below, are the same from run to run."""
+    with params.cmdline_override("device_tpu_max", "1"), \
+         params.cmdline_override("sched_dynamic_priority", "0"):
         ctx = parsec_tpu.init(nb_cores=1)
     d, = [d for d in ctx.devices if d.device_type == "tpu"]
     d.ctx = ctx
@@ -90,8 +93,9 @@ def test_a_fresh_taskpool_reuses_every_program(dev, op, make):
 def test_a_fresh_taskpool_reuses_the_sharded_programs(no_programs):
     """``cached_sharded_callable`` picks its cache as the stacked path
     does: on a chip mesh the shard_map programs are shared too."""
-    with params.cmdline_override("device_mesh_shape", "2x2"):
-        ctx = parsec_tpu.init(nb_cores=1)
+    with params.cmdline_override("device_mesh_shape", "2x2"), \
+         params.cmdline_override("sched_dynamic_priority", "0"):
+        ctx = parsec_tpu.init(nb_cores=1)   # as in _one_device
     try:
         dev = ctx.device_by_type("tpu")
         dev.ctx = ctx
