@@ -1,7 +1,8 @@
 """Data collections & distributions (SURVEY.md §2.6)."""
 from .collection import DataCollection, DictCollection, LocalArrayCollection
-from .matrix import (SymTwoDimBlockCyclic, SymTwoDimBlockCyclicBand,
-                     TiledMatrix, TwoDimBlockCyclic, TwoDimBlockCyclicBand,
+from .matrix import (BlockColumnCyclic, SymTwoDimBlockCyclic,
+                     SymTwoDimBlockCyclicBand, TiledMatrix,
+                     TwoDimBlockCyclic, TwoDimBlockCyclicBand,
                      TwoDimTabular, VectorTwoDimCyclic)
 from .redistribute import redistribute, redistribute_ptg, reshard_array
 from .subtile import SubtileView
@@ -9,7 +10,8 @@ from . import ops
 
 __all__ = [
     "DataCollection", "DictCollection", "LocalArrayCollection", "TiledMatrix",
-    "TwoDimBlockCyclic", "SymTwoDimBlockCyclic", "TwoDimBlockCyclicBand",
+    "TwoDimBlockCyclic", "BlockColumnCyclic", "SymTwoDimBlockCyclic",
+    "TwoDimBlockCyclicBand",
     "SymTwoDimBlockCyclicBand",
     "TwoDimTabular", "VectorTwoDimCyclic", "redistribute", "redistribute_ptg", "reshard_array",
     "ops", "SubtileView",

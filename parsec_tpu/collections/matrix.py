@@ -170,6 +170,23 @@ class TwoDimBlockCyclic(TiledMatrix):
         return 0
 
 
+class BlockColumnCyclic(TwoDimBlockCyclic):
+    """Block columns on one rank: the distribution DPLASMA's
+    ``dgetrf_1d`` asks for (``mb = M``, tiles of ``M x nb``).  Tile
+    (0, n) is the whole block column n, so a task that owns a tile owns
+    every row of it and a pivot search or a row interchange stays
+    inside one task.
+
+    Takes :class:`TwoDimBlockCyclic`'s leading arguments so that code
+    which tiles by ``(lm, ln, mb, nb)`` can be handed either; the tile
+    height is ``lm`` whatever ``mb`` says.
+    """
+
+    def __init__(self, lm: int, ln: int, mb: int, nb: int,
+                 dtype=np.float32) -> None:
+        super().__init__(lm, ln, lm, nb, dtype=dtype)
+
+
 class SymTwoDimBlockCyclic(TwoDimBlockCyclic):
     """Triangular/symmetric storage block-cyclic
     (ref: sym_two_dim_rectangle_cyclic.c)."""

@@ -65,7 +65,7 @@ from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 __all__ = ["DeviceBatchSpec", "bucket_size", "segment_plan",
            "stacked_callable_key", "program_name", "KernelsNamedFor",
-           "kernel_named_for",
+           "kernel_named_for", "programs_held",
            "settle", "downgrade",
            "build_stacked_callable", "cached_stacked_callable",
            "build_sharded_callable", "cached_sharded_callable",
@@ -289,6 +289,28 @@ def _cached(spec: DeviceBatchSpec, key: Tuple,
             if fn is None:
                 fn = cache[key] = build()
     return fn
+
+
+def programs_held(classes: Optional[Set[str]] = None) -> int:
+    """Compiled programs the process holds for task classes, by the
+    names they dispatch under (:func:`program_name`): every signature
+    of every token-cached stacked program (``<CLASS>_x<n>``) and of
+    every kernel cloned for a lone task (``<CLASS>``).  ``classes``
+    restricts the count to those classes.  A level, not a counter: it
+    stands still once every shape has run, and a body whose program
+    depends on a task local (one signature per ``k``) shows as a count
+    that grows with the DAG."""
+    def mine(name: str) -> bool:
+        return classes is None or re.sub(r"_x\d+$", "", name) in classes
+
+    with _lock:
+        stacked = [fn for cache in _shared_cache.values()
+                   for fn in cache.values() if mine(fn.name)]
+    lone = [fn for (name, _), fn in list(_class_kernels.items())
+            if mine(name)]
+    return (sum(fn.fn._cache_size() for fn in stacked)
+            + sum(fn._cache_size() for fn in lone))
+
 
 #: process-wide stage-callable cache (stagec/, ISSUE 12), living
 #: alongside the bucket cache above: token -> key -> fused jitted

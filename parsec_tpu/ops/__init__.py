@@ -1,7 +1,8 @@
 """Tile kernels (XLA/Pallas executables for task BODYs) and tile
-algorithms (dpotrf, dgeqrf, dgetrf_nopiv, pdgemm)."""
+algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm)."""
 from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt,
-                     gemm_tn_sub, geqrt, geqrt_r, getrf_nopiv, potrf, scal,
+                     gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
+                     getrf_1d_panel, getrf_1d_update, getrf_nopiv, potrf, scal,
                      syrk_ln, transpose, trsm_lower, trsm_lower_trans,
                      trsm_lower_unit, trsm_panel, trsm_upper_right, tsmqr,
                      tsqrt, tsqrt_r, unmqr)
@@ -12,6 +13,7 @@ from .dgeqrf import dgeqrf, dgeqrf_factory, dgeqrf_taskpool
 from .inverse import dgesv, dgetrs, dlauum, dpotri, dtrtri
 from .dgetrf import (dgetrf, dgetrf_factory, dgetrf_nopiv, dgetrf_nopiv_taskpool,
                      make_diag_dominant)
+from .dgetrf_1d import dgetrf_1d, dgetrf_1d_factory, dgetrf_1d_taskpool
 from .pdgemm import pdgemm, pdgemm_factory, pdgemm_taskpool
 from .dtrsm import (dposv, dtrsm_lower_taskpool, dtrsm_lower_trans_taskpool)
 
@@ -22,10 +24,12 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "gemm_nn_sub", "gemm", "axpy", "scal", "transpose",
            "geqrt", "geqrt_r", "unmqr", "tsqrt", "tsqrt_r", "tsmqr",
            "getrf_nopiv", "trsm_lower_unit", "trsm_upper_right",
+           "getrf_1d_panel", "getrf_1d_update", "getrf_1d_laswp",
            "dpotrf", "dpotrf_factory", "dpotrf_taskpool", "make_spd",
            "dpotrf_dtd",
            "dgeqrf", "dgeqrf_factory", "dgeqrf_taskpool",
            "dgetrf", "dgetrf_nopiv", "dgetrf_nopiv_taskpool", "dgetrf_factory",
+           "dgetrf_1d", "dgetrf_1d_factory", "dgetrf_1d_taskpool",
            "dtrtri", "dlauum", "dpotri", "dgetrs", "dgesv",
            "make_diag_dominant",
            "pdgemm", "pdgemm_factory", "pdgemm_taskpool",

@@ -14,6 +14,10 @@ MXU-shaped.
 
 On return descA holds unit-lower L strictly below the diagonal and U on
 and above: A = L U (verify by reconstruction).
+
+LU WITH partial pivoting through the runtime is ``ops.dgetrf_1d``
+(ops/dgetrf_1d.py: block columns, a pivot flow); ``dgetrf`` below is the
+same factorization of a numpy array as one jitted program.
 """
 from __future__ import annotations
 
@@ -153,14 +157,17 @@ def dgetrf_nopiv(context, A: TiledMatrix, rank: int = 0,
 
 def dgetrf(A: np.ndarray, nb: int = 256):
     """Blocked LU with partial pivoting: ``A = P L U`` (general matrices,
-    no diagonal-dominance requirement — the DPLASMA dgetrf-parity op the
-    nopiv PTG variant cannot cover).
+    no diagonal-dominance requirement) on a numpy array, outside the
+    runtime: no context, scheduler or device module sees it.  The same
+    factorization as a task graph on the runtime's normal path is
+    ``ops.dgetrf_1d(ctx, A)`` (ops/dgetrf_1d.py, DPLASMA's
+    ``dgetrf_1d``).
 
-    TPU-native design: pivoting's data-dependent row swaps do not fit an
-    affine PTG, so this is a single jitted XLA program — LAPACK-grade
-    panel factorization via ``lax.linalg.lu`` (XLA's pivoted LU custom
-    call), triangular solves for the block row, and one large MXU GEMM
-    per trailing update; the panel loop is unrolled at trace time
+    This one is a single jitted XLA program — the panel factorization
+    via ``lax.linalg.lu`` (on the TPU XLA's pivoted LU custom call,
+    which the v5e's compiler refuses from 16384 rows on), triangular
+    solves for the block row, and one large MXU GEMM per trailing
+    update; the panel loop is unrolled at trace time
     (problem-size-static, like a captured taskpool).
 
     Returns ``(LU, piv)``: packed factors (unit-lower L strictly below

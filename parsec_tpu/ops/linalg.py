@@ -179,6 +179,145 @@ def trsm_upper_right(t: Any, c: Any) -> Any:
     return _solve_tri(t, c.T, lower=False, trans="T").T
 
 
+# --- LU with partial pivoting on block columns (ops/dgetrf_1d.py) ---------
+#
+# Every kernel below works on a WHOLE block column at one static shape,
+# (N, nb): which rows are active is an operand, never a Python int, so
+# one program serves every panel index.  The pivot tile that travels
+# between them is int32, (4, N):
+#   row 0: [first row of the NEXT panel, first row of THIS panel, 0...]
+#   row 1: g, the panel's interchanges as a gather: after[i] = before[g[i]]
+#   row 2: perm, every interchange so far: row i is original row perm[i]
+#   row 3: ipiv so far, LAPACK's (0-based): row i was swapped with ipiv[i]
+PIV_ROWS = 4
+
+
+#: columns of a panel factored together, one column step after another
+LU_STRIP = 32
+
+
+def _lu_strip(st: Any, d0: Any) -> Any:
+    """Partial pivoting on one strip of a panel, column after column.
+
+    ``st`` is the strip TRANSPOSED, (w, N): ``st[i]`` is column i over
+    all N rows, which lie along the array's minor (lane) dimension, so
+    the pivot search is a reduction over lanes and the rank-1 update
+    one elementwise pass.  Column i is active from row ``d0 + i`` down;
+    the rows above are never read or written.  Each step: the pivot is
+    the entry of largest magnitude among the active rows (the first on
+    a tie), its row is exchanged with row ``d0 + i`` in all w columns,
+    the multipliers replace the column under the diagonal and the
+    strip's later columns are updated.  Returns (the strip, the
+    interchanges as a gather over all N rows, the w pivot rows)."""
+    w, n = st.shape
+    lane = jnp.arange(n, dtype=jnp.int32)
+    col = jnp.arange(w, dtype=jnp.int32)[:, None]
+
+    def exchange(x, d, p, axis):
+        xd = jax.lax.dynamic_slice_in_dim(x, d, 1, axis)
+        xp = jax.lax.dynamic_slice_in_dim(x, p, 1, axis)
+        x = jax.lax.dynamic_update_slice_in_dim(x, xp, d, axis)
+        return jax.lax.dynamic_update_slice_in_dim(x, xd, p, axis), xd, xp
+
+    def step(i, carry):
+        st, g, piv = carry
+        d = d0 + i
+        row = jax.lax.dynamic_slice(st, (i, 0), (1, n))
+        p = jnp.argmax(jnp.where(lane >= d, jnp.abs(row[0]), -1)
+                       ).astype(jnp.int32)
+        st, under_d, under_p = exchange(st, d, p, 1)
+        g, _, _ = exchange(g, d, p, 0)
+        # column i after the exchange, without reading the strip again
+        row = jnp.where(lane == p, under_d[i], row)
+        mult = jnp.where(lane > d, row / under_p[i], 0)
+        st = jnp.where((col == i) & (lane > d), mult,
+                       st - jnp.where(col > i, under_p, 0) * mult)
+        return st, g, jax.lax.dynamic_update_slice(piv, p[None], (i,))
+
+    return jax.lax.fori_loop(0, w, step,
+                             (st, lane, jnp.zeros((w,), jnp.int32)))
+
+
+def _lu_panel(x: Any, r: Any) -> Any:
+    """LU with exact partial pivoting of rows r.. of the (N, nb) block
+    column ``x`` at its full static height, ``r`` an operand.
+    Right-looking over strips of :data:`LU_STRIP` columns: a strip is
+    factored over all the active rows (:func:`_lu_strip`), its
+    interchanges are applied to the panel's other columns in one
+    gather, the strip's block row is solved and the columns to its
+    right updated (the rows at or above the block row masked out of
+    the product).  ``lax.linalg.lu`` is not used: on the TPU it is
+    XLA's ``LuDecomposition``, which holds every row of a 128-column
+    strip twice in 16 MiB of scoped VMEM and is refused at compile time
+    from 16384 rows on.  Returns (the column, the interchanges as a
+    gather g: after[i] = before[g[i]], the nb pivot rows)."""
+    n, nb = x.shape
+    lane = jnp.arange(n, dtype=jnp.int32)
+    g = lane
+    piv = jnp.zeros((nb,), jnp.int32)
+    for c0 in range(0, nb, LU_STRIP):
+        c1 = min(c0 + LU_STRIP, nb)
+        d0 = r + c0
+        st, gs, pv = _lu_strip(x[:, c0:c1].T, d0)
+        x = jnp.take(x, gs, axis=0, unique_indices=True, mode="clip")
+        x = x.at[:, c0:c1].set(st.T)
+        g = g[gs]
+        piv = piv.at[c0:c1].set(pv)
+        if c1 < nb:
+            w = c1 - c0
+            u = trsm_lower_unit(
+                jax.lax.dynamic_slice(st, (0, d0), (w, w)).T,
+                jax.lax.dynamic_slice(x, (d0, c1), (w, nb - c1)))
+            x = jax.lax.dynamic_update_slice(x, u, (d0, c1))
+            below = jnp.where(lane >= d0 + w, st, 0).T
+            x = x.at[:, c1:].set(gemm_nn_sub(x[:, c1:], below, u))
+    return x, g, piv
+
+
+@jax.jit
+def getrf_1d_panel(a: Any, q: Any) -> Any:
+    """PANEL(k): LU with partial pivoting of the active rows of one
+    block column.  ``a`` is (N, nb); ``q`` is the previous panel's pivot
+    tile (for the first panel: zeros over the identity permutation),
+    whose q[0, 0] is this panel's first row r.  Returns (the column:
+    rows r.. factored, the rows above untouched; this panel's pivot
+    tile)."""
+    n, nb = a.shape
+    r = q[0, 0]
+    a, g, piv = _lu_panel(a, r)
+    meta = jnp.zeros((n,), jnp.int32).at[0].set(r + nb).at[1].set(r)
+    ipiv = jax.lax.dynamic_update_slice(q[3], piv, (r,))
+    return a, jnp.stack([meta, g, q[2][g], ipiv])
+
+
+@jax.jit
+def getrf_1d_update(l: Any, p: Any, c: Any) -> Any:
+    """One block column right of panel k: interchange its rows by the
+    panel's pivots (a gather by p[1]: which rows move is known only
+    now), solve the block row U_kn = L_kk^-1 C[r:r+nb] and update the
+    rows below it, C -= L_*k U_kn.  ``l`` is the factored panel column,
+    ``p`` its pivot tile (r = p[0, 1]); the rows of ``l`` at or above
+    the block row are masked out of the product, not sliced away."""
+    n = c.shape[0]
+    nb = l.shape[1]
+    r = p[0, 1]
+    c = jnp.take(c, p[1], axis=0, unique_indices=True, mode="clip")
+    u = trsm_lower_unit(jax.lax.dynamic_slice(l, (r, 0), (nb, nb)),
+                        jax.lax.dynamic_slice(c, (r, 0), (nb, c.shape[1])))
+    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
+    c = gemm_nn_sub(c, jnp.where(rows >= r + nb, l, 0), u)
+    return jax.lax.dynamic_update_slice(c, u, (r, 0))
+
+
+@jax.jit
+def getrf_1d_laswp(a: Any, p: Any, f: Any) -> Any:
+    """The interchanges of every LATER panel applied to a factored
+    block column on the left: its rows are in the order p[2] (as its
+    own panel left them) and go to the final order f[2]."""
+    return jnp.take(a, jnp.argsort(p[2])[f[2]], axis=0,
+                    unique_indices=True, mode="clip")
+
+
 @jax.jit
 def axpy(y: Any, x: Any, alpha: float = 1.0) -> Any:
     return y + alpha * x

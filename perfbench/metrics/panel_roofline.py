@@ -1,0 +1,10 @@
+"""Tile kernels: the least time the chip could take for the DAG's PANEL
+tasks (count x max(flops / peak, bytes / bandwidth) of
+``kernels/<operation>.PANEL.json``: useful work, the mean task of the
+DAG) over the device seconds of the class's programs per factorization
+(``panel_device_s``); ``class_roofline.py``."""
+from perfbench import class_roofline
+
+
+def read(obs):
+    return class_roofline.read(obs, "PANEL")

@@ -1,0 +1,10 @@
+"""Tile kernels: the least time the chip could take for the DAG's UPDATE
+tasks (count x max(flops / peak, bytes / bandwidth) of
+``kernels/<operation>.UPDATE.json``: useful work, the mean task of the
+DAG) over the device seconds of the class's programs per factorization
+(``update_device_s``); ``class_roofline.py``."""
+from perfbench import class_roofline
+
+
+def read(obs):
+    return class_roofline.read(obs, "UPDATE")

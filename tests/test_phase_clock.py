@@ -562,11 +562,18 @@ def test_benchmark_lists_every_new_reader():
         assert per_layer[name]["workloads"] == cells, name
         assert per_layer[name]["moves"] == "factor_s"
     qr = [c for c in cells if c.startswith("dgeqrf")]
+    cholesky = [c for c in cells if c.startswith("dpotrf")]
+    lu = [c for c in cells if c.startswith("dgetrf")]
+    assert qr + cholesky + lu and set(qr + cholesky + lu) == set(cells)
     for name in CLASS_METRICS:
         want = qr if name[:5] in ("geqrt", "unmqr", "tsqrt", "tsmqr") \
-            else [c for c in cells if c not in qr]
+            else cholesky
         assert per_layer[name]["workloads"] == want, name
         assert per_layer[name]["source"] == "device_trace"
+    for cls in ("panel", "update", "laswp"):
+        for name in (f"{cls}_device_s", f"{cls}_roofline"):
+            assert per_layer[name]["workloads"] == lu, name
+            assert per_layer[name]["source"] == "device_trace"
 
 
 # ---------------------------------------------------------------- #
