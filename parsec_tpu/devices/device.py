@@ -1,17 +1,21 @@
-"""Device module base + registry + load-balanced placement.
+"""Device module base + registry + owner-computes placement.
 
 Reference behavior: ``parsec_device_module_t`` {attach, taskpool_register,
 memory_register, data_advise, ...} with per-device capability weights and
-``parsec_get_best_device`` = min(load + ratio*weight) with a sticky-device
-skew toward where the data already lives
-(ref: parsec/mca/device/device.c:79-168, device.h:77-125).
+``parsec_get_best_device`` (ref: parsec/mca/device/device.c:79-168,
+device.h:77-125): a task runs on the accelerator that OWNS the data it
+writes; a written tile no accelerator owns yet goes where
+``data_advise(.., "preferred_device")`` said, else to the least loaded
+device (``device_load`` + the task's estimate).  So load decides only a
+tile's first touch, and every later update of the tile finds it where
+it is: what still crosses between chips is what a task only READS.
 """
 from __future__ import annotations
 
 import threading
-from typing import Any, List, Optional
+from typing import List, Optional
 
-from ..utils.params import params
+from ..data.data import FlowAccess
 
 
 class Device:
@@ -83,29 +87,52 @@ def get_best_device(task, devices: List[Device],
                     eligible_types: Optional[set] = None) -> Device:
     """ref: parsec_get_best_device (device.c:79-168).
 
-    Sticky skew: a device already holding a valid copy of one of the task's
-    written flows gets a ``device_load_balance_skew`` percent discount.
+    1. The first flow the task writes whose tile one of the eligible
+       devices owns decides: the task runs there.
+    2. Otherwise (first touch: the host owns every written tile; or
+       the task writes no tile of a collection): the first written
+       tile's ``preferred_device`` if it names an eligible device,
+       else the least ``device_load`` + estimate.
+
+    The chosen device's ``stats`` count which rule placed the task
+    (``placed_by_owner`` / ``placed_by_load``).  With one eligible
+    device there is nothing to decide and nothing is counted.
     """
-    skew = params.get("device_load_balance_skew") / 100.0
-    best, best_score = None, None
-    data_homes = set()
-    for ref in task.data:
-        din = ref.data_in
-        if din is not None and din.data is not None:
-            od = din.data.owner_device
-            if od >= 0:
-                data_homes.add(od)
-    for dev in devices:
-        if eligible_types is not None and dev.device_type not in eligible_types:
+    if eligible_types is not None:
+        devices = [d for d in devices if d.device_type in eligible_types]
+    assert devices, "no eligible device"
+    if len(devices) == 1:
+        return devices[0]
+    tc = task.task_class
+    by_index = {d.device_index: d for d in devices}
+    advised = None
+    for flow in tc.flows:
+        if flow.ctl or not task.access_of(flow) & FlowAccess.WRITE:
             continue
-        est = dev.time_estimate_default
-        tc = task.task_class
-        if tc.time_estimate is not None:
-            est = tc.time_estimate(task, dev)
-        score = dev.device_load + est
-        if dev.device_index in data_homes:
-            score *= (1.0 - skew)
-        if best_score is None or score < best_score:
-            best, best_score = dev, score
-    assert best is not None, "no eligible device"
-    return best
+        din = task.data[flow.flow_index].data_in
+        data = din.data if din is not None else None
+        if data is None:
+            continue
+        owner = by_index.get(data.owner_device)
+        if owner is not None:
+            return _placed(owner, "placed_by_owner")
+        if advised is None:
+            advised = by_index.get(data.preferred_device)
+    if advised is not None:
+        return _placed(advised, "placed_by_load")
+    estimate = tc.time_estimate
+
+    def score(dev: Device) -> float:
+        return dev.device_load + (dev.time_estimate_default
+                                  if estimate is None
+                                  else estimate(task, dev))
+    # a tie goes to the first device of the list
+    return _placed(min(devices, key=score), "placed_by_load")
+
+
+def _placed(dev: Device, rule: str) -> Device:
+    # workers place tasks concurrently; the count must add up to the
+    # tasks placed
+    with dev._load_lock:
+        dev.stats[rule] += 1
+    return dev

@@ -43,7 +43,8 @@ class TemplateDevice(Device):
         # load balancer prefers them for tasks that have a chore here
         self.time_estimate_default = 1.0
         self._executor = executor or (lambda fn, *args: fn(*args))
-        self.stats = {"tasks": 0}
+        self.stats = {"tasks": 0,
+                      "placed_by_owner": 0, "placed_by_load": 0}
 
     def kernel_scheduler(self, es, task) -> Any:
         """Entry point called by the chore hook (the
@@ -104,6 +105,6 @@ def template_chore_hook(device_type: str = "template",
             dev = device_selector(task, devs)
         else:
             from .device import get_best_device
-            dev = get_best_device(task, devs, eligible_types={device_type})
+            dev = get_best_device(task, devs)
         return dev.kernel_scheduler(es, task)
     return hook

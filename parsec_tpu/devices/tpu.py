@@ -204,7 +204,11 @@ class JaxDevice(Device):
                       "segmented_flushes": 0, "flush_segments": 0,
                       # call records retired: tasks / retired_calls is
                       # the tasks a record held
-                      "retired_calls": 0}
+                      "retired_calls": 0,
+                      # which rule of get_best_device sent a task here:
+                      # the device owned a tile the task writes, or
+                      # first touch (advice, else load)
+                      "placed_by_owner": 0, "placed_by_load": 0}
         # eager completion (async dispatch IS completion; XLA orders the
         # dataflow) with a bounded in-flight window
         self.eager_complete = bool(params.get("tpu_eager_complete"))

@@ -340,8 +340,6 @@ def register_core_params() -> None:
     params.reg_int("arena_max_cached", -1, "cap on arena cached buffers (-1 off)")
     params.reg_int("task_startup_iter", 64, "startup enumerator chunk iterations")
     params.reg_int("task_startup_chunk", 256, "startup enumerator chunk size")
-    params.reg_int("device_load_balance_skew", 20,
-                   "percent skew favoring the device already holding the data")
     params.reg_bool("runtime_keep_highest_priority_task", True,
                     "keep best ready task on releasing thread, bypass scheduler")
     params.reg_int("verbose", 0, "global debug verbosity")

@@ -356,8 +356,9 @@ def test_first_calls_move_once_per_process(no_programs, one_worker_ctx):
 
 
 def test_stage_in_peer_bytes_counts_chip_to_chip(ctx4):
-    """With several accelerator devices in one context tiles follow
-    tasks between them: those pulls are the peer part of stage-in."""
+    """With several accelerator devices in one context a tile a task
+    only reads is pulled to the chip that needs it, from the chip that
+    wrote it: those pulls are the peer part of stage-in."""
     devs = _accel(ctx4)
     assert len(devs) > 1
     n, nb = 256, 32
