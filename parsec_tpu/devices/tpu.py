@@ -208,7 +208,10 @@ class JaxDevice(Device):
                       # which rule of get_best_device sent a task here:
                       # the device owned a tile the task writes, or
                       # first touch (advice, else load)
-                      "placed_by_owner": 0, "placed_by_load": 0}
+                      "placed_by_owner": 0, "placed_by_load": 0,
+                      # parts of compound taskpools (runtime/compound.py)
+                      # whose first device call left from here
+                      "compound_parts": 0}
         # eager completion (async dispatch IS completion; XLA orders the
         # dataflow) with a bounded in-flight window
         self.eager_complete = bool(params.get("tpu_eager_complete"))
@@ -497,6 +500,11 @@ class JaxDevice(Device):
         once per call, never per task."""
         n = len(rec.tasks)
         self.stats["tasks"] += n
+        part = rec.tasks[0].taskpool._part
+        if part is not None and not part["first_call_ns"]:
+            # the first device call of a part of a compound taskpool
+            part["first_call_ns"] = time.monotonic_ns()
+            self.stats["compound_parts"] += 1
         if self.eager_complete:
             # TPU-native completion model: jax dispatch is async and XLA's
             # execution queue already orders consumers after producers, so

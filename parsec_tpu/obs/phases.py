@@ -21,6 +21,15 @@ profiler's own trace as a ``jax.profiler.TraceAnnotation`` named
 ``parsec:<phase>``, so the runtime's spans sit on the ``/host:CPU``
 lines on the same clock as the device's ``XLA Ops`` lines.
 
+A request that ran a compound taskpool (``runtime/compound.py``: an
+``ops.dpoinv`` call is three taskpools in one ``add_taskpool``) also
+says, per part, when it was enqueued, when its first device call left
+and when it completed (``parts``, ns on this clock), and
+``compound_gap_ns``: the time between one part's completion and the
+next part's first device call, summed over the boundaries -- no device
+has anything queued then.  Not a phase: it is wall time of the request,
+not self time of a thread.
+
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``); ``format_report(record)`` prints the table.
 """
@@ -116,6 +125,9 @@ class PhaseClock:
         self._new_lock = threading.Lock()
         self._t1 = float("inf")
         self._root_anno = None
+        #: the part records of the compound taskpool the request ran
+        self._parts: Optional[List[Dict[str, Any]]] = None
+        self._gap_ns = 0
         self.caller = threading.get_ident()
         if traced:
             import jax
@@ -214,6 +226,14 @@ class PhaseClock:
             if st.stream is not None:
                 st.stream.span("phase:" + name, start, t)
 
+    def note_compound(self, parts: List[Dict[str, Any]],
+                      gap_ns: int) -> None:
+        """The request ran a compound taskpool, now complete: keep its
+        part records and the gap between them (the last compound's, if
+        a request runs several)."""
+        self._parts = parts
+        self._gap_ns = gap_ns
+
     def close(self) -> Dict[str, Any]:
         """End the root span: cut every thread's open spans at now,
         freeze the books and return the record."""
@@ -248,10 +268,14 @@ class PhaseClock:
             total.setdefault("other", [0, 0, 0])[0] += root
         if self._root_anno is not None:
             self._root_anno.__exit__(None, None, None)
-        return {"op": self.op, "id": self.id, "t0_ns": self.t0,
-                "t1_ns": t1, "traced": self.traced,
-                "phases": {k: _entry(v) for k, v in total.items()},
-                "by_thread": by_thread, "caller_thread": caller}
+        record = {"op": self.op, "id": self.id, "t0_ns": self.t0,
+                  "t1_ns": t1, "traced": self.traced,
+                  "phases": {k: _entry(v) for k, v in total.items()},
+                  "by_thread": by_thread, "caller_thread": caller}
+        if self._parts is not None:
+            record["parts"] = self._parts
+            record["compound_gap_ns"] = self._gap_ns
+        return record
 
 
 def _entry(acc: List[int]) -> Dict[str, int]:
@@ -317,4 +341,16 @@ def format_report(record: Dict[str, Any]) -> str:
                  f"{mine['other_ns'] / 1e9:.6f} s "
                  f"({100.0 * mine['other_ns'] / (root or 1):.2f}% of the "
                  f"root span)")
+    t0 = record["t0_ns"]
+    for i, part in enumerate(record.get("parts", ())):
+        lines.append(
+            f"part {i} {part['name']}: enqueued at "
+            f"{(part['enqueued_ns'] - t0) / 1e9:.6f} s, first device call "
+            + (f"{(part['first_call_ns'] - t0) / 1e9:.6f}"
+               if part["first_call_ns"] else "none")
+            + f", completed {(part['completed_ns'] - t0) / 1e9:.6f}")
+    if "compound_gap_ns" in record:
+        lines.append(f"compound_gap {record['compound_gap_ns'] / 1e9:.6f} s "
+                     f"between a part's completion and the next part's "
+                     f"first device call")
     return "\n".join(lines)

@@ -1,16 +1,20 @@
 """Tile kernels (XLA/Pallas executables for task BODYs) and tile
 algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm)."""
 from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt,
-                     gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
-                     getrf_1d_panel, getrf_1d_update, getrf_nopiv, potrf, scal,
-                     syrk_ln, transpose, trsm_lower, trsm_lower_trans,
-                     trsm_lower_unit, trsm_panel, trsm_upper_right, tsmqr,
-                     tsqrt, tsqrt_r, unmqr)
+                     gemm_tn, gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
+                     getrf_1d_panel, getrf_1d_update, getrf_nopiv, lauum_lower,
+                     potrf, scal, syrk_ln, syrk_lt, transpose,
+                     trmm_lower_trans, trsm_lower, trsm_lower_right_neg,
+                     trsm_lower_trans, trsm_lower_unit, trsm_panel,
+                     trsm_upper_right, trtri_lower, tsmqr, tsqrt, tsqrt_r,
+                     unmqr)
 from . import dpotrf as dpotrf_module
 from .dpotrf import dpotrf, dpotrf_factory, dpotrf_taskpool, make_spd
 from .dpotrf_dtd import dpotrf_dtd
 from .dgeqrf import dgeqrf, dgeqrf_factory, dgeqrf_taskpool
-from .inverse import dgesv, dgetrs, dlauum, dpotri, dtrtri
+from .inverse import dgesv, dgetrs
+from .dpoinv import (dlauum, dlauum_taskpool, dpoinv, dpotri, dtrtri,
+                     dtrtri_taskpool)
 from .dgetrf import (dgetrf, dgetrf_factory, dgetrf_nopiv, dgetrf_nopiv_taskpool,
                      make_diag_dominant)
 from .dgetrf_1d import dgetrf_1d, dgetrf_1d_factory, dgetrf_1d_taskpool
@@ -30,7 +34,10 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "dgeqrf", "dgeqrf_factory", "dgeqrf_taskpool",
            "dgetrf", "dgetrf_nopiv", "dgetrf_nopiv_taskpool", "dgetrf_factory",
            "dgetrf_1d", "dgetrf_1d_factory", "dgetrf_1d_taskpool",
-           "dtrtri", "dlauum", "dpotri", "dgetrs", "dgesv",
+           "dpoinv", "dpotri", "dtrtri", "dlauum", "dtrtri_taskpool",
+           "dlauum_taskpool", "trtri_lower", "trsm_lower_right_neg",
+           "trmm_lower_trans", "lauum_lower", "syrk_lt", "gemm_tn",
+           "dgetrs", "dgesv",
            "make_diag_dominant",
            "pdgemm", "pdgemm_factory", "pdgemm_taskpool",
            "dposv", "dtrsm_lower_taskpool", "dtrsm_lower_trans_taskpool",

@@ -1,12 +1,8 @@
-"""Matrix inverses — the DPLASMA potri-family slice (trtri, lauum,
-potri, posv-based general inverse via getrf/getrs).
-
-TPU-native design: these are MXU-shaped XLA programs, not task DAGs —
-a triangular inverse is one ``triangular_solve`` against the identity
-(XLA blocks it internally), and lauum/potri are single large GEMMs with
-true-f32 input precision (factor chains compound the MXU's default
-bf16-input error; see ops/dgetrf.py). Each shape compiles once
-(lru-cached jit), like a captured taskpool.
+"""General solves on whole arrays: ``dgetrs`` / ``dgesv`` over
+``dgetrf``'s packed factors (DPLASMA zgetrs / zgesv), each ONE jitted
+XLA program per shape, not a task DAG.  (The SPD inverse family --
+``dtrtri``, ``dlauum``, ``dpotri``, ``dpoinv`` -- is tiled and runs
+through the runtime: ops/dpoinv.py.)
 """
 from __future__ import annotations
 
@@ -14,57 +10,7 @@ import functools
 
 import numpy as np
 
-__all__ = ["dtrtri", "dlauum", "dpotri", "dgetrs", "dgesv"]
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_trtri(n: int, lower: bool, unit: bool, dtype_name: str):
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def f(T):
-        eye = jnp.eye(n, dtype=T.dtype)
-        return lax.linalg.triangular_solve(
-            T, eye, left_side=True, lower=lower, unit_diagonal=unit)
-    return jax.jit(f)
-
-
-def dtrtri(T, lower: bool = True, unit_diagonal: bool = False):
-    """Inverse of a triangular matrix (ref algorithm: DPLASMA ztrtri)."""
-    n = T.shape[0]
-    return _jit_trtri(n, lower, unit_diagonal, np.dtype(T.dtype).name)(T)
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_lauum(n: int, lower: bool, dtype_name: str):
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def f(T):
-        Tt = jnp.tril(T) if lower else jnp.triu(T)
-        # accumulate in f32 for <=32-bit inputs; f64 inputs keep f64
-        # accumulation (f32 would silently launder away 9 digits)
-        acc = jnp.promote_types(T.dtype, jnp.float32)
-        a, b = (Tt.T, Tt) if lower else (Tt, Tt.T)
-        prod = jnp.matmul(a, b, precision=lax.Precision.HIGHEST,
-                          preferred_element_type=acc)
-        return prod.astype(T.dtype)
-    return jax.jit(f)
-
-
-def dlauum(T, lower: bool = True):
-    """L^T L (lower) / U U^T (upper) — the lauum kernel of potri."""
-    return _jit_lauum(T.shape[0], lower, np.dtype(T.dtype).name)(T)
-
-
-def dpotri(L, lower: bool = True):
-    """SPD inverse from the Cholesky factor: A^{-1} = L^{-T} L^{-1}
-    (ref: DPLASMA zpotri = ztrtri + zlauum). ``L`` is dpotrf's output
-    (lower triangle holds the factor)."""
-    Linv = dtrtri(L, lower=lower)
-    return dlauum(Linv, lower=lower)
+__all__ = ["dgetrs", "dgesv"]
 
 
 @functools.lru_cache(maxsize=64)

@@ -173,6 +173,47 @@ def gemm_tn_sub(c: Any, a: Any, b: Any) -> Any:
 
 
 @jax.jit
+def gemm_tn(c: Any, a: Any, b: Any) -> Any:
+    """C <- C + A^T B (the off-diagonal accumulation of L^T L)."""
+    return c + jnp.dot(a.T, b, preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def trsm_lower_right_neg(t: Any, c: Any) -> Any:
+    """C <- -C L^{-1}, L = (non-unit) lower of T: the column step of the
+    in-place triangular inverse (solved as L^T X^T = -C^T)."""
+    return -_solve_tri(t, c.T, lower=True, trans="T").T
+
+
+@jax.jit
+def trtri_lower(t: Any) -> Any:
+    """T <- L^{-1}, L = (non-unit) lower of T: the inverse of one
+    diagonal tile, lower triangular (zeros above the diagonal)."""
+    return _solve_tri(t, jnp.eye(t.shape[0], dtype=t.dtype), lower=True)
+
+
+@jax.jit
+def trmm_lower_trans(t: Any, c: Any) -> Any:
+    """C <- L^T C, L = lower of T (what lies above T's diagonal is not
+    read)."""
+    return jnp.dot(jnp.tril(t).T, c, preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def lauum_lower(t: Any) -> Any:
+    """T <- L^T L, L = lower of T: the whole symmetric product, so the
+    diagonal tile of an inverse is right in both triangles."""
+    l = jnp.tril(t)
+    return jnp.dot(l.T, l, preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def syrk_lt(t: Any, a: Any) -> Any:
+    """T <- T + A^T A (transposed SYRK; the whole symmetric product)."""
+    return t + jnp.dot(a.T, a, preferred_element_type=jnp.float32)
+
+
+@jax.jit
 def trsm_upper_right(t: Any, c: Any) -> Any:
     """Column-panel update for LU: C <- C U^{-1}, U = upper of T
     (solved as U^T X^T = C^T)."""

@@ -221,6 +221,11 @@ class Taskpool(Obj):
 
     _id_iter = itertools.count(1)
 
+    #: the record of this taskpool as a part of a compound
+    #: (runtime/compound.py), which the device module stamps with the
+    #: part's first device call; None for a taskpool enqueued alone
+    _part: Optional[Dict[str, Any]] = None
+
     def __init__(self, name: str = "taskpool", nb_task_classes: int = 0) -> None:
         super().__init__()
         self.taskpool_id = next(Taskpool._id_iter)

@@ -1,0 +1,10 @@
+"""Tile kernels: the least time the chip could take for the DAG's TRMM
+tasks (count x max(flops / peak, bytes / bandwidth) of
+``kernels/<operation>.TRMM.json``: useful work, a triangular tile
+counted as its triangle) over the device seconds of the class's programs
+per factorization (``trmm_device_s``); ``class_roofline.py``."""
+from perfbench import class_roofline
+
+
+def read(obs):
+    return class_roofline.read(obs, "TRMM")

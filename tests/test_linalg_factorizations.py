@@ -479,30 +479,34 @@ def test_dgetrf_wide():
 # --------------------------------------------------------------------- #
 # inverses / solves (the potri family + gesv)                           #
 # --------------------------------------------------------------------- #
-def test_dtrtri_inverse():
+def test_dtrtri_inverse(ctx):
+    """The tiled triangular inverse (ops/dpoinv.py): L^-1 in place on
+    the lower tiles, through the runtime."""
     from parsec_tpu.ops import dtrtri
 
-    n = 96
+    n, nb = 96, 32
     rng = np.random.RandomState(21)
     L = np.tril(rng.rand(n, n).astype(np.float32)) + 2 * np.eye(
         n, dtype=np.float32)
-    Linv = np.asarray(dtrtri(L, lower=True))
+    A = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32).from_numpy(L)
+    dtrtri(ctx, A)
+    Linv = np.tril(A.to_numpy())
     np.testing.assert_allclose(Linv @ L, np.eye(n), atol=2e-4)
-    U = L.T.copy()
-    Uinv = np.asarray(dtrtri(U, lower=False))
-    np.testing.assert_allclose(U @ Uinv, np.eye(n), atol=2e-4)
+    np.testing.assert_allclose(L @ Linv, np.eye(n), atol=2e-4)
 
 
 def test_dpotri_spd_inverse_from_cholesky(ctx):
-    """potrf (PTG) then potri: the full DPLASMA zpotri pipeline."""
+    """potrf (PTG) then potri (dtrtri composed with dlauum): the full
+    DPLASMA zpotri pipeline, every tile operation a task."""
     from parsec_tpu.ops import dpotri, dpotrf_taskpool, make_spd
 
     n, nb = 128, 64
     M = make_spd(n, seed=22)
     A = TwoDimBlockCyclic(n, n, nb, nb, dtype=np.float32).from_numpy(M)
     _run(ctx, dpotrf_taskpool(A))
-    L = np.tril(A.to_numpy()).astype(np.float32)
-    Ainv = np.asarray(dpotri(L))
+    dpotri(ctx, A)
+    low = np.tril(A.to_numpy())
+    Ainv = low + np.tril(low, -1).T
     np.testing.assert_allclose(Ainv @ M, np.eye(n), atol=5e-3)
 
 

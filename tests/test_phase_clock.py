@@ -565,12 +565,24 @@ def test_benchmark_lists_every_new_reader():
     qr = [c for c in cells if c.startswith("dgeqrf")]
     cholesky = [c for c in cells if c.startswith("dpotrf")]
     lu = [c for c in cells if c.startswith("dgetrf")]
-    assert qr + cholesky + lu and set(qr + cholesky + lu) == set(cells)
+    inverse = [c for c in cells if c.startswith("dpoinv")]
+    assert set(qr + cholesky + lu + inverse) == set(cells)
+    assert qr and cholesky and lu and inverse
     for name in CLASS_METRICS:
+        # dpoinv's first part is dpotrf's DAG, under dpotrf's class names
         want = qr if name[:5] in ("geqrt", "unmqr", "tsqrt", "tsmqr") \
-            else cholesky
+            else cholesky + inverse
         assert per_layer[name]["workloads"] == want, name
         assert per_layer[name]["source"] == "device_trace"
+    for cls in ("trtri", "trsmr", "trsml", "gemmi",
+                "lauum", "trmm", "syrkt", "gemmt"):
+        for name in (f"{cls}_device_s", f"{cls}_roofline"):
+            assert per_layer[name]["workloads"] == inverse, name
+            assert per_layer[name]["source"] == "device_trace"
+    for name in ("compound_gap_s", "part_potrf_s", "part_trtri_s",
+                 "part_lauum_s"):
+        assert per_layer[name]["workloads"] == inverse, name
+        assert per_layer[name]["source"] == "program_span"
     for cls in ("panel", "update", "laswp"):
         for name in (f"{cls}_device_s", f"{cls}_roofline"):
             assert per_layer[name]["workloads"] == lu, name
