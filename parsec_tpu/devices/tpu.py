@@ -191,6 +191,13 @@ class JaxDevice(Device):
                       # the part of stage_in_bytes pulled from a copy
                       # on another chip
                       "stage_in_peer_bytes": 0,
+                      # ``device_put`` calls that moved tiles here for
+                      # a stage-in, from the host or another chip (the
+                      # one call of a set's list is one), and the tiles
+                      # they carried
+                      "stage_in_transfers": 0, "stage_in_tiles": 0,
+                      # tiles ``prestage_many`` staged ahead of the
+                      # per-task stage-in / found there by it
                       "prefetch_issued": 0, "prefetch_hits": 0,
                       "donated": 0,
                       # every rung a dispatch gave up (a run that must
@@ -219,12 +226,14 @@ class JaxDevice(Device):
         self._window: List[_InFlight] = []
         self._window_tasks = 0      # tasks the window's calls hold
         self._eager_done: List[_InFlight] = []
-        # batched dispatch + async stage-in prefetch (the task-stream
-        # pipeline; ISSUE 5): same-class ready tasks accumulate in
-        # ``pending`` and are stacked into one jitted call per
-        # (class, shapes, dtypes, bucket) at the next manager flush
+        # batched dispatch (the task-stream pipeline; ISSUE 5):
+        # same-class ready tasks accumulate in ``pending`` and are
+        # stacked into one jitted call per (class, shapes, dtypes,
+        # bucket) at the next manager flush
         self.batch_max = int(params.get("device_batch_max"))
         self.batch_mode = str(params.get("device_batch_mode"))
+        # read by the stage compiler's prestager and the tuner only:
+        # the manager stages a drained set whole (``prestage_many``)
         self.prefetch_depth = int(params.get("device_prefetch_depth"))
         self.donate = bool(params.get("device_donate"))
         # segmented flush (ISSUE 7), read only when the context spans
@@ -232,8 +241,9 @@ class JaxDevice(Device):
         # sub-calls so early segments' outputs retire (and their
         # dependency sends start) while later segments still execute
         self.flush_segments = int(params.get("device_flush_segments"))
-        # copies staged early by the prefetcher: id(copy) -> version;
-        # a stage-in that finds its copy here already valid is a HIT
+        # copies ``prestage_many`` staged: id(copy) -> version; a
+        # per-task stage-in that finds its copy here still valid is a
+        # HIT
         self._prefetched: Dict[int, int] = {}
 
     def _probe_budget(self) -> int:
@@ -270,12 +280,7 @@ class JaxDevice(Device):
             # accumulate: a same-class burst becomes ONE stacked
             # dispatch at the next manager flush (idle workers call
             # progress() every cycle, so the deferral is bounded by the
-            # releasing worker's remaining ready tasks).  Meanwhile
-            # stage-in the head of the queue early so its H2D overlaps
-            # the batch currently executing (the reference's push/exec
-            # stream overlap, device_cuda_module.c:1961-2012).
-            if 0 < len(self.pending) <= self.prefetch_depth:
-                self._prefetch_task(task)
+            # releasing worker's remaining ready tasks)
             return HookReturn.ASYNC
         # queue full (or batching off): become the manager right away
         # (first thread wins)
@@ -392,6 +397,9 @@ class JaxDevice(Device):
                 # detached copy (e.g. NEW tile scratch): move payload directly
                 if donate_ok is not None and access & FlowAccess.WRITE:
                     donate_ok[flow.flow_index] = True
+                if not is_device_array(ref.data_in.payload):
+                    self.stats["stage_in_transfers"] += 1
+                    self.stats["stage_in_tiles"] += 1
                 arrays.append(jax.device_put(ref.data_in.payload, target))
                 continue
             copy = data.get_copy(self.device_index)
@@ -409,13 +417,14 @@ class JaxDevice(Device):
                     copy.payload = jax.device_put(
                         src.payload, self._placement(data, target))
                 self.stats["stage_in_bytes"] += nbytes
+                self.stats["stage_in_transfers"] += 1
+                self.stats["stage_in_tiles"] += 1
                 if is_device_array(src.payload):
                     self.stats["stage_in_peer_bytes"] += nbytes
                 self._prefetched.pop(id(copy), None)  # staged-over: stale
             elif self._prefetched.pop(id(copy), None) is not None:
-                # the prefetcher staged this tile while an earlier batch
-                # executed and the version held: its H2D overlapped
-                # compute instead of serializing ahead of the dispatch
+                # the set pass staged this tile with its drained set
+                # and the version held
                 self.stats["prefetch_hits"] += 1
             data.complete_transfer_ownership(self.device_index, access)
             self._lru_touch(copy, owned=bool(access & FlowAccess.WRITE))
@@ -538,6 +547,15 @@ class JaxDevice(Device):
         buckets, fall back per-task for singletons / shape-divergent /
         unbatchable tasks.  Returns the number of tasks submitted."""
         from .batching import bucket_size, settle
+        try:
+            self._stage_in_set(items)
+        except Exception as exc:
+            plog.warning("tpu set stage-in failed: %s", exc)
+            # nothing was dispatched: the whole set goes BACK to
+            # pending, for the abort path's drain() to credit its load
+            for item in items:
+                self.pending.push_back(item)
+            raise
         groups: Dict[Any, List[Tuple]] = {}
         order: List[Any] = []   # dispatch groups in arrival order
         n = 0
@@ -745,147 +763,124 @@ class JaxDevice(Device):
                          sum(entry[1] for entry in chunk), waits)
 
     # ------------------------------------------------------------------ #
-    # async stage-in prefetch: overlap the NEXT batch's H2D with the     #
-    # current batch's execution (ref: the 3-stream push/exec/pop         #
-    # overlap, device_cuda_module.c:1961-2012)                           #
+    # set stage-in: the host tiles a drained ready set needs go to the   #
+    # chip in ONE call, ahead of the per-task stage-in                   #
     # ------------------------------------------------------------------ #
-    def _prefetch_task(self, task: Task) -> None:
-        """Early device_put of a queued task's host-resident inputs.
-        Runs on the submitting worker while the manager executes the
-        previous batch, so every check re-validates under the data lock
-        before committing (a racing stage-in must win)."""
+    def _stage_in_set(self, items: List[Tuple[Task, float]]) -> None:
+        """Stage every input tile of a drained set that is still on the
+        host (``prestage_many``), so that the per-task stage-in finds
+        it resident.  Semantics never depend on this pass: what it
+        leaves (a source on another chip, detached scratch, a lost
+        race) the per-task stage-in stages."""
         clock = self._phases
         if clock is not None:
-            clock.push("stage_in", cls=task.task_class.name)
+            clock.push("stage_in", cls="set", n=len(items))
         try:
-            target = self._stage_target(task)
-            for flow in task.task_class.flows:
-                if flow.ctl:
-                    continue
-                ref = task.data[flow.flow_index]
-                if ref.data_in is None or ref.data_in.data is None:
-                    continue
-                self.prestage_data(ref.data_in.data, dtt=ref.data_in.dtt,
-                                   target=target)
+            by_target: Dict[Any, List[Task]] = {}
+            for task, _est in items:
+                by_target.setdefault(self._stage_target(task),
+                                     []).append(task)
+            for target, tasks in by_target.items():
+                self.prestage_many(self._input_datas(tasks), target)
         finally:
             if clock is not None:
                 clock.pop("stage_in")
 
-    def prestage_data(self, data: Data, dtt=None, target=None) -> bool:
-        """Stage one Data's newest host payload onto this device EARLY
-        — the per-tile half of the §6.1 prefetcher, shared with the
-        stage compiler's prestager (ISSUE 13: stage N+1's packed-buffer
-        H2D overlaps stage N's execution / lowering).  Every check
-        re-validates under the data lock before committing, so a
-        racing stage-in always wins.  Returns True when a payload was
-        committed (the later stage-in will be a prefetch HIT)."""
-        import jax
-        if target is None:
-            target = self.jax_device
-        with data._lock:
-            copy = data.get_copy(self.device_index)
-            newest = data.newest_version()
-            if copy is not None and copy.coherency != Coherency.INVALID \
-                    and copy.version >= newest:
-                return False   # already device-resident and current
-            src = data.newest_copy(exclude_device=self.device_index)
-            # snapshot the version WITH the payload decision: the
-            # commit below must stamp the version these bytes had,
-            # not whatever the source advanced to meanwhile (an
-            # eviction writeback bumping the host copy between our
-            # device_put and the commit must not get its new
-            # version pinned onto old bytes)
-            src_version = src.version if src is not None else -1
-        from ..data.data import is_device_array
-        if src is None or src.payload is None \
-                or is_device_array(src.payload):
-            return False   # nothing to pull, or source is device-side
-        nbytes = getattr(src.payload, "nbytes", 0)
-        self._reserve(nbytes)
-        with self._xfer("in", nbytes):
-            buf = jax.device_put(src.payload, self._placement(data, target))
-        committed = False
-        old = 0
-        with data._lock:
-            if copy is None:
-                copy = data.get_copy(self.device_index)
-            if copy is None:
-                copy = DataCopy(data, self.device_index, payload=None,
-                                dtt=dtt)
-                data.attach_copy(copy)
-            # commit only if a concurrent stage-in did not get there
-            # first (it owns the coherency transition; clobbering an
-            # OWNED copy or an in-use reader would corrupt state)
-            if copy.readers == 0 and copy.coherency != Coherency.OWNED \
-                    and (copy.coherency == Coherency.INVALID
-                         or copy.version < src_version):
-                old = getattr(copy.payload, "nbytes", 0)
-                copy.payload = buf
-                copy.version = src_version
-                copy.coherency = Coherency.SHARED
-                self._prefetched[id(copy)] = src_version
-                committed = True
-        if committed:
-            self._account(-old)
-            self._lru_touch(copy, owned=False)
-            self.stats["prefetch_issued"] += 1
-            self.stats["stage_in_bytes"] += nbytes
-        else:
-            self._account(-nbytes)   # lost the race: undo the hold
-        return committed
-
-    def prestage_many(self, datas: List[Data],
-                      target=None) -> List[Data]:
-        """Batched ``prestage_data``: ONE ``jax.device_put`` call moves
-        every eligible payload (eager per-tile device_put costs ~0.2 ms
-        of dispatch each on CPU jax; batching amortizes it — the same
-        lesson as the mesh stack/unbind kernels).  Same per-copy
-        re-validation under the data lock; returns the Datas whose
-        payloads actually committed (already-resident tiles and lost
-        races are excluded, so the caller's hit accounting is exact)."""
-        import jax
-        from ..data.data import is_device_array
-        if target is None:
-            target = self.jax_device
-        plan = []   # (data, copy-or-None, src payload, src_version)
-        for data in datas:
-            with data._lock:
-                copy = data.get_copy(self.device_index)
-                newest = data.newest_version()
-                if copy is not None \
-                        and copy.coherency != Coherency.INVALID \
-                        and copy.version >= newest:
+    @staticmethod
+    def _input_datas(tasks: List[Task]):
+        """The Data behind every non-CTL flow of ``tasks`` that carries
+        one, in flow order, duplicates included."""
+        for task in tasks:
+            refs = task.data
+            for flow in task.task_class.flows:
+                if flow.ctl:
                     continue
-                src = data.newest_copy(exclude_device=self.device_index)
+                copy_in = refs[flow.flow_index].data_in
+                if copy_in is not None and copy_in.data is not None:
+                    yield copy_in.data
+
+    def prestage_data(self, data: Data) -> bool:
+        """``prestage_many`` of one Data: was its payload committed?"""
+        return bool(self.prestage_many((data,)))
+
+    def prestage_many(self, datas, target=None) -> List[Data]:
+        """Stage the newest HOST payload of every Data of ``datas`` that
+        has no current copy here, ahead of the stage-in that needs it:
+        the one function that issues the host-to-device stage-in of a
+        set (the manager's drained ready set; the stage compiler's
+        prestager).  Plan: a Data whose copy here is the owner (every
+        tile a task has written, the common case of a drained set)
+        costs one ``get_copy`` and one compare; any other is looked at
+        under its lock and, unless current, its newest host copy taken
+        with the version those bytes have.  Transfer: ``_reserve`` once, ONE
+        ``jax.device_put`` of the list.  jax walks the list and issues
+        a copy per array, so this saves jax's Python a tile and not the
+        copy: packing the tiles into one array first (``np.stack``, one
+        copy, a jitted split) saves nothing more on the v5e, the host
+        copy costs what the calls do (PERF.md section 6, PR 34).
+        Commit, under each Data's lock again: SHARED at the snapshotted
+        version, unless a racing stage-in got there first (it owns the
+        coherency transition; clobbering an OWNED copy or an in-use
+        reader would corrupt state), whose hold is undone.  Returns the
+        Datas whose payloads committed (resident tiles and lost races
+        are excluded, so a caller's hit accounting is exact)."""
+        import jax
+        from ..data.data import is_device_array
+        if target is None:
+            target = self.jax_device
+        index = self.device_index
+        INVALID, OWNED = Coherency.INVALID, Coherency.OWNED
+        # id(data) -> (data, copy-or-None, src, src_version)
+        plan: Dict[int, Tuple] = {}
+        for data in datas:
+            copy = data.get_copy(index)
+            if copy is not None and copy.coherency == OWNED:
+                continue   # the one newest copy is here
+            if id(data) in plan:
+                continue
+            with data._lock:
+                copy = data.get_copy(index)
+                if copy is not None and copy.coherency != INVALID \
+                        and copy.version >= data.newest_version():
+                    continue
+                src = data.newest_copy(exclude_device=index)
+                # snapshot the version WITH the payload decision: the
+                # commit below must stamp the version these bytes had,
+                # not whatever the source advanced to meanwhile (an
+                # eviction writeback bumping the host copy between our
+                # device_put and the commit must not get its new
+                # version pinned onto old bytes)
                 src_version = src.version if src is not None else -1
             if src is None or src.payload is None \
                     or is_device_array(src.payload):
-                continue
-            plan.append((data, copy, src, src_version))
+                continue   # nothing to pull, or the source is a chip
+            plan[id(data)] = (data, copy, src, src_version)
         if not plan:
             return []
+        entries = list(plan.values())
         nbytes = sum(getattr(s.payload, "nbytes", 0)
-                     for _d, _c, s, _v in plan)
+                     for _d, _c, s, _v in entries)
         self._reserve(nbytes)
         with self._xfer("in", nbytes):
             bufs = jax.device_put(
-                [s.payload for _d, _c, s, _v in plan],
-                [self._placement(d, target) for d, _c, _s, _v in plan])
+                [s.payload for _d, _c, s, _v in entries],
+                [self._placement(d, target) for d, _c, _s, _v in entries])
+        self.stats["stage_in_transfers"] += 1
+        self.stats["stage_in_tiles"] += len(entries)
         committed_datas: List[Data] = []
         undo = 0
-        for (data, copy, src, src_version), buf in zip(plan, bufs):
+        for (data, copy, src, src_version), buf in zip(entries, bufs):
             committed = False
             old = 0
             with data._lock:
                 if copy is None:
-                    copy = data.get_copy(self.device_index)
+                    copy = data.get_copy(index)
                 if copy is None:
-                    copy = DataCopy(data, self.device_index,
-                                    payload=None, dtt=src.dtt)
+                    copy = DataCopy(data, index, payload=None,
+                                    dtt=src.dtt)
                     data.attach_copy(copy)
-                if copy.readers == 0 \
-                        and copy.coherency != Coherency.OWNED \
-                        and (copy.coherency == Coherency.INVALID
+                if copy.readers == 0 and copy.coherency != OWNED \
+                        and (copy.coherency == INVALID
                              or copy.version < src_version):
                     old = getattr(copy.payload, "nbytes", 0)
                     copy.payload = buf

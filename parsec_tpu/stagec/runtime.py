@@ -42,8 +42,8 @@ ISSUE 13 grew three fronts onto this engine, all behind the same knob:
   at ARRIVAL (while the producing stage still executes or the wire
   still delivers), a spawning stage's own host-resident tiles stage
   under its trace/compile, and completed stages prestage the next
-  pending stages' final-valued tiles — all through the §6.1
-  prefetcher's device seam (``JaxDevice.prestage_data``), bounded by
+  pending stages' final-valued tiles — all through the §6.1 set
+  stage-in's device seam (``JaxDevice.prestage_many``), bounded by
   ``device_prefetch_depth``, counted in ``PRESTAGE_ISSUED``/
   ``PRESTAGE_HITS`` and visible to the live overlap gauge.
 
@@ -282,7 +282,7 @@ class StageCompiler:
                         self._rg_of[k] = gi
 
         # prestage/execute overlap (ISSUE 13): early H2D of stage
-        # inputs through the §6.1 prefetcher's device seam, bounded by
+        # inputs through the §6.1 set stage-in's device seam, bounded by
         # device_prefetch_depth stages with outstanding prestages
         self._prestage_depth = int(getattr(self._dev, "prefetch_depth",
                                            0))
@@ -544,7 +544,7 @@ class StageCompiler:
         if id(rec) not in self._prestage_recs \
                 and len(self._prestage_recs) >= self._prestage_depth:
             return
-        if self._dev.prestage_data(copy.data, dtt=copy.dtt):
+        if self._dev.prestage_data(copy.data):
             self._prestage_recs.add(id(rec))
             rec.prestaged.append(copy.data)
             self.stats["prestage_issued"] += 1

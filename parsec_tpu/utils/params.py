@@ -445,9 +445,10 @@ def register_core_params() -> None:
                     "process's XLA client — the mesh-local fast path; "
                     "off forces every payload through host bytes")
     params.reg_int("device_prefetch_depth", 4,
-                   "stage-in (device_put) the inputs of up to this many "
-                   "queued tasks while the current batch executes "
-                   "(0 = no async prefetch)")
+                   "the stage compiler's prestager: at most this many "
+                   "pending stages hold outstanding early stage-ins "
+                   "(0 = none).  The classic path reads it no more: "
+                   "its manager stages a drained ready set whole")
     params.reg_int("device_flush_segments", 4,
                    "across ranks only (a context of one rank makes no "
                    "send and flushes every group as ONE stacked call, "

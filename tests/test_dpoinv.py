@@ -288,24 +288,20 @@ def test_a_call_that_composes_nothing_has_no_parts():
 @pytest.mark.parametrize("entry,parts", [("dpoinv", 3), ("dpotri", 2)])
 def test_stage_in_is_one_pass_over_the_lower_tiles(ctx, entry, parts):
     """A part finds its tiles where the part before left them: the whole
-    call stages in each lower tile once (and the few tiles again that
-    the prefetcher staged ahead of a stage-in: PERF.md section 7), not
-    once a part, and stages nothing out."""
+    call stages in each lower tile once, not once a part, and stages
+    nothing out."""
     nt = 6
     M = poinv.make_input(nt * NB, 9)
     if entry == "dpotri":
         M = np.tril(np.linalg.cholesky(M.astype(np.float64))).astype(
             np.float32)
     A = _tiled(M)
-    keys = ("stage_in_bytes", "stage_out_bytes", "compound_parts",
-            "prefetch_issued")
+    keys = ("stage_in_bytes", "stage_out_bytes", "compound_parts")
     before = [_stat(ctx, k) for k in keys]
     getattr(ops, entry)(ctx, A)
-    staged, out, composed, prefetched = (
+    staged, out, composed = (
         _stat(ctx, k) - b for k, b in zip(keys, before))
-    tile, lower = NB * NB * 4, nt * (nt + 1) // 2
-    assert lower * tile <= staged <= (lower + prefetched) * tile
-    assert staged < 1.25 * lower * tile
+    assert staged == nt * (nt + 1) // 2 * NB * NB * 4
     assert out == 0
     assert composed == parts
 
