@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.lists import Dequeue
 from ..data.data import Coherency, Data, DataCopy, FlowAccess
+from ..obs.phases import BRACKETS, _now
 from ..runtime.taskpool import HookReturn, Task
 from ..utils import logging as plog
 from ..utils.params import params
@@ -181,7 +182,7 @@ class JaxDevice(Device):
                       "evictions": 0, "tasks": 0,
                       # batched-dispatch pipeline telemetry (guide §9.1)
                       "batches": 0, "batched_tasks": 0,
-                      "dispatch_ns": 0, "dispatch_tasks": 0,
+                      "dispatch_tasks": 0,
                       # the part of dispatch_ns spent in each program's
                       # first call on this device (trace + lower + load)
                       "first_call_ns": 0, "first_calls": 0,
@@ -219,6 +220,21 @@ class JaxDevice(Device):
                       # parts of compound taskpools (runtime/compound.py)
                       # whose first device call left from here
                       "compound_parts": 0}
+        # the manager's always-on brackets (obs.phases.BRACKETS), one
+        # per place it works under ``_manager_lock`` and none per task:
+        # wall ns (``time.monotonic_ns``, a vDSO read) and how many.
+        # Disjoint: a bracket inside another is taken out of it.
+        # ``set_stage``: the set pass, once a drain; ``group``: the rest
+        # of ``_dispatch_ready`` less the two inside it; ``dispatch``:
+        # the device call (``first_call_ns`` is its part);
+        # ``chip_wait``: ``_retire``'s wait for the call's outputs;
+        # ``epilog``: ``_epilog`` up to ``complete_executions``;
+        # ``complete``: that call.  A root span's record holds what
+        # they moved by (obs/phases.py: ``manager``).  No bracket reads
+        # the thread's CPU clock: a system call, and on the v5e's host a
+        # fresh value costs 0.6 ms (PERF.md section 6, PR 35).
+        for b in BRACKETS:
+            self.stats.update({b + "_ns": 0, b + "_n": 0})
         # eager completion (async dispatch IS completion; XLA orders the
         # dataflow) with a bounded in-flight window
         self.eager_complete = bool(params.get("tpu_eager_complete"))
@@ -348,6 +364,12 @@ class JaxDevice(Device):
             if clock is not None:
                 clock.pop("manager")
             self._manager_lock.release()
+
+    def _book(self, bracket: str, wall_ns: int) -> None:
+        """Close one always-on bracket of the manager."""
+        st = self.stats
+        st[bracket + "_ns"] += wall_ns
+        st[bracket + "_n"] += 1
 
     # ------------------------------------------------------------------ #
     # stage-in / execute                                                 #
@@ -479,16 +501,17 @@ class JaxDevice(Device):
         assert fn is not None, f"tpu chore of {tc.name} has no executable"
         # fn is the DSL's wrapper: (task, per-flow device arrays) -> outputs
         clock = self._phases
+        t0 = _now()
         if clock is not None:
-            clock.push("dispatch", cls=tc.name, n=1)
-        t0 = time.perf_counter_ns()
+            clock.push("dispatch", t0, cls=tc.name, n=1)
         try:
             outputs = fn(task, inputs)
         finally:
-            dt = time.perf_counter_ns() - t0
+            t1 = _now()
             if clock is not None:
-                clock.pop("dispatch", tasks=1)
-        self.stats["dispatch_ns"] += dt
+                clock.pop("dispatch", tasks=1, at_ns=t1)
+        dt = t1 - t0
+        self._book("dispatch", dt)
         self.stats["dispatch_tasks"] += 1
         self._note_profile(es, tc.name, dt / 1e3, 1)
         if outputs is None:
@@ -545,8 +568,11 @@ class JaxDevice(Device):
         """Dispatch a drained ready set: group by (class, static context,
         shapes, dtypes, donate mask), stack each group into power-of-two
         buckets, fall back per-task for singletons / shape-divergent /
-        unbatchable tasks.  Returns the number of tasks submitted."""
-        from .batching import bucket_size, settle
+        unbatchable tasks.  Returns the number of tasks submitted.
+
+        Two always-on brackets: ``set_stage`` around the set pass,
+        ``group`` around the rest less the ``dispatch`` and
+        ``chip_wait`` brackets closed inside it."""
         try:
             self._stage_in_set(items)
         except Exception as exc:
@@ -556,6 +582,19 @@ class JaxDevice(Device):
             for item in items:
                 self.pending.push_back(item)
             raise
+        st = self.stats
+        t0 = _now()
+        inside = st["dispatch_ns"] + st["chip_wait_ns"]
+        try:
+            return self._dispatch_groups(es, items)
+        finally:
+            self._book("group", _now() - t0 - (
+                st["dispatch_ns"] + st["chip_wait_ns"] - inside))
+
+    def _dispatch_groups(self, es, items: List[Tuple[Task, float]]) -> int:
+        """``_dispatch_ready`` after the set pass: the per-task
+        stage-in, the grouping and every dispatch."""
+        from .batching import bucket_size, settle
         groups: Dict[Any, List[Tuple]] = {}
         order: List[Any] = []   # dispatch groups in arrival order
         n = 0
@@ -696,9 +735,9 @@ class JaxDevice(Device):
         first = fn.first_call_on(self.name)
         span = "first_call" if first else "dispatch"
         clock = self._phases
+        t0 = _now()
         if clock is not None:
-            clock.push(span, cls=chunk[0][0].task_class.name, n=n)
-        t0 = time.perf_counter_ns()
+            clock.push(span, t0, cls=chunk[0][0].task_class.name, n=n)
         try:
             outs = fn(*flat)
         except Exception as exc:
@@ -731,10 +770,11 @@ class JaxDevice(Device):
                 for task, est, inputs, _ in chunk:
                     self._submit_prepared(es, task, est, inputs)
                 return
-        dt = time.perf_counter_ns() - t0
+        t1 = _now()
         if clock is not None:
-            clock.pop(span, tasks=n)
-        self.stats["dispatch_ns"] += dt
+            clock.pop(span, tasks=n, at_ns=t1)
+        dt = t1 - t0
+        self._book("dispatch", dt)
         self.stats["dispatch_tasks"] += n
         if first:
             self.stats["first_call_ns"] += dt
@@ -771,10 +811,12 @@ class JaxDevice(Device):
         host (``prestage_many``), so that the per-task stage-in finds
         it resident.  Semantics never depend on this pass: what it
         leaves (a source on another chip, detached scratch, a lost
-        race) the per-task stage-in stages."""
+        race) the per-task stage-in stages.  The always-on bracket
+        ``set_stage``."""
         clock = self._phases
+        t0 = _now()
         if clock is not None:
-            clock.push("stage_in", cls="set", n=len(items))
+            clock.push("stage_in", t0, cls="set", n=len(items))
         try:
             by_target: Dict[Any, List[Task]] = {}
             for task, _est in items:
@@ -783,8 +825,10 @@ class JaxDevice(Device):
             for target, tasks in by_target.items():
                 self.prestage_many(self._input_datas(tasks), target)
         finally:
+            t1 = _now()
             if clock is not None:
-                clock.pop("stage_in")
+                clock.pop("stage_in", at_ns=t1)
+            self._book("set_stage", t1 - t0)
 
     @staticmethod
     def _input_datas(tasks: List[Task]):
@@ -977,25 +1021,36 @@ class JaxDevice(Device):
         outputs become ready together) and surface any async kernel
         error once — against a task of the call that DISPATCHED it (es
         or context present: recorded as a task error; teardown:
-        logged)."""
-        clock = self._phases
-        if clock is not None:   # may wait for the kernel: backpressure
-            clock.push("epilog", cls=rec.tasks[0].task_class.name,
-                       n=len(rec.tasks))
+        logged).  The wait, and nothing else here, is the always-on
+        bracket and the phase ``chip_wait``: the manager blocked on the
+        device (the eager window's backpressure, a drain), not running
+        Python."""
         self.load_sub(rec.est)
         self.stats["retired_calls"] += 1
+        failed = None
+        clock = self._phases
+        t0 = _now()
+        if clock is not None:
+            clock.push("chip_wait", t0, cls=rec.tasks[0].task_class.name,
+                       n=len(rec.tasks))
         try:
             for a in rec.live():
                 a.block_until_ready()
         except Exception as exc:
+            failed = exc
+        t1 = _now()
+        if clock is not None:
+            clock.pop("chip_wait", at_ns=t1)
+        self._book("chip_wait", t1 - t0)
+        if failed is not None:
             ctx = context if context is not None else \
                 (es.context if es is not None else None)
             if ctx is not None:
-                ctx.record_task_error(exc, rec.tasks[0])
+                ctx.record_task_error(failed, rec.tasks[0])
             else:
                 plog.warning("async kernel of %s (a call of %d) failed "
                              "at drain: %s", rec.tasks[0].snprintf(),
-                             len(rec.tasks), exc)
+                             len(rec.tasks), failed)
         obs = self._obs
         if obs is not None and obs.tracker is not None and es is not None:
             # the device-busy interval for the live overlap gauge:
@@ -1006,8 +1061,6 @@ class JaxDevice(Device):
             # about when the kernel finished.
             obs.tracker.note("compute", rec.t0,
                              rec.done_est or time.monotonic_ns())
-        if clock is not None:
-            clock.pop("epilog")
 
     def _epilog(self, es, rec: _InFlight) -> None:
         """The epilog of one call (ref: parsec_cuda_kernel_epilog,
@@ -1015,13 +1068,15 @@ class JaxDevice(Device):
         installs the written copies and releases the readers, one
         ``_account`` takes the summed delta; then the tasks complete in
         dispatch order and what they made ready is handed to the
-        scheduler once."""
+        scheduler once.  Two always-on brackets: ``epilog`` up to
+        ``complete_executions``, ``complete`` around it."""
         from ..runtime.scheduling import complete_executions
         tasks = rec.tasks
         n = len(tasks)
         clock = self._phases
+        t0 = _now()
         if clock is not None:
-            clock.push("epilog", cls=tasks[0].task_class.name, n=n)
+            clock.push("epilog", t0, cls=tasks[0].task_class.name, n=n)
         if not self.eager_complete:
             # non-eager: the poll loop just observed the call ready —
             # note the device-busy interval (eager mode notes at window
@@ -1070,9 +1125,14 @@ class JaxDevice(Device):
             self.load_sub(rec.est)
             self.stats["retired_calls"] += 1
         self.executed_tasks += n
+        t1 = _now()
         if clock is not None:
-            clock.pop("epilog")
-        complete_executions(es, tasks)
+            clock.pop("epilog", at_ns=t1)
+        self._book("epilog", t1 - t0)
+        try:
+            complete_executions(es, tasks)
+        finally:
+            self._book("complete", _now() - t1)
 
     # ------------------------------------------------------------------ #
     # memory management: accounting arena + LRU eviction                 #
@@ -1413,9 +1473,9 @@ class JaxMeshDevice(JaxDevice):
                 self._stage_target(chunk[i][0]), 0))
             first = fn.first_call_on(self.name)
             span = "first_call" if first else "dispatch"
+            t0 = _now()
             if clock is not None:
-                clock.push(span, cls=chunk[0][0].task_class.name, n=n)
-            t0 = time.perf_counter_ns()
+                clock.push(span, t0, cls=chunk[0][0].task_class.name, n=n)
             # per-chip assembly: ONE jitted stack call per chip builds
             # that chip's shard of every batch arg (rows already
             # resident there stay put; stragglers hop)
@@ -1440,10 +1500,11 @@ class JaxMeshDevice(JaxDevice):
                 clock.pop(span)
             raise _MeshDispatchFailed(
                 f"{type(exc).__name__}: {exc}") from exc
-        dt = time.perf_counter_ns() - t0
+        t1 = _now()
         if clock is not None:
-            clock.pop(span, tasks=n)
-        self.stats["dispatch_ns"] += dt
+            clock.pop(span, tasks=n, at_ns=t1)
+        dt = t1 - t0
+        self._book("dispatch", dt)
         self.stats["dispatch_tasks"] += n
         if first:
             self.stats["first_call_ns"] += dt

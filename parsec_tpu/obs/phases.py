@@ -14,8 +14,9 @@ root span's start and end is its ``other``; so per thread
 
 A clock exists only while someone can read it: a JAX profiler session
 is recording when the root span opens (``session_recording``), or the
-context was built with ``profile=True``.  Otherwise ``root_span`` is
-one check and every site stays on its ``is None`` fast path.  Under a
+context was built with ``profile=True``.  Otherwise every site stays on
+its ``is None`` fast path, and the call still leaves a record: its two
+stamps and its ``manager`` block (below).  Under a
 session every span (but ``UNANNOTATED``) is also written into the
 profiler's own trace as a ``jax.profiler.TraceAnnotation`` named
 ``parsec:<phase>``, so the runtime's spans sit on the ``/host:CPU``
@@ -30,8 +31,19 @@ next part's first device call, summed over the boundaries -- no device
 has anything queued then.  Not a phase: it is wall time of the request,
 not self time of a thread.
 
+The ``manager`` block is in EVERY record, clock or none: the device
+module brackets the six places a device manager works (``BRACKETS``;
+``devices/tpu.py``) with the wall clock in every run, in ``dev.stats``,
+and the record holds what those counters moved by during the call: per
+bracket ``wall_ns`` and ``count``, summed over the accelerator devices
+(``manager``) and for each (``by_device``).  The brackets of one device
+are disjoint, so their sum is at most the call's root span.
+
 Closed root spans leave one record each in a bounded process-wide list
-(``completed()``); ``format_report(record)`` prints the table.
+(``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
+``manager``, ``by_device``, a compound call's ``parts`` and
+``compound_gap_ns``, and with a clock ``phases``, ``by_thread`` and
+``caller_thread``.  ``format_report(record)`` prints the tables.
 """
 from __future__ import annotations
 
@@ -41,14 +53,21 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["PHASES", "PhaseClock", "root_span", "session_recording",
-           "completed", "clear_completed", "format_report"]
+__all__ = ["PHASES", "BRACKETS", "PhaseClock", "root_span",
+           "session_recording", "completed", "clear_completed",
+           "format_report"]
 
 #: every phase a site books under, in the order the report prints them
 PHASES = ("select", "idle_poll", "parked", "prepare_input", "exec",
           "schedule", "complete", "release_deps", "manager", "stage_in",
-          "dispatch", "first_call", "epilog", "dtd_insert", "dtd_window",
-          "dtd_flush", "other")
+          "dispatch", "first_call", "chip_wait", "epilog", "dtd_insert",
+          "dtd_window", "dtd_flush", "other")
+
+#: the places a device manager works, each bracketed in every run by
+#: the device module (``devices/tpu.py``: counters ``<bracket>_ns`` and
+#: ``<bracket>_n`` in ``dev.stats``)
+BRACKETS = ("set_stage", "group", "dispatch", "chip_wait", "epilog",
+            "complete")
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -62,13 +81,15 @@ UNANNOTATED = frozenset(("select", "idle_poll"))
 #: thread (above obs.spans' comm / device / health rows)
 PHASE_STREAM_TID = (1 << 20) + (1 << 11)
 
-_COMPLETED_MAX = 64
+#: a 30 s window of the benchmark makes up to 40 calls after set-up's
+#: two; its readers want every one of them
+_COMPLETED_MAX = 256
 _completed: "deque[Dict[str, Any]]" = deque(maxlen=_COMPLETED_MAX)
 
 
 def completed() -> List[Dict[str, Any]]:
-    """The records of the last (at most 64) closed root spans, oldest
-    first."""
+    """The records of the last (at most ``_COMPLETED_MAX``) closed root
+    spans, oldest first."""
     return list(_completed)
 
 
@@ -125,9 +146,6 @@ class PhaseClock:
         self._new_lock = threading.Lock()
         self._t1 = float("inf")
         self._root_anno = None
-        #: the part records of the compound taskpool the request ran
-        self._parts: Optional[List[Dict[str, Any]]] = None
-        self._gap_ns = 0
         self.caller = threading.get_ident()
         if traced:
             import jax
@@ -153,9 +171,12 @@ class PhaseClock:
                 st = self._threads[ident] = _Thread(name, stream)
         return st
 
-    def push(self, phase: str, **args: Any) -> None:
-        """Open a span of ``phase`` on the calling thread.  ``args``
-        go into the profiler's trace with the span."""
+    def push(self, phase: str, at_ns: Optional[int] = None,
+             **args: Any) -> None:
+        """Open a span of ``phase`` on the calling thread, at ``at_ns``
+        where the site has read ``_now()`` for a bracket of its own
+        (one read feeds both).  ``args`` go into the profiler's trace
+        with the span."""
         st = self._threads.get(_get_ident())
         if st is None:
             st = self._thread()
@@ -165,17 +186,18 @@ class PhaseClock:
         if self.traced and phase not in UNANNOTATED:
             anno = self._annotation("parsec:" + phase, id=self.id, **args)
             anno.__enter__()
-        t = _now()
+        t = _now() if at_ns is None else at_ns
         # only the owner appends; close() cuts what it finds at _t1
         st.stack.append([phase, t if t < self._t1 else self._t1, 0, anno])
 
     def pop(self, phase: str, booked_as: Optional[str] = None,
-            tasks: int = 0) -> None:
+            tasks: int = 0, at_ns: Optional[int] = None) -> None:
         """Close the calling thread's innermost open span of ``phase``
-        and book its self time under ``booked_as`` (default: its own
-        name).  Spans left open above it (an exception skipped their
-        end) close with it; an end with no begin is ignored."""
-        t = _now()
+        (at ``at_ns``, as ``push`` takes it) and book its self time
+        under ``booked_as`` (default: its own name).  Spans left open
+        above it (an exception skipped their end) close with it; an end
+        with no begin is ignored."""
+        t = _now() if at_ns is None else at_ns
         st = self._threads.get(_get_ident())
         if st is None:
             return
@@ -226,14 +248,6 @@ class PhaseClock:
             if st.stream is not None:
                 st.stream.span("phase:" + name, start, t)
 
-    def note_compound(self, parts: List[Dict[str, Any]],
-                      gap_ns: int) -> None:
-        """The request ran a compound taskpool, now complete: keep its
-        part records and the gap between them (the last compound's, if
-        a request runs several)."""
-        self._parts = parts
-        self._gap_ns = gap_ns
-
     def close(self) -> Dict[str, Any]:
         """End the root span: cut every thread's open spans at now,
         freeze the books and return the record."""
@@ -268,14 +282,10 @@ class PhaseClock:
             total.setdefault("other", [0, 0, 0])[0] += root
         if self._root_anno is not None:
             self._root_anno.__exit__(None, None, None)
-        record = {"op": self.op, "id": self.id, "t0_ns": self.t0,
-                  "t1_ns": t1, "traced": self.traced,
-                  "phases": {k: _entry(v) for k, v in total.items()},
-                  "by_thread": by_thread, "caller_thread": caller}
-        if self._parts is not None:
-            record["parts"] = self._parts
-            record["compound_gap_ns"] = self._gap_ns
-        return record
+        return {"op": self.op, "id": self.id, "t0_ns": self.t0,
+                "t1_ns": t1, "traced": self.traced,
+                "phases": {k: _entry(v) for k, v in total.items()},
+                "by_thread": by_thread, "caller_thread": caller}
 
 
 def _entry(acc: List[int]) -> Dict[str, int]:
@@ -285,62 +295,120 @@ def _entry(acc: List[int]) -> Dict[str, int]:
     return out
 
 
+def _bracket_counters(devices: List[Any]) -> List[tuple]:
+    """``(device, {bracket: {field: counter now}})`` of each of
+    ``devices`` that keeps the manager's brackets."""
+    return [(dev, {b: {"wall_ns": dev.stats[b + "_ns"],
+                       "count": dev.stats[b + "_n"]} for b in BRACKETS})
+            for dev in devices
+            if BRACKETS[0] + "_ns" in getattr(dev, "stats", ())]
+
+
+def _manager_block(before: List[tuple]) -> Dict[str, Any]:
+    """``manager`` and ``by_device`` of a record: what the bracket
+    counters moved by since ``before``."""
+    after = _bracket_counters([dev for dev, _was in before])
+    by_device = [dict({b: {f: now[b][f] - was[b][f] for f in now[b]}
+                       for b in BRACKETS}, device=dev.name)
+                 for (dev, was), (_dev, now) in zip(before, after)]
+    return {"manager": {b: {f: sum(e[b][f] for e in by_device)
+                            for f in ("wall_ns", "count")}
+                        for b in BRACKETS},
+            "by_device": by_device}
+
+
 @contextlib.contextmanager
 def root_span(context: Any, op: str, ident: int) -> Iterator[Optional[PhaseClock]]:
-    """The root span of one blocking request on ``context``.  Yields the
-    clock, or None when nobody could read one (no profiler session
-    recording and no ``Context(profile=True)``) or when the context is
-    already inside a root span."""
-    traced = session_recording()
-    if (not traced and context.profile is None) \
-            or context._phase_clock is not None:
+    """The root span of one blocking request on ``context``; it leaves
+    one record in ``completed()``.  Yields the phase clock, or None
+    when nobody could read one (no profiler session recording and no
+    ``Context(profile=True)``: the record then holds the stamps and the
+    ``manager`` block alone) or when the context is already inside a
+    root span (no record)."""
+    if context._root_call is not None:
         yield None
         return
-    from ..profiling.pins import TaskProfilerModule
-    clock = PhaseClock(op, ident, traced, profile=context.profile)
-    module = context._task_profiler
-    borrowed = module is None
-    if borrowed:    # a session with no profile=True: a module for the call
-        module = TaskProfilerModule(None, context=context)
-        module.enable()
-    module.clock = clock
-    context._phase_clock = clock
-    for dev in context.devices:
-        dev._phases = clock
+    traced = session_recording()
+    clock = module = None
+    borrowed = False
+    if traced or context.profile is not None:
+        from ..profiling.pins import TaskProfilerModule
+        clock = PhaseClock(op, ident, traced, profile=context.profile)
+        module = context._task_profiler
+        borrowed = module is None
+        if borrowed:    # a session with no profile=True: a module for the call
+            module = TaskProfilerModule(None, context=context)
+            module.enable()
+        module.clock = clock
+        context._phase_clock = clock
+        for dev in context.devices:
+            dev._phases = clock
+    # what the call's taskpools leave for its record (a compound's
+    # ``parts`` and ``compound_gap_ns``: runtime/compound.py)
+    call: Dict[str, Any] = {}
+    context._root_call = call
+    before = _bracket_counters(context.devices)
+    t0 = _now()
     try:
         yield clock
     finally:
-        for dev in context.devices:
-            dev._phases = None
-        context._phase_clock = None
-        module.clock = None
-        if borrowed:
-            module.disable()
-        _completed.append(clock.close())
+        t1 = _now()
+        context._root_call = None
+        if clock is None:
+            record = {"op": op, "id": ident, "t0_ns": t0, "t1_ns": t1,
+                      "traced": False}
+        else:
+            for dev in context.devices:
+                dev._phases = None
+            context._phase_clock = None
+            module.clock = None
+            if borrowed:
+                module.disable()
+            record = clock.close()
+        record.update(_manager_block(before))
+        record.update(call)
+        _completed.append(record)
 
 
 def format_report(record: Dict[str, Any]) -> str:
-    """The phase table of one record: per phase the self seconds summed
-    over threads, the span count, the share of threads x root span; then
-    the calling thread's own line."""
+    """The tables of one record: with a clock, per phase the self
+    seconds summed over threads, the span count, the share of threads x
+    root span, then the calling thread's own line; always, per bracket
+    of the device managers the wall seconds and the count; then a
+    compound call's parts."""
     root = record["t1_ns"] - record["t0_ns"]
-    n = len(record["by_thread"])
+    n = len(record.get("by_thread", ()))
     lines = [f"{record['op']} #{record['id']}: root span {root / 1e9:.6f} s, "
-             f"{n} thread(s), "
-             f"{'profiler session' if record['traced'] else 'no session'}",
-             f"{'phase':<14}{'self s':>12}{'spans':>10}{'tasks':>8}"
-             f"{'share %':>9}"]
-    known = [p for p in PHASES if p in record["phases"]]
-    for name in known + sorted(set(record["phases"]) - set(known)):
-        e = record["phases"][name]
-        lines.append(f"{name:<14}{e['self_ns'] / 1e9:>12.6f}{e['count']:>10}"
-                     f"{e.get('tasks', ''):>8}"
-                     f"{100.0 * e['self_ns'] / (root * n or 1):>9.2f}")
-    mine = record["by_thread"][record["caller_thread"]]
-    lines.append(f"calling thread {record['caller_thread']}: other "
-                 f"{mine['other_ns'] / 1e9:.6f} s "
-                 f"({100.0 * mine['other_ns'] / (root or 1):.2f}% of the "
-                 f"root span)")
+             + (f"{n} thread(s), " if n else "no phase clock, ")
+             + ("profiler session" if record["traced"] else "no session")]
+    if "phases" in record:
+        lines.append(f"{'phase':<14}{'self s':>12}{'spans':>10}{'tasks':>8}"
+                     f"{'share %':>9}")
+        known = [p for p in PHASES if p in record["phases"]]
+        for name in known + sorted(set(record["phases"]) - set(known)):
+            e = record["phases"][name]
+            lines.append(
+                f"{name:<14}{e['self_ns'] / 1e9:>12.6f}{e['count']:>10}"
+                f"{e.get('tasks', ''):>8}"
+                f"{100.0 * e['self_ns'] / (root * n or 1):>9.2f}")
+        mine = record["by_thread"][record["caller_thread"]]
+        lines.append(f"calling thread {record['caller_thread']}: other "
+                     f"{mine['other_ns'] / 1e9:.6f} s "
+                     f"({100.0 * mine['other_ns'] / (root or 1):.2f}% of the "
+                     f"root span)")
+    if record.get("by_device"):
+        lines.append(f"{'manager':<14}{'wall s':>12}{'count':>10}"
+                     f"{'share %':>17}")
+        for name in BRACKETS:
+            e = record["manager"][name]
+            lines.append(
+                f"{name:<14}{e['wall_ns'] / 1e9:>12.6f}{e['count']:>10}"
+                f"{100.0 * e['wall_ns'] / (root or 1):>17.2f}")
+        managers = len(record["by_device"])
+        inside = sum(e["wall_ns"] for e in record["manager"].values())
+        lines.append(f"in no bracket: {(root - inside / managers) / 1e9:.6f} "
+                     f"s of the root span (the brackets' mean over "
+                     f"{managers} manager(s) taken out)")
     t0 = record["t0_ns"]
     for i, part in enumerate(record.get("parts", ())):
         lines.append(

@@ -11,8 +11,9 @@ on the clock of ``obs.phases``, when it was enqueued, when its first
 device call left (stamped by the device module, 0 if it made none) and
 when it completed.  Between one part's completion and the next part's
 first device call no device has anything queued: ``compound_gap_ns``
-sums those boundaries.  When the last part ends the records go to the
-root span the compound ran under, if any (``obs.phases``).
+sums those boundaries.  When the last part ends the records go into the
+record of the root span the compound ran under, if any (``obs.phases``),
+traced or not.
 """
 from __future__ import annotations
 
@@ -42,10 +43,10 @@ class CompoundTaskpool(Taskpool):
 
     def _launch_next(self, context) -> None:
         if self._idx >= len(self.parts):
-            clock = context._phase_clock
-            if clock is not None:
-                clock.note_compound(self.records,
-                                    compound_gap_ns(self.records))
+            call = context._root_call
+            if call is not None:    # the last compound's, if it runs several
+                call["parts"] = self.records
+                call["compound_gap_ns"] = compound_gap_ns(self.records)
             self.pending_action_done()
             return
         sub = self.parts[self._idx]

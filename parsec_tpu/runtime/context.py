@@ -164,6 +164,9 @@ class Context:
         # and clears it); None keeps park / progress_engines on a
         # one-attribute-check fast path
         self._phase_clock = None
+        # what the open root span's taskpools leave for its record (a
+        # dict root_span sets and clears); None outside a root span
+        self._root_call = None
         self._forensics_dumped = False
         if profile or prof_prefix:
             self.profile = Profile(rank=rank)

@@ -506,8 +506,9 @@ def _assert_burst_result(cs, nb=16, chain=False):
 
 def test_stacked_call_retires_as_one_record():
     """A stacked call of 8 tasks: ONE record retired, one ``epilog``
-    span from ``_epilog`` and one from ``_retire``, and
-    every task still has its own ``complete`` span."""
+    span from ``_epilog`` and one ``chip_wait`` span from ``_retire``
+    (the always-on brackets count the same), and every task still has
+    its own ``complete`` span."""
     from parsec_tpu.obs import phases
     from parsec_tpu.utils.params import params
     with params.cmdline_override("device_tpu_max", "1"), \
@@ -524,7 +525,10 @@ def test_stacked_call_retires_as_one_record():
         assert dev.stats["tasks"] == 8
         assert dev.stats["batches"] == 1 and dev.stats["batched_tasks"] == 8
         assert dev.stats["retired_calls"] == 1
-        assert rec["phases"]["epilog"]["count"] == 2
+        assert rec["phases"]["epilog"]["count"] == 1
+        assert rec["phases"]["chip_wait"]["count"] == 1
+        assert [rec["manager"][b]["count"] for b in phases.BRACKETS] \
+            == [1, 1, 1, 1, 1, 1]
         assert rec["phases"]["complete"]["count"] == 8
         assert rec["phases"]["release_deps"]["count"] == 8
         assert dev._window == [] and dev._window_tasks == 0

@@ -146,7 +146,8 @@ def test_record_with_profile_switch_and_chrome_export():
 
 
 # ---------------------------------------------------------------- #
-# (b) no session, no profile switch: nothing is switched on        #
+# (b) no session, no profile switch: nothing is switched on, and   #
+# the call's record holds its stamps and the manager block alone   #
 # ---------------------------------------------------------------- #
 def test_no_session_no_record(one_device_ctx, monkeypatch):
     phases.clear_completed()
@@ -165,8 +166,13 @@ def test_no_session_no_record(one_device_ctx, monkeypatch):
     assert active is False and clock is None
     assert all(p is None for p in dev_phases)
     assert all(o is None for o in dev_obs)
-    assert phases.completed() == []
+    rec, = phases.completed()
+    assert rec["traced"] is False and rec["op"] == "dpotrf"
+    assert "phases" not in rec and "by_thread" not in rec
+    assert set(rec["manager"]) == set(phases.BRACKETS)
     assert all(d._obs is None for d in one_device_ctx.devices)
+    assert one_device_ctx._root_call is None
+    phases.clear_completed()
 
 
 # ---------------------------------------------------------------- #
@@ -193,7 +199,8 @@ def test_spans_in_xplane_nested_in_callers_annotation(one_device_ctx,
     names = {n for spans in by_line for n, _, _, _ in spans}
     for want in ("parsec:op", "parsec:exec", "parsec:complete",
                  "parsec:release_deps", "parsec:manager", "parsec:stage_in",
-                 "parsec:epilog", "parsec:prepare_input"):
+                 "parsec:epilog", "parsec:chip_wait",
+                 "parsec:prepare_input"):
         assert want in names, (want, sorted(names))
     assert names & {"parsec:dispatch", "parsec:first_call"}
     # idle workers' microsecond spans are booked, not annotated
@@ -352,7 +359,8 @@ def test_first_calls_move_once_per_process(no_programs, one_worker_ctx):
         == after["batches"] - mid["batches"] >= 1
     assert after["stage_in_bytes"] > 0
     assert after["stage_in_peer_bytes"] == 0
-    assert phases.completed() == []     # counters need no session
+    # counters need no session, and no record has a phase table
+    assert not any("phases" in r for r in phases.completed())
 
 
 def test_stage_in_peer_bytes_counts_chip_to_chip(ctx4):
@@ -451,10 +459,13 @@ def test_clock_books_balance_under_thread_contention():
 
 def test_completed_is_bounded():
     phases.clear_completed()
-    for i in range(70):
+    cap = phases._COMPLETED_MAX
+    assert cap >= 64        # a window's calls and set-up's two fit
+    for i in range(cap + 6):
         phases._completed.append(phases.PhaseClock("x", i, False).close())
     got = phases.completed()
-    assert len(got) == 64 and got[0]["id"] == 6 and got[-1]["id"] == 69
+    assert len(got) == cap and got[0]["id"] == 6 \
+        and got[-1]["id"] == cap + 5
     phases.clear_completed()
 
 
