@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular as _solve_tri
 
+from . import pallas_kernels
+
 
 @jax.jit
 def potrf(t: Any) -> Any:
@@ -249,7 +251,17 @@ def _lu_strip(st: Any, d0: Any) -> Any:
     a tie), its row is exchanged with row ``d0 + i`` in all w columns,
     the multipliers replace the column under the diagonal and the
     strip's later columns are updated.  Returns (the strip, the
-    interchanges as a gather over all N rows, the w pivot rows)."""
+    interchanges as a gather over all N rows, the w pivot rows).
+
+    This is the lowering for every platform but the TPU, and for the
+    shapes the kernel does not take: the strip is the carry of an XLA
+    loop, and XLA keeps a carry in HBM, so on the v5e each step reads
+    and writes the whole strip in several operations (7 us a step at
+    (32, 16384)).  On the TPU :func:`_lu_strip_lowered` runs the same
+    steps as one Mosaic kernel over the strip held in VMEM
+    (``pallas_kernels.lu_strip_vmem``, 1.4 us a step), with the same
+    pivots and, wherever no inactive row holds a negative zero, the same
+    bits."""
     w, n = st.shape
     lane = jnp.arange(n, dtype=jnp.int32)
     col = jnp.arange(w, dtype=jnp.int32)[:, None]
@@ -279,19 +291,36 @@ def _lu_strip(st: Any, d0: Any) -> Any:
                              (st, lane, jnp.zeros((w,), jnp.int32)))
 
 
+def _lu_strip_lowered(st: Any, d0: Any) -> Any:
+    """:func:`_lu_strip` in the lowering the program's platform and the
+    strip's shape call for: on the TPU, for a strip the kernel takes
+    (``pallas_kernels.lu_strip_fits``), the Mosaic kernel that keeps the
+    strip in VMEM; the XLA loop anywhere else."""
+    if not pallas_kernels.lu_strip_fits(*st.shape):
+        return _lu_strip(st, d0)
+    return jax.lax.platform_dependent(
+        st, d0, tpu=pallas_kernels.lu_strip_vmem, default=_lu_strip)
+
+
 def _lu_panel(x: Any, r: Any) -> Any:
     """LU with exact partial pivoting of rows r.. of the (N, nb) block
     column ``x`` at its full static height, ``r`` an operand.
     Right-looking over strips of :data:`LU_STRIP` columns: a strip is
-    factored over all the active rows (:func:`_lu_strip`), its
-    interchanges are applied to the panel's other columns in one
-    gather, the strip's block row is solved and the columns to its
-    right updated (the rows at or above the block row masked out of
-    the product).  ``lax.linalg.lu`` is not used: on the TPU it is
-    XLA's ``LuDecomposition``, which holds every row of a 128-column
-    strip twice in 16 MiB of scoped VMEM and is refused at compile time
-    from 16384 rows on.  Returns (the column, the interchanges as a
-    gather g: after[i] = before[g[i]], the nb pivot rows)."""
+    factored over all the active rows (:func:`_lu_strip_lowered`: in
+    VMEM by one Mosaic kernel where the program is lowered for the TPU
+    and the strip's shape fits, by :func:`_lu_strip`'s XLA loop
+    anywhere else), its interchanges are applied to the panel's other
+    columns in one gather, the strip's block row is solved and the
+    columns to its right updated (the rows at or above the block row
+    masked out of the product).  These strip passes run at the full
+    height in XLA on every platform, and on the v5e they are now most
+    of a panel (PERF.md section 5).  ``lax.linalg.lu`` is still not
+    used: on the TPU it is XLA's ``LuDecomposition``, which holds every
+    row of a 128-column strip twice in 16 MiB of scoped VMEM and is
+    refused at compile time from 16384 rows on; the strip kernel asks
+    for the VMEM its own (w, N) needs and compiles at N = 57344.
+    Returns (the column, the interchanges as a gather g: after[i] =
+    before[g[i]], the nb pivot rows)."""
     n, nb = x.shape
     lane = jnp.arange(n, dtype=jnp.int32)
     g = lane
@@ -299,7 +328,7 @@ def _lu_panel(x: Any, r: Any) -> Any:
     for c0 in range(0, nb, LU_STRIP):
         c1 = min(c0 + LU_STRIP, nb)
         d0 = r + c0
-        st, gs, pv = _lu_strip(x[:, c0:c1].T, d0)
+        st, gs, pv = _lu_strip_lowered(x[:, c0:c1].T, d0)
         x = jnp.take(x, gs, axis=0, unique_indices=True, mode="clip")
         x = x.at[:, c0:c1].set(st.T)
         g = g[gs]

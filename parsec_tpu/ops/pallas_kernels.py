@@ -4,7 +4,9 @@ The reference keeps its hot math in hand-tuned native kernels (CUDA chores
 generated per task class, ref: parsec/interfaces/ptg/ptg-compiler/jdf2c.c:6557;
 the lone .cu kernel tests/dsl/dtd/dtd_test_new_tile_cuda_kernels.cu). The
 TPU-native analog is Pallas: Mosaic kernels that tile onto MXU/VPU with
-explicit VMEM residency. Two kernels live here:
+explicit VMEM residency. Three kernels live here; ``lu_strip_vmem`` is
+the one a task body of the runtime runs, the other two serve the
+transformer model and the ring-attention layer and no task class:
 
 - ``flash_attention``: blockwise online-softmax attention (fwd is a single
   Pallas kernel with grid (BH, q_blocks, k_blocks); m/l/acc live in VMEM
@@ -14,13 +16,21 @@ explicit VMEM residency. Two kernels live here:
 - ``matmul``: blocked GEMM with a float32 VMEM accumulator across the
   sequential K grid dimension (the MXU-feeding pattern the dpotrf update
   kernels ride on).
+- ``lu_strip_vmem``: one strip of LU's pivoted panel (``ops.linalg.
+  _lu_panel``, task class PANEL of ``ops.dgetrf_1d``) held in VMEM for
+  all its column steps.  ``ops.linalg._lu_strip_lowered`` picks it by the
+  platform a program is lowered for and by the strip's shape; it reads
+  no parameter, ``use_pallas`` and ``_on_tpu`` below are not asked.
 
-Off-TPU (the virtual-CPU test mesh) the same kernels run with
-``interpret=True``, so tests validate the exact kernel code path.
+Off-TPU (the virtual-CPU test mesh) ``flash_attention`` and ``matmul``
+run with ``interpret=True``, so tests validate the exact kernel code
+path; ``lu_strip_vmem`` is not lowered there at all (the XLA loop is)
+and its tests pass ``interpret=True`` themselves.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -456,3 +466,115 @@ def _matmul_impl(a: Any, b: Any, block_m: int, block_n: int,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(a, b)
+
+
+# ---------------------------------------------------------------------------
+# One strip of LU's pivoted panel, held in VMEM for all its column steps
+# ---------------------------------------------------------------------------
+
+#: what a strip kernel may ask of a core's VMEM: working sets above this
+#: take the XLA loop (the smallest VMEM of the chips in use is 64 MiB)
+_LU_STRIP_VMEM_MAX = 48 << 20
+
+
+def _lu_strip_vmem_bytes(w: int, n: int) -> int:
+    """The VMEM one strip kernel asks for: the strip as it comes in and
+    as it goes out, the gather, and room for Mosaic's own scratch."""
+    return 2 * w * n * 4 + n * 4 + (4 << 20)
+
+
+def lu_strip_fits(w: int, n: int) -> bool:
+    """The shape rule of :func:`lu_strip_vmem`: the N rows fill whole
+    lanes and the working set fits the VMEM the kernel asks for."""
+    return n % 128 == 0 and _lu_strip_vmem_bytes(w, n) <= _LU_STRIP_VMEM_MAX
+
+
+def _lu_strip_kernel(d0_ref, st_ref, out_ref, g_ref, piv_ref,
+                     *, w: int, rows: int, ch: int):
+    # row position r*128 + l of the strip's (rows, 128) view of a column
+    pos = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
+    cpos = (jax.lax.broadcasted_iota(jnp.int32, (1, ch, 128), 1) * 128
+            + jax.lax.broadcasted_iota(jnp.int32, (1, ch, 128), 2))  # in a chunk
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2)
+    lane2 = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (w, 1, 1), 0)
+    n, d0 = rows * 128, d0_ref[0]
+    out_ref[...] = st_ref[...]
+    g_ref[...] = pos
+
+    def exchange(ref, at_d, at_p, is_d, is_p, lo):
+        # entries d and p of every column (a max over one lane keeps the
+        # bits, the sign of a zero too); p may be d: read p again
+        sd = ref[at_d]
+        xd = jnp.max(jnp.where(is_d, sd, lo), axis=-1, keepdims=True)
+        xp = jnp.max(jnp.where(is_p, ref[at_p], lo), axis=-1, keepdims=True)
+        ref[at_d] = jnp.where(is_d, xp, sd)
+        ref[at_p] = jnp.where(is_p, xd, ref[at_p])
+        return xp
+
+    def step(i, carry):
+        d = d0 + i
+        a = jnp.where(pos >= d, jnp.abs(out_ref[pl.ds(i, 1)][0]), -1.0)
+        p = jnp.min(jnp.where(a == jnp.max(a), pos, n))   # first on a tie
+        rd, ld, rp, lp = d // 128, d % 128, p // 128, p % 128
+        xp = exchange(out_ref, (slice(None), pl.ds(rd, 1)),
+                      (slice(None), pl.ds(rp, 1)), lane == ld, lane == lp,
+                      -jnp.inf)                             # (w, 1, 1)
+        exchange(g_ref, pl.ds(rd, 1), pl.ds(rp, 1), lane2 == ld, lane2 == lp,
+                 -1)
+        pivot = jnp.max(jnp.where(col == i, xp, -jnp.inf))
+        right = jnp.where(col > i, xp, 0.0)                 # (w, 1, 1)
+
+        def chunk(c, carry):
+            r0 = pl.multiple_of(c * ch, ch)
+            rs = pl.ds(r0, ch)
+            x = out_ref[:, rs]
+            mult = out_ref[pl.ds(i, 1), rs] / pivot
+            out_ref[:, rs] = jnp.where(
+                cpos + r0 * 128 > d,                        # the rows under d
+                jnp.where(col == i, mult, x - right * mult), x)
+            return carry
+
+        # the chunks of rows wholly above d hold nothing active
+        jax.lax.fori_loop(d // (128 * ch), rows // ch, chunk, 0)
+        piv_ref[i] = p
+        return carry
+
+    jax.lax.fori_loop(0, w, step, 0)
+
+
+def lu_strip_vmem(st: Any, d0: Any, *, interpret: bool = False) -> Any:
+    """``ops.linalg._lu_strip`` as ONE Mosaic kernel: the (w, N) strip is
+    brought into VMEM once, its w column steps run there, and the strip,
+    the gather and the w pivot rows are written back once.
+
+    The strip is viewed as (w, N/128, 128): a column is N/1024 dense
+    vregs and row i sits at ``[i // 128, i % 128]``.  A step searches its
+    column (two reductions to a scalar: the largest magnitude among the
+    active rows, then the first row that has it), exchanges rows d and p
+    through two one-sublane slices, and updates the strip in ONE pass
+    that walks chunks of 8 sublanes from the one that holds row d down.
+    The arithmetic is ``_lu_strip``'s to the operation: one divide a
+    multiplier, a multiply then a subtract; the rows above ``d0`` are
+    never written.  Shapes: :func:`lu_strip_fits`.  Reads no parameter;
+    ``interpret`` is for the tests on the CPU."""
+    w, n = st.shape
+    rows = n // 128
+    kernel = functools.partial(_lu_strip_kernel, w=w, rows=rows,
+                               ch=math.gcd(rows, 8))
+    out, g, piv = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((w, rows, 128), st.dtype),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.int32),
+                   jax.ShapeDtypeStruct((w,), jnp.int32)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
+                   pl.BlockSpec(memory_space=pltpu.VMEM),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_lu_strip_vmem_bytes(w, n)),
+        name="lu_strip_vmem", interpret=interpret,
+    )(jnp.reshape(d0, (1,)).astype(jnp.int32), st.reshape(w, rows, 128))
+    return out.reshape(w, n), g.reshape(n), piv
