@@ -4,14 +4,19 @@ to come out not correct.
 
     python3 perfbench/checks/broken_kernel.py --workload <cell> \
         --kernel getrf_1d_laswp --returns 0 [--seed N]
+    python3 perfbench/checks/broken_kernel.py --workload <cell> \
+        --kernel gemm --drops 4
 
 One process.  It runs the cell's timed path (``run.Factorizer``: the same
 entry point, tiling and sizes) once as it is and once with
 ``parsec_tpu.ops.<kernel>`` replaced by a function that hands back its
-argument number ``--returns`` unchanged, and prints the number the cell's
-check compares beside the configuration's limit both times: the first
-has to pass and the second to miss.  Exit code 0 when both do.  Never
-run by the benchmark's own runs.
+argument number ``--returns`` unchanged, or with ``--drops N`` by the
+kernel itself called without its arguments from number N on, whose
+defaults then apply (``gemm(c, a, b, alpha, beta)`` without its
+``beta``), and prints the number the cell's check compares beside the
+configuration's limit both times: the first has to pass and the second
+to miss.  Exit code 0 when both do.  Never run by the benchmark's own
+runs.
 """
 import argparse
 import os
@@ -28,6 +33,7 @@ def main():
     ap.add_argument("--workload", required=True)
     ap.add_argument("--kernel", required=True)
     ap.add_argument("--returns", type=int, default=0)
+    ap.add_argument("--drops", type=int, default=None)
     ap.add_argument("--seed", type=int, default=2 ** 31 + 4242)
     args = ap.parse_args()
 
@@ -38,25 +44,24 @@ def main():
     jax.config.update("jax_default_matmul_precision",
                       cell.config["matmul_precision"])
     harness.gate_device(jax, cell, rehearse=False)
-    import numpy as np
     import parsec_tpu
     from parsec_tpu import ops
     ref = cell.reference()
     limit = float(cell.config["check"]["limit"])
-    M = ref.make_input(cell.sizes["N"], args.seed).astype(
-        np.dtype(cell.config["storage_dtype"]), copy=False)
+    M = harness.seeded_input(ref, cell, args.seed)
     exp = ref.expected(M, args.seed)
     sound = getattr(ops, args.kernel)
+    broken = (lambda *a: a[args.returns]) if args.drops is None \
+        else (lambda *a: sound(*a[:args.drops]))
     readings = {}
     ctx = parsec_tpu.init()
     fz = harness.Factorizer(jax, ctx, cell, M, harness.HostClocks(jax))
     try:
-        for label, kernel in (("sound", sound),
-                              ("broken", lambda *a: a[args.returns])):
+        for label, kernel in (("sound", sound), ("broken", broken)):
             setattr(ops, args.kernel, kernel)
-            A = fz.tile()
-            _, _, why = fz.factor(A)
-            readings[label] = ref.residual(A.to_numpy(), exp)
+            operands = fz.tile()
+            _, _, why = fz.factor(operands)
+            readings[label] = ref.residual(fz.pull(operands), exp)
             print(f"{cell.name} {args.kernel} {label}: residual "
                   f"{readings[label]:.6e} (limit {limit:g})"
                   f"{' FAILED: ' + why if why else ''}", flush=True)

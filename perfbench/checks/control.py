@@ -38,7 +38,6 @@ def main():
     cell = spec.Cell(spec.load_benchmark(), args.workload)
     import jax
     device, _ = harness.gate_device(jax, cell, rehearse=False)
-    import numpy as np
     import parsec_tpu
     ref = cell.reference()
     limit = float(cell.config["check"]["limit"])
@@ -51,18 +50,17 @@ def main():
             readings[precision] = []
             for i in range(args.seeds):
                 seed = args.base + 7919 * i
-                M = fz.M = ref.make_input(cell.sizes["N"], seed).astype(
-                    np.dtype(cell.config["storage_dtype"]), copy=False)
-                A = fz.tile()
-                wall, _, why = fz.factor(A)
+                M = fz.M = harness.seeded_input(ref, cell, seed)
+                operands = fz.tile()
+                wall, _, why = fz.factor(operands)
                 t = time.perf_counter()
-                res = ref.residual(A.to_numpy(), ref.expected(M, seed))
+                res = ref.residual(fz.pull(operands), ref.expected(M, seed))
                 readings[precision].append(res)
                 print(f"control {cell.name} {precision} seed {seed}: "
                       f"residual {res:.6e} (limit {limit:g}) factor "
                       f"{wall:.3f} s check {time.perf_counter() - t:.1f} s"
                       f"{' FAILED: ' + why if why else ''}", flush=True)
-                del A, M
+                del operands, M
     finally:
         ctx.fini()
     summary = {"cell": cell.name, "device": device, "limit": limit,

@@ -182,7 +182,6 @@ def main(argv=None):
         result = attribute(xplane.load(args.xplane), xplane)
     else:
         import jax
-        import numpy as np
         cell = spec.Cell(spec.load_benchmark(), args.workload)
         jax.config.update("jax_default_matmul_precision",
                           cell.config["matmul_precision"])
@@ -192,8 +191,7 @@ def main(argv=None):
             print(f"idle_by_phase: REFUSED: {exc}", file=sys.stderr)
             return 2
         import parsec_tpu
-        M = cell.reference().make_input(cell.sizes["N"], args.seed).astype(
-            np.dtype(cell.config["storage_dtype"]), copy=False)
+        M = harness.seeded_input(cell.reference(), cell, args.seed)
         ctx = parsec_tpu.init()
         tmp = tempfile.mkdtemp(prefix="perfbench_trace_")
         try:

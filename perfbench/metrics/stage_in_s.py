@@ -1,6 +1,9 @@
 """Data movement: host seconds per factorization resolving tasks' input
-flows to arrays on the chip (``_stage_in``, the prefetcher, every
-``device_put`` they issue); self time, all threads."""
+flows to arrays on the chip: ``_stage_in_set`` once per drained ready
+set (the look at every input flow of the set and the ONE ``device_put``
+of its host tiles, since PR 34), ``_stage_in`` per task (which then
+finds those tiles resident), every ``device_put`` either issues; self
+time, all threads."""
 from perfbench import spans
 
 

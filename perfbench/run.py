@@ -1,19 +1,24 @@
 #!/usr/bin/env python3
-"""One run of one cell: seconds per checked factorization through the
-entry point a user calls, with no knob set.
+"""One run of one cell: seconds per checked call of the entry point a
+user calls, with no knob set.  In most cells the call is a
+factorization of one matrix in place, and the metrics keep that name
+(``factor_s`` is the seconds per timed call); what the call is otherwise
+is the ``note`` of its operation file, printed with the cell.
 
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 ONE process; it owns the chip(s) for its whole life.  Set-up (all of it
 is ``setup_s``): imports, the native core, the input built on the host
-from ``--seed``, ``parsec_tpu.init()`` with nothing set, one cold
-factorization (compiles, or loads from the persistent cache) and one
-more to warm.  Then the window: a closed loop, one caller,
-factorizations back to back until ``--seconds`` have passed, each timed
-from the entry-point call to ``block_until_ready`` on every tile's
-newest copy.  After the window the warm-up factor and the window's last
-one are held against the plain float64 reference (``reference/``), and
-the last line of stdout is the one JSON object of the contract.
+from ``--seed``, ``parsec_tpu.init()`` with nothing set, one cold call
+(compiles, or loads from the persistent cache), the calls on small grids
+an operation file may ask for (``warm_up``) and one more to warm.
+Then the window: a closed loop, one caller, calls back to back until
+``--seconds`` have passed, each timed from the entry-point call
+``entry(ctx, *operands, **args)`` to ``block_until_ready`` on every
+tile's newest copy of every operand the call writes.  After the window
+the warm-up result and the window's last one are held against the plain
+float64 reference (``reference/``), and the last line of stdout is the
+one JSON object of the contract.
 
 It fails, printing no result, unless JAX's default backend is a TPU
 whose ``device_kind`` is in ``peaks.json`` and exactly the cell's chips
@@ -21,8 +26,8 @@ are there.  ``--rehearse N,NB`` is the CPU dry run: tiny sizes, every
 line marked REHEARSAL, every time and device number "not measured", and
 never the contract's last line.
 
-Which cell, configuration, traffic, operation, kernel class and metric
-exist is data (``spec.py``); nothing in this file names one.
+Which cell, configuration, traffic, operation, operand, kernel class and
+metric exist is data (``spec.py``); nothing in this file names one.
 """
 import sys
 import time
@@ -33,6 +38,7 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
@@ -47,9 +53,9 @@ if ROOT not in sys.path:
 from perfbench import roofline, spec, xplane  # noqa: E402
 
 DOWNGRADE_KEYS = ("batch_downgrades", "donate_retries", "mesh_downgrades")
-#: factorizations of the window the profiler is on for in a --trace 1
-#: run (the second and third: a whole window's trace is too large to
-#: reduce), and consecutive raising factorizations that end a window
+#: calls of the window the profiler is on for in a --trace 1 run (the
+#: second and third: a whole window's trace is too large to reduce), and
+#: consecutive raising calls that end a window
 N_TRACED = 2
 MAX_RAISED_IN_A_ROW = 3
 NOT_MEASURED = "not measured"
@@ -104,11 +110,22 @@ def deltas(devs, before):
             for d, b in zip(devs, before)]
 
 
-def block_on_tiles(jax, A):
+def block_on_tiles(jax, written):
     """``wait()`` returns at dispatch; the work is done when every
-    tile's newest copy is ready."""
+    tile's newest copy is ready, of every collection the call wrote."""
     jax.block_until_ready([A.data_of(*c).newest_copy().payload
-                           for c in A.tiles()])
+                           for A in written for c in A.tiles()])
+
+
+def seeded_input(ref, cell, seed):
+    """The reference's input from the seed in the configuration's
+    storage type: one array, or a dict of arrays by operand name."""
+    import numpy as np
+    dtype = np.dtype(cell.config["storage_dtype"])
+    M = ref.make_input(cell.sizes["N"], seed)
+    if isinstance(M, dict):
+        return {k: v.astype(dtype, copy=False) for k, v in M.items()}
+    return M.astype(dtype, copy=False)
 
 
 def memory_peak(jax):
@@ -167,31 +184,48 @@ class Factorizer:
         self.jax, self.ctx, self.M, self.clocks = jax, ctx, M, clocks
         self.entry = cell.entry()
         self.collection = cell.collection()
+        self.names = [name for name, _ in cell.operands]
+        self.written = [name for name, mode in cell.operands
+                        if mode == "inout"]
+        self.args = cell.args
         self.nb = cell.sizes["NB"]
         self.devs = accel_devices(ctx)
         self.want_tasks = cell.n_tasks()
         self.refill = cell.traffic["matrix"] == "refilled"
-        self.A = None
+        self.tiled = None
 
     def tile(self):
-        """The input tiled for the next factorization (host work,
-        outside the timer): by the traffic file either the caller's one
-        tiled matrix filled again from the input, or a new collection
-        each time."""
-        if self.A is None or not self.refill:
-            n = self.M.shape[0]
-            self.A = self.collection(n, n, self.nb, self.nb,
-                                     dtype=self.M.dtype)
-        return self.A.from_numpy(self.M)
+        """The input tiled for the next call (host work, outside the
+        timer), one square collection per operand in the entry point's
+        order: by the traffic file either the caller's tiled matrices
+        filled again from the input, every one of them, or new
+        collections each time."""
+        M = self.M if isinstance(self.M, dict) else {self.names[0]: self.M}
+        if self.tiled is None or not self.refill:
+            self.tiled = {}
+            for name in self.names:
+                n = M[name].shape[0]
+                self.tiled[name] = self.collection(n, n, self.nb, self.nb,
+                                                   dtype=M[name].dtype)
+        return {name: A.from_numpy(M[name])
+                for name, A in self.tiled.items()}
 
-    def factor(self, A):
-        """(wall seconds, per-device counter deltas, why it failed or
-        None)."""
+    def pull(self, operands):
+        """What the call wrote, on the host, as the reference's
+        ``residual`` takes it: the one ``inout`` operand's array, or a
+        dict by name where there are several."""
+        out = {name: operands[name].to_numpy() for name in self.written}
+        return out if len(out) > 1 else out[self.written[0]]
+
+    def factor(self, operands):
+        """One timed call.  (wall seconds, per-device counter deltas,
+        why it failed or None)."""
         before = snapshot(self.devs)
         host0 = self.clocks.read()
+        written = [operands[name] for name in self.written]
         t0 = time.perf_counter()
-        self.entry(self.ctx, A)
-        block_on_tiles(self.jax, A)
+        self.entry(self.ctx, *operands.values(), **self.args)
+        block_on_tiles(self.jax, written)
         wall = time.perf_counter() - t0
         d = deltas(self.devs, before)
         # the host's own clocks ride with the first device's counters
@@ -209,12 +243,43 @@ class Factorizer:
         return wall, d, why
 
 
+def warm_smaller(fz, cell):
+    """The operation file's ``warm_up``: calls of the entry point on
+    small grids of the cell's tile, zeros in them, between the cold call
+    and the warm one.  A k-level of 2 to 16 ready tasks reaches the
+    manager whole, or a task or two ahead of the rest, and is dispatched
+    as stacked calls of 2, 4 and 8, so these calls build here the small
+    buckets that a call at the cell's size meets once in a dozen calls,
+    which would else be built in the window.  Nothing of
+    them is checked but that no fast rung gave way.  Returns (programs
+    built or loaded, of them from the persistent cache, what failed)."""
+    import numpy as np
+    dtype, nb = np.dtype(cell.config["storage_dtype"]), fz.nb
+    tiled = [{name: (fz.collection(r * nb, c * nb, nb, nb, dtype=dtype),
+                     np.zeros((r * nb, c * nb), dtype))
+              for name, (r, c) in grid.items()}
+             for grid in cell.warm_up_grids()]
+    before, host0 = snapshot(fz.devs), fz.clocks.read()
+    for _ in range(cell.warm_up["rounds"]):
+        for grid in tiled:
+            operands = {name: A.from_numpy(Z) for name, (A, Z) in grid.items()}
+            fz.entry(fz.ctx, *operands.values(), **fz.args)
+            block_on_tiles(fz.jax, [operands[name] for name in fz.written])
+    moved = {key: sum(x.get(key, 0) for x in deltas(fz.devs, before))
+             for key in DOWNGRADE_KEYS}
+    host = fz.clocks.read()
+    return (host["loads"] - host0["loads"],
+            host["cache_hits"] - host0["cache_hits"],
+            [f"small set-up calls: {key} moved by {n}: a fast rung gave way"
+             for key, n in moved.items() if n])
+
+
 def run_window(fz, seconds, trace_dir):
     """The closed loop; with a ``trace_dir`` the profiler is on for the
-    window's second to (1 + N_TRACED)th factorization.  Returns what
-    the window saw: walls, per-factorization counter deltas, bytes in
-    use after each, failure reasons, the count started, the last tiled
-    matrix that finished, the count traced."""
+    window's second to (1 + N_TRACED)th call.  Returns what the window
+    saw: walls, per-call counter deltas, bytes in use after each,
+    failure reasons, the count started, the tiled operands of the last
+    call that finished, the count traced."""
     jax = fz.jax
     w = types.SimpleNamespace(walls=[], per_factor=[], in_use=[],
                               reasons=[], attempted=0, last=None,
@@ -235,23 +300,23 @@ def run_window(fz, seconds, trace_dir):
             w.attempted += 1
             try:
                 with jax.profiler.TraceAnnotation("perfbench:tile_input"):
-                    A = fz.tile()
+                    operands = fz.tile()
                 with jax.profiler.TraceAnnotation("perfbench:entry_call"):
-                    wall, d, why = fz.factor(A)
+                    wall, d, why = fz.factor(operands)
             except Exception:   # the boundary: count it, go on
-                w.reasons.append(f"factorization {w.attempted} raised:\n"
+                w.reasons.append(f"call {w.attempted} raised:\n"
                                  f"{traceback.format_exc()}")
                 raised_in_a_row += 1
                 if raised_in_a_row >= MAX_RAISED_IN_A_ROW:
                     break
                 continue
             raised_in_a_row = 0
-            w.last = A
+            w.last = operands
             w.walls.append(wall)
             w.per_factor.append(d)
             w.in_use.append(memory_in_use(jax))
             if why:
-                w.reasons.append(f"factorization {w.attempted}: {why}")
+                w.reasons.append(f"call {w.attempted}: {why}")
             if profiling:
                 w.n_traced += 1
                 if w.n_traced == N_TRACED:
@@ -261,7 +326,7 @@ def run_window(fz, seconds, trace_dir):
 
 
 def sum_counters(per_factor):
-    """(summed over factorizations and devices, summed per device)."""
+    """(summed over calls and devices, summed per device)."""
     if not per_factor:
         return {}, []
     by_dev = []
@@ -280,23 +345,28 @@ def sum_counters(per_factor):
 
 def check_factors(ref, cell, M, seed, say, factors):
     """The comparison that decides ``correct``: after the window,
-    outside set-up, each factor pulled from the chip against the plain
+    outside set-up, each result pulled from the chip against the plain
     reference, the number printed beside its limit.  Returns what
-    missed."""
+    missed, and every number compared with its limit by a short name."""
     limit = float(cell.config["check"]["limit"])
     exp = ref.expected(M, seed)
-    misses = []
+    misses, compared = [], {}
     for label, factor in factors.items():
         if factor is None:
-            misses.append(f"{label}: no factor to check")
+            misses.append(f"{label}: no result to check")
             continue
         res = ref.residual(factor, exp)
         ok = bool(res <= limit)     # a NaN residual is not correct
+        # the last line stays JSON whatever the number: inf and nan go
+        # as their names
+        compared[label.replace(" ", "_").replace("-", "_") + "_residual"] \
+            = {"value": res if math.isfinite(res) else repr(res),
+               "limit": limit}
         say(f"check {label}: residual {res:.6e} against limit {limit:g}: "
             f"{'ok' if ok else 'MISSED'}")
         if not ok:
-            misses.append(f"{label} factorization missed its tolerance")
-    return misses
+            misses.append(f"{label} call missed its tolerance")
+    return misses, compared
 
 
 def run_cell(args, say):
@@ -319,7 +389,6 @@ def run_cell(args, say):
     jax.config.update("jax_default_matmul_precision",
                       cell.config["matmul_precision"])
     device, peaks = gate_device(jax, cell, rehearse)
-    import numpy as np
     try:
         import parsec_tpu
         from parsec_tpu import native
@@ -334,6 +403,10 @@ def run_cell(args, say):
         f"{cell.n_tasks()} tasks {cell.kernel_counts()}, "
         f"{cell.flops() / 1e12:.3f} TFLOP, matmul precision "
         f"{cell.config['matmul_precision']}, mca {cell.config.get('mca', {})}")
+    say(f"one call is {cell.op['entry']}(ctx, "
+        f"{', '.join(f'{n} [{m}]' for n, m in cell.operands)}"
+        f"{''.join(f', {k}={v!r}' for k, v in cell.args.items())}): "
+        f"{cell.op.get('note', '')}")
     say(f"device {device}; jax {jax.__version__}; compile cache "
         f"{jax.config.jax_compilation_cache_dir}")
 
@@ -341,11 +414,12 @@ def run_cell(args, say):
 
     ref = cell.reference()
     t = time.perf_counter()
-    M = ref.make_input(cell.sizes["N"], args.seed).astype(
-        np.dtype(cell.config["storage_dtype"]), copy=False)
+    M = seeded_input(ref, cell, args.seed)
     # a time taken on the CPU is never printed: a rehearsal shows counts
     sec = (lambda x: NOT_MEASURED) if rehearse else (lambda x: f"{x:.4f}")
-    say(f"input {M.shape} {M.dtype} from seed {args.seed} in "
+    shapes = {k: (v.shape, str(v.dtype)) for k, v in M.items()} \
+        if isinstance(M, dict) else f"{M.shape} {M.dtype}"
+    say(f"input {shapes} from seed {args.seed} in "
         f"{sec(time.perf_counter() - t)} s")
     ctx = parsec_tpu.init()
     trace_dir = tempfile.mkdtemp(prefix="perfbench_trace_") if args.trace \
@@ -357,30 +431,38 @@ def run_cell(args, say):
         say(f"runtime devices {[d.name for d in ctx.devices]}, "
             f"{ctx.nb_cores} worker threads")
         setup_fail = []
-        A = fz.tile()
-        first_factor_s, _, why = fz.factor(A)
+        first_factor_s, _, why = fz.factor(fz.tile())
         peak_first = memory_peak(jax)
         if why:
-            setup_fail.append(f"cold factorization: {why}")
-        A = fz.tile()
-        warm_s, _, why = fz.factor(A)
+            setup_fail.append(f"cold call: {why}")
+        if cell.warm_up:
+            t = time.perf_counter()
+            loads, hits, fails = warm_smaller(fz, cell)
+            setup_fail += fails
+            say(f"{cell.warm_up['rounds']} rounds of "
+                f"{len(cell.warm_up['grids'])} calls on small grids in "
+                f"{sec(time.perf_counter() - t)} s: {loads} programs built "
+                f"or loaded, {hits} of them from the persistent cache; "
+                f"peak_bytes_in_use {memory_peak(jax)}")
+        operands = fz.tile()
+        warm_s, _, why = fz.factor(operands)
         if why:
-            setup_fail.append(f"warm-up factorization: {why}")
-        warm_factor = A.to_numpy()
-        del A
+            setup_fail.append(f"warm-up call: {why}")
+        warm_factor = fz.pull(operands)
+        del operands
         setup_s = time.perf_counter() - T_START
-        say(f"set-up {sec(setup_s)} s: first factorization "
+        say(f"set-up {sec(setup_s)} s: first call "
             f"{sec(first_factor_s)} s, second {sec(warm_s)} s; "
             f"peak_bytes_in_use {peak_first} after the first, "
             f"{memory_peak(jax)} after the second")
 
         w = run_window(fz, args.seconds, trace_dir)
         peak = memory_peak(jax)
-        last_factor = w.last.to_numpy() if w.last is not None else None
+        last_factor = fz.pull(w.last) if w.last is not None else None
     finally:
         ctx.fini()
 
-    misses = check_factors(ref, cell, M, args.seed, say, {
+    misses, compared = check_factors(ref, cell, M, args.seed, say, {
         "warm-up": warm_factor, "last of the window": last_factor})
     reasons = setup_fail + w.reasons + misses
     for r in reasons:
@@ -414,7 +496,7 @@ def run_cell(args, say):
             f"cache), {sec(counters['load_s'])} s in them; "
             f"{sec(counters['gc_s'])} s of garbage collection")
         for key in ("load_s", "gc_s"):
-            say(f"per factorization, {key}: "
+            say(f"per call, {key}: "
                 f"{[sec(d[0][key]) for d in w.per_factor]}")
     breakdown = None
     if args.trace:
@@ -433,7 +515,7 @@ def run_cell(args, say):
                 "device_ops": xplane.top(tr["ops_s"] or tr["modules_s"]),
                 "idle_gaps": xplane.top(tr["idle_by_label_s"], 4)
                 + [[f"longest:{n}", s] for n, s in tr["longest_gaps"][:6]]}
-            say(f"trace of {w.n_traced} factorization(s): window "
+            say(f"trace of {w.n_traced} call(s): window "
                 f"{tr['window_s']:.4f} s, busy {tr['busy_s']:.4f} s "
                 f"(by chip {tr['busy_by_chip_s']}); modules "
                 f"{xplane.top(tr['modules_s'], 5)}")
@@ -456,6 +538,12 @@ def run_cell(args, say):
               "metrics": metrics, "device": device}
     if breakdown:
         result["breakdown"] = breakdown
+    # each number compared beside its limit: the result's last key, and
+    # the run's last lines on standard error
+    result["compared"] = compared
+    for name, c in compared.items():
+        print(f"compared {name}: {c['value']} limit {c['limit']:g}",
+              file=sys.stderr, flush=True)
     return result
 
 

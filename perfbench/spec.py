@@ -2,9 +2,9 @@
 
 ``BENCHMARK.json`` names cells, configurations and metrics; a cell names
 its configuration and its traffic; a configuration names its operation;
-an operation names its entry point, its reference and its kernel
-classes.  Adding any of them is adding files and entries: nothing here
-or in ``run.py`` lists a name.
+an operation names its entry point, its operands, its reference and
+its kernel classes.  Adding any of them is adding files and entries:
+nothing here or in ``run.py`` lists a name.
 """
 import importlib
 import importlib.util
@@ -18,6 +18,10 @@ ROOT = os.path.dirname(HERE)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+#: what an operation file says of an operand: read only, or written in
+#: place (the timer ends when every tile of every ``inout`` one is ready)
+MODES = ("in", "inout")
+ONE_MATRIX_IN_PLACE = [{"name": "A", "mode": "inout"}]
 
 
 class SpecError(Exception):
@@ -58,6 +62,69 @@ def formula(expr, env):
     return eval(expr, {"__builtins__": {}}, dict(env))  # noqa: S307
 
 
+def _operands_of(op, what):
+    """[(name, mode)] in the order the entry point takes them after the
+    context; an operation file without ``operands`` is one matrix
+    factored in place."""
+    operands = op.get("operands", ONE_MATRIX_IN_PLACE)
+    if not isinstance(operands, list) or not operands:
+        raise SpecError(f"{what}: 'operands' is a list of at least one "
+                        f"{{'name': ..., 'mode': ...}}")
+    out = []
+    for o in operands:
+        if not isinstance(o, dict) or set(o) != {"name", "mode"}:
+            raise SpecError(f"{what}: operand {o!r} has not just a 'name' "
+                            f"and a 'mode'")
+        check_name(o["name"], f"{what}: operand")
+        if o["mode"] not in MODES:
+            raise SpecError(f"{what}: operand {o['name']!r} has mode "
+                            f"{o['mode']!r}, none of {MODES}")
+        out.append((o["name"], o["mode"]))
+    if len({n for n, _ in out}) != len(out):
+        raise SpecError(f"{what}: two operands share a name: {out}")
+    if not any(mode == "inout" for _, mode in out):
+        raise SpecError(f"{what}: no operand is 'inout', so the call "
+                        f"writes nothing a check could read")
+    return out
+
+
+def _args_of(op, what):
+    """Keyword arguments of the entry point: scalars only."""
+    args = op.get("args", {})
+    if not isinstance(args, dict):
+        raise SpecError(f"{what}: 'args' is an object of keyword arguments")
+    for k, v in args.items():
+        if not k.isidentifier() \
+                or not isinstance(v, (bool, int, float, str)):
+            raise SpecError(f"{what}: argument {k!r} = {v!r} is not a "
+                            f"scalar under a keyword")
+    return dict(args)
+
+
+def _warm_up_of(op, names, what):
+    """Set-up calls on small grids of the same tile, for an operation
+    whose stacked programs a call at the cell's size meets only now and
+    then: {"rounds": n, "grids": [{operand: [rows, columns], ...}, ...]},
+    rows and columns counted in tiles, each a number or a formula of the
+    cell's sizes; every grid is called once a round.  Absent: none."""
+    plan = op.get("warm_up")
+    if plan is None:
+        return None
+    ok = isinstance(plan, dict) and set(plan) == {"rounds", "grids"} \
+        and type(plan["rounds"]) is int and plan["rounds"] >= 1 \
+        and isinstance(plan["grids"], list) and plan["grids"] \
+        and all(isinstance(g, dict) and set(g) == set(names)
+                and all(isinstance(rc, list) and len(rc) == 2
+                        and all(type(e) in (int, str) for e in rc)
+                        for rc in g.values())
+                for g in plan["grids"])
+    if not ok:
+        raise SpecError(f"{what}: 'warm_up' is {{'rounds': a count from 1, "
+                        f"'grids': [{{operand: [rows, columns] in tiles "
+                        f"for each of {sorted(names)}}}, ...]}}")
+    return plan
+
+
 class Cell:
     """One entry of ``workloads`` with every file it leads to."""
 
@@ -92,6 +159,10 @@ class Cell:
                             f"'refilled' or 'fresh' matrix")
         self.op_name = check_name(self.config["operation"], "operation")
         self.op = data_file("operations", self.op_name)
+        self.operands = _operands_of(self.op, f"operation {self.op_name!r}")
+        self.args = _args_of(self.op, f"operation {self.op_name!r}")
+        self.warm_up = _warm_up_of(self.op, [n for n, _ in self.operands],
+                                   f"operation {self.op_name!r}")
         self.sizes = {}
         self.resize(**{k: v for k, v in self.traffic.items()
                        if isinstance(v, int) and not isinstance(v, bool)})
@@ -105,6 +176,21 @@ class Cell:
         self.sizes.update(sizes)
         for k, expr in self.op.get("grid", {}).items():
             self.sizes[k] = formula(expr, self.sizes)
+
+    def warm_up_grids(self):
+        """[{operand: (rows, columns) in tiles}] of one round of the
+        operation's small set-up calls; empty where it asks none."""
+        if self.warm_up is None:
+            return []
+        grids = []
+        for g in self.warm_up["grids"]:
+            grid = {name: tuple(int(formula(str(e), self.sizes)) for e in rc)
+                    for name, rc in g.items()}
+            if min(min(rc) for rc in grid.values()) < 1:
+                raise SpecError(f"operation {self.op_name!r}: warm_up grid "
+                                f"{g} has no tile at sizes {self.sizes}")
+            grids.append(grid)
+        return grids
 
     def kernel_counts(self):
         return {k["class"]: int(formula(k["count"], self.sizes))
