@@ -14,17 +14,49 @@ TPU-native re-design: a "datatype" is a (dtype, shape, region) descriptor
 MPI pack/unpack. Local and remote variants share the promise machinery:
 the local trigger converts an existing host/device copy; the remote
 variant is armed before the payload exists and converts on arrival.
+
+A tile that lives on an accelerator is converted THERE, by one compiled
+program named ``CONVERT`` (``jit_CONVERT`` in a device trace), through
+the device holding it (``JaxDevice.convert``: reserved in its memory
+accounting, counted in its ``stats``): no host round trip.  The copy it
+makes belongs to no ``Data`` (``copy.data is None``), so a stage-in
+takes its payload as it is and no LRU keeps it.  A promise taken with
+``acquire`` is COUNTED: the PTG runtime takes one use for every local
+successor of the produced tile that declares the type, where the tile
+is produced, and gives it back when that successor completes
+(``release``); with the last use the promise leaves the table and the
+converted payload is dropped.  ``reshaped_copy`` is the uncounted form:
+its promise lives until ``clear()`` or the taskpool's end.
 """
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
 from ..core.future import DataCopyFuture
-from .data import Coherency, Data, DataCopy
+from .data import Coherency, Data, DataCopy, is_device_array
 from .datatype import Datatype, dtt_of_array
+
+_now = time.monotonic_ns
+#: dst datatype -> the jitted program converting a device tile to it
+_programs: Dict[Datatype, Any] = {}
+
+
+def conversion_program(dst: Datatype) -> Any:
+    """The compiled conversion of a device array to ``dst``, one per
+    datatype and process; it runs as ``jit_CONVERT``."""
+    fn = _programs.get(dst)
+    if fn is None:
+        import jax
+
+        def CONVERT(arr):
+            return reshape_array(arr, dst, dtt_of_array(arr))
+
+        fn = _programs[dst] = jax.jit(CONVERT)
+    return fn
 
 
 def reshape_array(arr: Any, dst: Datatype, src: Optional[Datatype] = None) -> Any:
@@ -66,17 +98,23 @@ def _needs_reshape(copy: DataCopy, dst: Datatype) -> bool:
 class ReshapeRepo:
     """Per-taskpool table of reshape promises with dedup.
 
-    Keyed by (source copy identity, destination datatype): N consumers of
-    one produced copy that declare the same [type=...] share ONE converted
-    copy, converted once (ref: reshape dedup of concurrent promises,
-    parsec_reshape.c setup_matching_reshape paths).
+    Keyed by (source copy identity, its version, destination datatype):
+    N consumers of one produced copy that declare the same [type=...]
+    share ONE converted copy, converted once (ref: reshape dedup of
+    concurrent promises, parsec_reshape.c setup_matching_reshape paths).
+    A tile's device copy is one object from version to version, so the
+    version is part of the key.
     """
 
     def __init__(self) -> None:
         self._promises: Dict[Tuple, DataCopyFuture] = {}
+        # counted promises: key -> uses still out; id(converted copy)
+        # -> (key, the device that made it or None)
+        self._uses: Dict[Tuple, int] = {}
+        self._counted: Dict[int, Tuple] = {}
         self._lock = threading.Lock()
         self.stats = {"local_promises": 0, "remote_promises": 0,
-                      "conversions": 0, "hits": 0}
+                      "conversions": 0, "hits": 0, "released": 0}
 
     # -- local reshape ------------------------------------------------------
     def reshaped_copy(self, copy: Optional[DataCopy], dst: Datatype,
@@ -84,25 +122,86 @@ class ReshapeRepo:
         """Return a copy matching ``dst``, converting lazily via a shared
         promise. Non-matching copies are never mutated — the original
         stays valid for consumers that want the producer's type."""
-        if copy is None or copy.payload is None:
-            return copy
-        if not _needs_reshape(copy, dst):
-            return copy
-        fut = self.promise(copy, dst)
-        return fut.get_or_trigger()
+        return self._converted(copy, dst, es, use=False)
 
-    def promise(self, copy: DataCopy, dst: Datatype) -> DataCopyFuture:
-        """The shared promise converting ``copy`` to ``dst`` (local
-        variant: the source payload already exists)."""
-        key = (id(copy), dst)
+    def acquire(self, copy: Optional[DataCopy], dst: Datatype,
+                es: Any = None) -> Optional[DataCopy]:
+        """``reshaped_copy`` with one USE taken on the converted copy:
+        whoever acquires gives it back with ``release``, and the last
+        use out drops the promise and the converted payload."""
+        return self._converted(copy, dst, es, use=True)
+
+    def _converted(self, copy: Optional[DataCopy], dst: Datatype, es: Any,
+                   use: bool) -> Optional[DataCopy]:
+        if copy is None or copy.payload is None \
+                or not _needs_reshape(copy, dst):
+            return copy
+        with _Pass(copy, es) as dev:
+            conv = self._promise(copy, dst, dev, use).get_or_trigger()
+            if use:
+                with self._lock:
+                    self._counted.setdefault(id(conv),
+                                             (_key(copy, dst), dev))
+            return conv
+
+    def retain(self, conv: Optional[DataCopy]) -> None:
+        """One more use of a copy ``acquire`` returned (a producer holds
+        one while it hands the copy to its successors)."""
         with self._lock:
+            ent = self._counted.get(id(conv))
+            if ent is not None:
+                self._uses[ent[0]] += 1
+
+    def release(self, conv: Optional[DataCopy], drop: bool = True) -> None:
+        """Give back one use; nothing for a copy that is not counted.
+        The last use out takes the promise from the table and, unless
+        ``drop`` is false (the reader WROTE the copy and hands it on: it
+        is that task's now), drops the converted payload."""
+        with self._lock:
+            ent = self._counted.get(id(conv))
+            if ent is None:
+                return
+            key, dev = ent
+            self._uses[key] -= 1
+            if self._uses[key] > 0:
+                return
+            del self._uses[key], self._counted[id(conv)]
+            self._promises.pop(key, None)
+            self.stats["released"] += 1
+        nbytes = getattr(conv.payload, "nbytes", 0)
+        if drop:
+            conv.payload = None
+        if dev is not None:
+            dev.release_converted(nbytes)
+
+    def _promise(self, copy: DataCopy, dst: Datatype, dev: Any,
+                 use: bool = False) -> DataCopyFuture:
+        """The shared promise converting ``copy`` to ``dst`` (local
+        variant: the source payload already exists), the conversion made
+        by ``dev`` (the accelerator holding the tile) when there is one;
+        ``use`` counts one use."""
+        key = _key(copy, dst)
+        with self._lock:
+            if use:
+                self._uses[key] = self._uses.get(key, 0) + 1
             fut = self._promises.get(key)
             if fut is not None:
                 self.stats["hits"] += 1
+                if dev is not None:
+                    dev.stats["reshape_hits"] += 1
                 return fut
 
             def trigger(_spec, _copy=copy, _dst=dst):
                 self.stats["conversions"] += 1
+                if dev is not None:
+                    # made where the tile lives; the copy is nobody's
+                    # Data, so a stage-in takes its payload as it is
+                    c = DataCopy(None, dev.device_index,
+                                 payload=dev.convert(_copy.payload, _dst),
+                                 dtt=_dst)
+                    c.version = _copy.version
+                    c.coherency = Coherency.OWNED
+                    return c
                 src_dtt = _copy.dtt or dtt_of_array(_copy.payload)
                 arr = reshape_array(_copy.payload, _dst, src_dtt)
                 return _detached_copy(arr, _dst, version=_copy.version)
@@ -150,6 +249,60 @@ class ReshapeRepo:
     def clear(self) -> None:
         with self._lock:
             self._promises.clear()
+            self._uses.clear()
+            self._counted.clear()
+
+    def held(self) -> int:
+        """Promises in the table (counted ones leave with their last
+        use)."""
+        with self._lock:
+            return len(self._promises)
+
+
+def _key(copy: DataCopy, dst: Datatype) -> Tuple:
+    return (id(copy), copy.version, dst)
+
+
+def _device_of(copy: DataCopy, es: Any) -> Any:
+    """The accelerator device holding ``copy``'s payload, if it can
+    convert there; None for a host payload or with no context."""
+    if es is None or not is_device_array(copy.payload):
+        return None
+    for dev in getattr(getattr(es, "context", None), "devices", ()):
+        if dev.device_index == copy.device_id:
+            return dev if hasattr(dev, "convert") else None
+    return None
+
+
+class _Pass:
+    """One trip through the reshape engine for a flow that declares a
+    type: the phase ``reshape`` of the open root span's clock
+    (``parsec:reshape`` in a profiler trace), and in every run the wall
+    nanoseconds in ``reshape_ns`` / ``reshape_n`` of the device holding
+    the tile.  Yields that device (None: a host tile)."""
+
+    __slots__ = ("dev", "clock", "t0")
+
+    def __init__(self, copy: DataCopy, es: Any) -> None:
+        self.dev = _device_of(copy, es)
+        self.clock = getattr(getattr(es, "context", None),
+                             "_phase_clock", None)
+
+    def __enter__(self) -> Any:
+        self.t0 = _now()
+        if self.clock is not None:
+            self.clock.push("reshape", self.t0)
+        return self.dev
+
+    def __exit__(self, *exc) -> bool:
+        t1 = _now()
+        if self.clock is not None:
+            self.clock.pop("reshape", at_ns=t1)
+        if self.dev is not None:
+            st = self.dev.stats
+            st["reshape_ns"] += t1 - self.t0
+            st["reshape_n"] += 1
+        return False
 
 
 def _detached_copy(arr: Any, dtt: Datatype, version: int = 1) -> DataCopy:

@@ -219,7 +219,14 @@ class JaxDevice(Device):
                       "placed_by_owner": 0, "placed_by_load": 0,
                       # parts of compound taskpools (runtime/compound.py)
                       # whose first device call left from here
-                      "compound_parts": 0}
+                      "compound_parts": 0,
+                      # the reshape engine (data/reshape.py) on tiles
+                      # that live here: conversions made on this chip
+                      # and the bytes of the copies they made, lookups
+                      # an earlier conversion answered, and the wall ns
+                      # and count of the passes through the engine
+                      "conversions": 0, "conversion_bytes": 0,
+                      "reshape_hits": 0, "reshape_ns": 0, "reshape_n": 0}
         # the manager's always-on brackets (obs.phases.BRACKETS), one
         # per place it works under ``_manager_lock`` and none per task:
         # wall ns (``time.monotonic_ns``, a vDSO read) and how many.
@@ -978,6 +985,23 @@ class JaxDevice(Device):
         self._account(-old)
         self._reserve(getattr(arr, "nbytes", 0))
         self._lru_touch(copy, owned=True)
+
+    def convert(self, payload: Any, dst: Any) -> Any:
+        """The reshape engine's conversion of a tile that lives here to
+        datatype ``dst``, made here: one program (``jit_CONVERT``), no
+        host round trip.  The converted array is the engine's to keep
+        and to give back (``release_converted``); no LRU lists it."""
+        from ..data.reshape import conversion_program
+        nbytes = dst.nbytes
+        self._reserve(nbytes)
+        out = conversion_program(dst)(payload)
+        self.stats["conversions"] += 1
+        self.stats["conversion_bytes"] += nbytes
+        return out
+
+    def release_converted(self, nbytes: int) -> None:
+        """The reshape engine dropped a copy ``convert`` made."""
+        self._account(-nbytes)
 
     def drain(self, context=None) -> None:
         """Retire every remaining window entry, call by call (called

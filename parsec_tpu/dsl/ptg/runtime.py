@@ -75,6 +75,14 @@ class PTGTaskClass(TaskClass):
                 plog.debug.verbose(
                     1, "ptg codegen failed for %s (%s); interpreting",
                     ast.name, exc)
+        # flows some in-dep of which declares a datatype ([type=...],
+        # [type_remote=...], [type_data=...]): flow name -> (index,
+        # flow); only these pass through the reshape engine
+        self._typed_in = {
+            f.name: (i, f) for i, f in enumerate(ast.flows)
+            if not f.is_ctl and any(
+                k in d.properties for d in f.deps_in()
+                for k in ("type", "type_remote", "type_data"))}
         self.prepare_input = self._prepare_input
         self.release_deps = self._release_deps
         self.iterate_successors = self._iterate_successors
@@ -226,9 +234,11 @@ class PTGTaskClass(TaskClass):
         # activation-sourced (remote) and memory/task-sourced (local) flows
         # alike (ref: parsec_reshape.c; receiver-side datatype lookup,
         # remote_dep_mpi.c:766)
-        for i, f in enumerate(self.ast.flows):
+        # (a local producer's release already handed a typed flow its
+        # converted copy, with a use counted on it: nothing left to do)
+        for i, f in self._typed_in.values():
             ref = task.data[i]
-            if f.is_ctl or ref.data_in is None:
+            if ref.data_in is None:
                 continue
             dtt = self._input_dtt(f, env, ref.data_in)
             if dtt is not None:
@@ -372,6 +382,13 @@ class PTGTaskClass(TaskClass):
         With static dep management active the whole walk is ONE native
         call: the lowered CSR edges route copies and decrement dense
         counters in C (ref: --dep-management=index-array)."""
+        repo = self.tp.reshape_repo
+        for i, _f in self._typed_in.values():
+            # this task has read its converted inputs: the uses its
+            # producers took for it go back, and a conversion's last
+            # reader drops it (a flow the task wrote hands its copy on)
+            repo.release(task.data[i].data_in,
+                         drop=self.flows[i].access == FlowAccess.READ)
         if self.tp._engine is not None:
             copies = tuple(
                 None if f.is_ctl
@@ -384,6 +401,11 @@ class PTGTaskClass(TaskClass):
         remote_edges: Dict[int, List[Tuple]] = {}
         flow_payloads: Dict[int, Any] = {}
         flow_dtts: Dict[int, Any] = {}
+        # converted copies handed out below: this release holds a use
+        # of its own on each until it has walked every successor, so
+        # that an early reader's completion on another thread cannot
+        # drop a copy a later one still gets
+        held: Dict[int, Any] = {}
 
         def activate(succ_tc: "PTGTaskClass", succ_locals: Tuple,
                      flow_name: str, copy, out_idx: int,
@@ -400,7 +422,19 @@ class PTGTaskClass(TaskClass):
                     # reshape — successors receive the converted copy
                     # (local_output_reshape semantics)
                     dtt = self.resolve_dtt_name(edge_type, copy, flow_name)
-                    copy = self.tp.reshape_repo.reshaped_copy(copy, dtt, es)
+                    copy = repo.reshaped_copy(copy, dtt, es)
+                elif flow_name in succ_tc._typed_in and copy is not None:
+                    # [type=...] on the successor's IN dep: converted
+                    # HERE, where the tile is produced, once for all
+                    # the successors that declare the type
+                    dtt = succ_tc._input_dtt(
+                        succ_tc._typed_in[flow_name][1], env, copy)
+                    if dtt is not None:
+                        conv = repo.acquire(copy, dtt, es)
+                        if conv is not copy and id(conv) not in held:
+                            held[id(conv)] = conv
+                            repo.retain(conv)
+                        copy = conv
                 t = succ_tc.activate(succ_locals, flow_name, copy)
                 if t is not None:
                     ready.append(t)
@@ -442,6 +476,8 @@ class PTGTaskClass(TaskClass):
                     # matching consumer type must not reconvert
 
         self._iterate_successors(es, task, activate)
+        for conv in held.values():
+            repo.release(conv)
         if remote_edges:
             self.tp.comm.activate_batch(self.tp, task, flow_payloads,
                                         remote_edges, flow_dtts)

@@ -37,7 +37,9 @@ module brackets the six places a device manager works (``BRACKETS``;
 and the record holds what those counters moved by during the call: per
 bracket ``wall_ns`` and ``count``, summed over the accelerator devices
 (``manager``) and for each (``by_device``).  The brackets of one device
-are disjoint, so their sum is at most the call's root span.
+are disjoint, so their sum is at most the call's root span.  Each
+``by_device`` entry also holds, under ``reshape``, what the reshape
+engine's counters of that device moved by (``RESHAPE_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -53,13 +55,14 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["PHASES", "BRACKETS", "PhaseClock", "root_span",
+__all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PhaseClock", "root_span",
            "session_recording", "completed", "clear_completed",
            "format_report"]
 
 #: every phase a site books under, in the order the report prints them
 PHASES = ("select", "idle_poll", "parked", "prepare_input", "exec",
-          "schedule", "complete", "release_deps", "manager", "stage_in",
+          "schedule", "complete", "release_deps", "reshape", "manager",
+          "stage_in",
           "dispatch", "first_call", "chip_wait", "epilog", "dtd_insert",
           "dtd_window", "dtd_flush", "other")
 
@@ -68,6 +71,13 @@ PHASES = ("select", "idle_poll", "parked", "prepare_input", "exec",
 #: ``<bracket>_n`` in ``dev.stats``)
 BRACKETS = ("set_stage", "group", "dispatch", "chip_wait", "epilog",
             "complete")
+
+#: the reshape engine's always-on counters of a device (``dev.stats``,
+#: data/reshape.py): conversions made on the chip and their bytes,
+#: lookups an earlier conversion answered, and the wall ns and count of
+#: the passes through the engine (``reshape`` is also a phase)
+RESHAPE_COUNTERS = ("conversions", "conversion_bytes", "reshape_hits",
+                    "reshape_ns", "reshape_n")
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -298,8 +308,11 @@ def _entry(acc: List[int]) -> Dict[str, int]:
 def _bracket_counters(devices: List[Any]) -> List[tuple]:
     """``(device, {bracket: {field: counter now}})`` of each of
     ``devices`` that keeps the manager's brackets."""
-    return [(dev, {b: {"wall_ns": dev.stats[b + "_ns"],
-                       "count": dev.stats[b + "_n"]} for b in BRACKETS})
+    return [(dev, dict({b: {"wall_ns": dev.stats[b + "_ns"],
+                            "count": dev.stats[b + "_n"]}
+                        for b in BRACKETS},
+                       reshape={c: dev.stats.get(c, 0)
+                                for c in RESHAPE_COUNTERS}))
             for dev in devices
             if BRACKETS[0] + "_ns" in getattr(dev, "stats", ())]
 
@@ -309,7 +322,7 @@ def _manager_block(before: List[tuple]) -> Dict[str, Any]:
     counters moved by since ``before``."""
     after = _bracket_counters([dev for dev, _was in before])
     by_device = [dict({b: {f: now[b][f] - was[b][f] for f in now[b]}
-                       for b in BRACKETS}, device=dev.name)
+                       for b in BRACKETS + ("reshape",)}, device=dev.name)
                  for (dev, was), (_dev, now) in zip(before, after)]
     return {"manager": {b: {f: sum(e[b][f] for e in by_device)
                             for f in ("wall_ns", "count")}
@@ -409,6 +422,16 @@ def format_report(record: Dict[str, Any]) -> str:
         lines.append(f"in no bracket: {(root - inside / managers) / 1e9:.6f} "
                      f"s of the root span (the brackets' mean over "
                      f"{managers} manager(s) taken out)")
+        conv = {c: sum(e.get("reshape", {}).get(c, 0)
+                       for e in record["by_device"])
+                for c in RESHAPE_COUNTERS}
+        if conv["reshape_n"]:
+            lines.append(
+                f"reshape: {conv['conversions']} conversions on the "
+                f"device(s), {conv['conversion_bytes']} bytes made, "
+                f"{conv['reshape_hits']} look-ups an earlier one answered; "
+                f"{conv['reshape_ns'] / 1e9:.6f} s in {conv['reshape_n']} "
+                f"passes")
     t0 = record["t0_ns"]
     for i, part in enumerate(record.get("parts", ())):
         lines.append(

@@ -1,16 +1,19 @@
 """Tile kernels (XLA/Pallas executables for task BODYs) and tile
 algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm)."""
-from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt,
+from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt, gemm_nt_lo,
+                     gemm_nt_mid,
                      gemm_tn, gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
                      getrf_1d_panel, getrf_1d_update, getrf_nopiv, lauum_lower,
                      potrf, scal, syrk_ln, syrk_lt, transpose,
                      trmm_lower_trans, trsm_lower, trsm_lower_right_neg,
                      trsm_lower_trans, trsm_lower_unit, trsm_panel,
+                     trsm_panel_mid,
                      trsm_upper_right, trtri_lower, tsmqr, tsqrt, tsqrt_r,
                      unmqr)
 from . import dpotrf as dpotrf_module
 from .dpotrf import dpotrf, dpotrf_factory, dpotrf_taskpool, make_spd
 from .dpotrf_dtd import dpotrf_dtd
+from .dpotrf_mp import dpotrf_mp, dpotrf_mp_taskpool
 from .dgeqrf import dgeqrf, dgeqrf_factory, dgeqrf_taskpool
 from .inverse import dgesv, dgetrs
 from .dpoinv import (dlauum, dlauum_taskpool, dpoinv, dpotri, dtrtri,
@@ -30,7 +33,8 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "getrf_nopiv", "trsm_lower_unit", "trsm_upper_right",
            "getrf_1d_panel", "getrf_1d_update", "getrf_1d_laswp",
            "dpotrf", "dpotrf_factory", "dpotrf_taskpool", "make_spd",
-           "dpotrf_dtd",
+           "dpotrf_dtd", "dpotrf_mp", "dpotrf_mp_taskpool",
+           "gemm_nt_mid", "gemm_nt_lo", "trsm_panel_mid",
            "dgeqrf", "dgeqrf_factory", "dgeqrf_taskpool",
            "dgetrf", "dgetrf_nopiv", "dgetrf_nopiv_taskpool", "dgetrf_factory",
            "dgetrf_1d", "dgetrf_1d_factory", "dgetrf_1d_taskpool",

@@ -49,6 +49,55 @@ def gemm_nt(c: Any, a: Any, b: Any) -> Any:
     return c - jnp.dot(a, b.T, preferred_element_type=jnp.float32)
 
 
+# The levels below ``highest`` of the mixed-precision tile Cholesky
+# (ops/dpotrf_mp.py).  The kernels above follow the process's
+# ``jax_default_matmul_precision``; these state their own: *mid* is f32
+# operands at ``HIGH`` (bf16_3x, three MXU passes), *lo* is bf16
+# operands in one pass.  Accumulation and the tile written are f32.
+_MID = jax.lax.Precision.HIGH
+#: a triangular solve is split down to diagonal blocks of this order
+TRSM_LEAF = 256
+
+
+@jax.jit
+def gemm_nt_mid(c: Any, a: Any, b: Any) -> Any:
+    """C <- C - A B^T, f32 operands in three bf16 passes."""
+    return c - jnp.dot(a, b.T, precision=_MID,
+                       preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def gemm_nt_lo(c: Any, a: Any, b: Any) -> Any:
+    """C <- C - A B^T with A and B as bf16 (the reshape engine's copies
+    of the f32 tiles: the conversion is on the flow, not here), one MXU
+    pass, accumulated and subtracted in f32."""
+    return c - jnp.dot(a, b.T, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32)
+
+
+def trsm_panel_split(t: Any, c: Any, precision: Any, leaf: int) -> Any:
+    """C <- C * T^{-T} by halving T: X1 = C1 T11^{-T}, X2 = (C2 - X1
+    T21^T) T22^{-T}, the products at ``precision`` and the diagonal
+    blocks of order ``leaf`` or less by ``trsm_panel``'s solve (XLA's
+    triangular solve runs at ``highest`` whatever is asked: it holds
+    1 / (NB / leaf) of the operations)."""
+    n = t.shape[0]
+    if n <= leaf or n % 2:
+        return _solve_tri(t, c.T, lower=True).T
+    h = n // 2
+    x1 = trsm_panel_split(t[:h, :h], c[:, :h], precision, leaf)
+    c2 = c[:, h:] - jnp.dot(x1, t[h:, :h].T, precision=precision,
+                            preferred_element_type=jnp.float32)
+    x2 = trsm_panel_split(t[h:, h:], c2, precision, leaf)
+    return jnp.concatenate([x1, x2], axis=1)
+
+
+@jax.jit
+def trsm_panel_mid(t: Any, c: Any) -> Any:
+    """``trsm_panel`` with its products in three bf16 passes."""
+    return trsm_panel_split(t, c, _MID, TRSM_LEAF)
+
+
 @jax.jit
 def gemm_nn(c: Any, a: Any, b: Any) -> Any:
     """C <- C + A B."""

@@ -62,8 +62,11 @@ def _moved(dev, before):
 
 def _check_device_entry(entry, root_ns):
     """One device's six brackets: all there, disjoint (they add to no
-    more than the root span)."""
-    assert set(entry) == set(phases.BRACKETS) | {"device"}
+    more than the root span); beside them the reshape engine's
+    counters, still where nothing declares a type."""
+    assert set(entry) == set(phases.BRACKETS) | {"device", "reshape"}
+    assert set(entry["reshape"]) == set(phases.RESHAPE_COUNTERS)
+    assert all(v >= 0 for v in entry["reshape"].values())
     for b in phases.BRACKETS:
         e = entry[b]
         assert set(e) == FIELDS

@@ -160,16 +160,17 @@ def test_platform_rule_the_cpu_program_holds_no_mosaic_call():
 
 
 def test_the_metric_reads_the_kernel_by_its_name_and_nothing_else():
-    """``panel_strip_device_s``: listed for the LU cell alone; the
-    seconds of the ``XLA Ops`` that are the Mosaic call, a traced
+    """``panel_strip_device_s``: listed for every LU cell and no other;
+    the seconds of the ``XLA Ops`` that are the Mosaic call, a traced
     factorization; nothing where the trace has none (the parent)."""
-    import json
     from perfbench import spec
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        entry = [m for m in json.load(f)["per_layer"]
-                 if m["name"] == "panel_strip_device_s"]
+    bench = spec.load_benchmark()
+    entry = [m for m in bench["per_layer"]
+             if m["name"] == "panel_strip_device_s"]
     assert len(entry) == 1 and entry[0]["moves"] == "factor_s"
-    assert entry[0]["workloads"] == ["dgetrf.n16384-nb512"]
+    lu = [w["name"] for w in bench["workloads"]
+          if spec.Cell(bench, w["name"]).op_name == "dgetrf_1d"]
+    assert len(lu) >= 2 and entry[0]["workloads"] == lu
     read = spec.metric_reader("panel_strip_device_s").read
     call = ('%lu_strip_vmem{} = (f32[32,128,128], s32[128,128], s32[32]) '
             'custom-call(s32[1] %r, f32[32,128,128] %c), '
@@ -228,3 +229,36 @@ def test_strip_alone_compiles_for_the_v5e_at_a_full_chip_height(one_chip):
     d0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     text = jax.jit(pk.lu_strip_vmem).lower(st, d0).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("kernel,operands", [
+    ("gemm_nt_mid", "fff"), ("gemm_nt_lo", "fhh"), ("trsm_panel_mid", "ff"),
+])
+def test_kernels_below_highest_compile_for_the_v5e_at_the_cells_tile(
+        one_chip, kernel, operands):
+    """The mixed-precision Cholesky's mid and lo kernels (ops/linalg.py)
+    at NB = 2048 (kept in this file: one file's worker holds the TPU's
+    compiler): each writes f32, lo takes bf16 operands, and a product
+    of the mid kernels is split in three bf16 passes, of lo in none."""
+    nb = 2048
+    shapes = {"f": jax.ShapeDtypeStruct((nb, nb), jnp.float32,
+                                        sharding=one_chip),
+              "h": jax.ShapeDtypeStruct((nb, nb), jnp.bfloat16,
+                                        sharding=one_chip)}
+    with jax.default_matmul_precision("highest"):
+        compiled = getattr(linalg, kernel).lower(
+            *(shapes[c] for c in operands)).compile()
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.dtype == jnp.float32 and out.shape == (nb, nb)
+
+
+def test_conversion_program_compiles_for_the_v5e(one_chip):
+    from parsec_tpu.data.datatype import Datatype
+    from parsec_tpu.data.reshape import conversion_program
+    nb = 2048
+    src = jax.ShapeDtypeStruct((nb, nb), jnp.float32, sharding=one_chip)
+    compiled = conversion_program(
+        Datatype(jnp.bfloat16, (nb, nb))).lower(src).compile()
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.dtype == jnp.bfloat16 and out.shape == (nb, nb)
+    assert "jit_CONVERT" in compiled.as_text()
