@@ -15,6 +15,7 @@ accuracy at ~3x the MXU cost.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -300,7 +301,9 @@ def _lu_strip(st: Any, d0: Any) -> Any:
     a tie), its row is exchanged with row ``d0 + i`` in all w columns,
     the multipliers replace the column under the diagonal and the
     strip's later columns are updated.  Returns (the strip, the
-    interchanges as a gather over all N rows, the w pivot rows).
+    interchanges as a gather over all N rows, the w pivot rows); the
+    panel's pass reads that gather at the at most 2 w rows that moved
+    (the pivot rows and the block row) and moves nothing else.
 
     This is the lowering for every platform but the TPU, and for the
     shapes the kernel does not take: the strip is the carry of an XLA
@@ -351,6 +354,65 @@ def _lu_strip_lowered(st: Any, d0: Any) -> Any:
         st, d0, tpu=pallas_kernels.lu_strip_vmem, default=_lu_strip)
 
 
+def _lu_pass(x: Any, st: Any, rows: Any, new: Any, d0: Any, *, c0: int,
+             c1: int) -> Any:
+    """What strip ``[c0, c1)`` of a panel changes in it, in XLA.  The
+    strip ``st`` (transposed, as the strip kernel left it) goes into its
+    columns.  ``new`` holds the rows that moved on the columns from the
+    strip's lane tile to the right edge, ``new[j]`` for row ``rows[j]``:
+    the pivots' rows, then the block row, which right of the strip is U
+    already.  They are stored left of the strip within its lane tile and
+    right of it (as small scatters whose duplicates carry equal values;
+    right of the strip the block row last, which a pivot row inside it
+    must not outlive), and the rows under the block row take ``- L @ U``,
+    L the strip; a row at or above the block row keeps its bits.
+    Returns (the panel, the next strip's columns transposed, as the
+    strip kernel takes them, or None after the panel's last strip).  The
+    lowering for every platform but the TPU and for the shapes the
+    kernel does not take (``pallas_kernels.lu_pass_vmem``)."""
+    n, nb = x.shape
+    w = c1 - c0
+    lo = pallas_kernels.lu_pass_window(c0)
+    x = x.at[:, c0:c1].set(st.T)
+    if lo < c0:
+        x = x.at[rows, lo:c0].set(new[:, :c0 - lo])
+    if c1 == nb:
+        return x, None
+    right = new[:, c1 - lo:]
+    x = x.at[rows[:w], c1:].set(right[:w])
+    x = jax.lax.dynamic_update_slice(x, right[w:], (d0, c1))
+    under = jnp.arange(n, dtype=jnp.int32) >= d0 + w
+    below = jnp.where(under, st, 0).T
+    x = x.at[:, c1:].set(jnp.where(
+        under[:, None], gemm_nn_sub(x[:, c1:], below, right[w:]), x[:, c1:]))
+    return x, x[:, c1:min(c1 + LU_STRIP, nb)].T
+
+
+def _lu_pass_lowered(x: Any, st: Any, rows: Any, new: Any, d0: Any, *,
+                     c0: int, c1: int) -> Any:
+    """:func:`_lu_pass` in the lowering the program's platform and the
+    panel's shape call for: on the TPU, for a panel the kernel takes
+    (``pallas_kernels.lu_pass_fits``), the Mosaic kernel that walks the
+    panel in place; XLA anywhere else."""
+    if not pallas_kernels.lu_pass_fits(c1 - c0, *x.shape):
+        return _lu_pass(x, st, rows, new, d0, c0=c0, c1=c1)
+    return jax.lax.platform_dependent(
+        x, st, rows, new, d0,
+        tpu=functools.partial(pallas_kernels.lu_pass_vmem, c0=c0, c1=c1),
+        default=functools.partial(_lu_pass, c0=c0, c1=c1))
+
+
+def _lu_interchanged(g: Any, rows: Any, src: Any) -> Any:
+    """``gs[g]`` for a strip's interchanges ``gs``, which are the
+    identity but for ``gs[rows] = src`` (a row named twice has one
+    source): the gather ``g`` taken after the strip's.  As 2 w compares
+    of all of ``g`` and not as a gather of its N entries, which the TPU
+    takes one entry at a time (0.116 ms at N = 16384, more than a whole
+    pass of the panel; PERF.md section 5)."""
+    hit = jnp.where(g[None, :] == rows[:, None], src[:, None], -1).max(axis=0)
+    return jnp.where(hit >= 0, hit, g)
+
+
 def _lu_panel(x: Any, r: Any) -> Any:
     """LU with exact partial pivoting of rows r.. of the (N, nb) block
     column ``x`` at its full static height, ``r`` an operand.
@@ -358,39 +420,69 @@ def _lu_panel(x: Any, r: Any) -> Any:
     factored over all the active rows (:func:`_lu_strip_lowered`: in
     VMEM by one Mosaic kernel where the program is lowered for the TPU
     and the strip's shape fits, by :func:`_lu_strip`'s XLA loop
-    anywhere else), its interchanges are applied to the panel's other
-    columns in one gather, the strip's block row is solved and the
-    columns to its right updated (the rows at or above the block row
-    masked out of the product).  These strip passes run at the full
-    height in XLA on every platform, and on the v5e they are now most
-    of a panel (PERF.md section 5).  ``lax.linalg.lu`` is still not
-    used: on the TPU it is XLA's ``LuDecomposition``, which holds every
-    row of a 128-column strip twice in 16 MiB of scoped VMEM and is
-    refused at compile time from 16384 rows on; the strip kernel asks
-    for the VMEM its own (w, N) needs and compiles at N = 57344.
-    Returns (the column, the interchanges as a gather g: after[i] =
-    before[g[i]], the nb pivot rows)."""
+    anywhere else) and then a strip PASS moves only what the strip
+    changed (:func:`_lu_pass_lowered`: one Mosaic kernel over the panel
+    in place on the TPU, XLA anywhere else):
+
+    - the at most 2 w rows the strip's interchanges moved (its pivots'
+      rows and its block row) are gathered, and the block row is solved
+      against the strip's unit lower triangle;
+    - the strip's own w columns go back into the panel;
+    - the columns to its RIGHT are read once and written once: moved
+      rows, block row and ``- L @ U`` under the block row in one walk,
+      which also hands out the next strip; a row above the block row is
+      never written;
+    - the columns to its LEFT, already factored, are not touched, but
+      for those of the strip's own lane tile (``LU_PASS_TILE`` = 128
+      columns), which the walk brings in anyway and exchanges too.  The
+      interchanges of the LATER lane tiles' strips are composed and
+      applied to each lane tile ONCE, after the last strip: one gather a
+      lane tile, one whole-panel pass a PANEL where there were four a
+      strip.  Composing costs no gather of N row numbers either
+      (:func:`_lu_interchanged`).
+
+    ``lax.linalg.lu`` is still not used: on the TPU it is XLA's
+    ``LuDecomposition``, which holds every row of a 128-column strip
+    twice in 16 MiB of scoped VMEM and is refused at compile time from
+    16384 rows on; the strip kernel asks for the VMEM its own (w, N)
+    needs and compiles at N = 57344.  Returns (the column, the
+    interchanges as a gather g: after[i] = before[g[i]], the nb pivot
+    rows)."""
     n, nb = x.shape
-    lane = jnp.arange(n, dtype=jnp.int32)
-    g = lane
+    tile = pallas_kernels.LU_PASS_TILE
     piv = jnp.zeros((nb,), jnp.int32)
+    moved = []
+    strip = x[:, :LU_STRIP].T
     for c0 in range(0, nb, LU_STRIP):
         c1 = min(c0 + LU_STRIP, nb)
+        w = c1 - c0
         d0 = r + c0
-        st, gs, pv = _lu_strip_lowered(x[:, c0:c1].T, d0)
-        x = jnp.take(x, gs, axis=0, unique_indices=True, mode="clip")
-        x = x.at[:, c0:c1].set(st.T)
-        g = g[gs]
+        lo = pallas_kernels.lu_pass_window(c0)
+        st, gs, pv = _lu_strip_lowered(strip, d0)
         piv = piv.at[c0:c1].set(pv)
+        rows = jnp.concatenate([pv, d0 + jnp.arange(w, dtype=jnp.int32)])
+        src = gs[rows]
+        moved.append((rows, src))
+        if c1 == nb == w:           # one strip alone: nothing else to move
+            x = st.T
+            break
+        new = jnp.take(x, src, axis=0, mode="clip")[:, lo:]
         if c1 < nb:
-            w = c1 - c0
             u = trsm_lower_unit(
                 jax.lax.dynamic_slice(st, (0, d0), (w, w)).T,
-                jax.lax.dynamic_slice(x, (d0, c1), (w, nb - c1)))
-            x = jax.lax.dynamic_update_slice(x, u, (d0, c1))
-            below = jnp.where(lane >= d0 + w, st, 0).T
-            x = x.at[:, c1:].set(gemm_nn_sub(x[:, c1:], below, u))
-    return x, g, piv
+                new[w:, c1 - lo:])
+            new = new.at[w:, c1 - lo:].set(u)
+        x, strip = _lu_pass_lowered(x, st, rows, new, d0, c0=c0, c1=c1)
+    # the interchanges of the later lane tiles' strips, on each lane tile
+    g = jnp.arange(n, dtype=jnp.int32)
+    cols = [x[:, (nb - 1) // tile * tile:]]
+    for k in range(len(moved) - 1, -1, -1):
+        g = _lu_interchanged(g, *moved[k])
+        c0 = k * LU_STRIP
+        if c0 and c0 % tile == 0:
+            cols.append(jnp.take(x[:, c0 - tile:c0], g, axis=0,
+                                 unique_indices=True, mode="clip"))
+    return jnp.concatenate(cols[::-1], axis=1), g, piv
 
 
 @jax.jit

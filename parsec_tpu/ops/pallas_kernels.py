@@ -4,9 +4,10 @@ The reference keeps its hot math in hand-tuned native kernels (CUDA chores
 generated per task class, ref: parsec/interfaces/ptg/ptg-compiler/jdf2c.c:6557;
 the lone .cu kernel tests/dsl/dtd/dtd_test_new_tile_cuda_kernels.cu). The
 TPU-native analog is Pallas: Mosaic kernels that tile onto MXU/VPU with
-explicit VMEM residency. Three kernels live here; ``lu_strip_vmem`` is
-the one a task body of the runtime runs, the other two serve the
-transformer model and the ring-attention layer and no task class:
+explicit VMEM residency. Four kernels live here; ``lu_strip_vmem`` and
+``lu_pass_vmem`` are the ones a task body of the runtime runs, the other
+two serve the transformer model and the ring-attention layer and no task
+class:
 
 - ``flash_attention``: blockwise online-softmax attention (fwd is a single
   Pallas kernel with grid (BH, q_blocks, k_blocks); m/l/acc live in VMEM
@@ -18,14 +19,20 @@ transformer model and the ring-attention layer and no task class:
   kernels ride on).
 - ``lu_strip_vmem``: one strip of LU's pivoted panel (``ops.linalg.
   _lu_panel``, task class PANEL of ``ops.dgetrf_1d``) held in VMEM for
-  all its column steps.  ``ops.linalg._lu_strip_lowered`` picks it by the
-  platform a program is lowered for and by the strip's shape; it reads
+  all its column steps.
+- ``lu_pass_vmem``: what that strip changed in the rest of the panel,
+  the panel walked ONCE and in place: the columns from the strip's lane
+  tile to the right edge, blocks of rows from the one holding the
+  strip's first row down; the rows that moved stored from VMEM, the
+  product subtracted under the block row.
+  ``ops.linalg._lu_strip_lowered`` and ``_lu_pass_lowered`` pick the two
+  by the platform a program is lowered for and by the shapes; they read
   no parameter, ``use_pallas`` and ``_on_tpu`` below are not asked.
 
 Off-TPU (the virtual-CPU test mesh) ``flash_attention`` and ``matmul``
 run with ``interpret=True``, so tests validate the exact kernel code
-path; ``lu_strip_vmem`` is not lowered there at all (the XLA loop is)
-and its tests pass ``interpret=True`` themselves.
+path; the two LU kernels are not lowered there at all (XLA is) and
+their tests pass ``interpret=True`` themselves.
 """
 from __future__ import annotations
 
@@ -578,3 +585,199 @@ def lu_strip_vmem(st: Any, d0: Any, *, interpret: bool = False) -> Any:
         name="lu_strip_vmem", interpret=interpret,
     )(jnp.reshape(d0, (1,)).astype(jnp.int32), st.reshape(w, rows, 128))
     return out.reshape(w, n), g.reshape(n), piv
+
+
+# ---------------------------------------------------------------------------
+# One strip pass of LU's pivoted panel: the columns right of the strip,
+# read once and written once, interchange and update together
+# ---------------------------------------------------------------------------
+
+#: columns of a lane tile.  A pass brings the strip's lane tile in whole
+#: (the multipliers are read from it) and exchanges rows in ALL of it
+#: but the strip's own columns, so what waits for the end of the panel
+#: is whole lane tiles
+LU_PASS_TILE = 128
+
+#: rows of the panel one grid step of a pass holds in VMEM, and rows of
+#: it one product takes
+_LU_PASS_ROWS = 512
+_LU_PASS_CHUNK = 128
+
+
+def lu_pass_window(c0: int) -> int:
+    """The first column a pass over the strip that starts at column
+    ``c0`` brings in and exchanges rows in: its lane tile's."""
+    return c0 // LU_PASS_TILE * LU_PASS_TILE
+
+
+def _lu_pass_vmem_bytes(w: int, nb: int) -> int:
+    """The VMEM one pass kernel asks for at its widest window: a block
+    of rows coming in and one going out, each twice (the pipeline's two
+    buffers), the moved rows likewise, a chunk's
+    product and its operands, and room for Mosaic's own scratch."""
+    return (4 * _LU_PASS_ROWS + 4 * 2 * w + 4 * _LU_PASS_CHUNK) * nb * 4 \
+        + (4 << 20)
+
+
+def lu_pass_fits(w: int, n: int, nb: int) -> bool:
+    """The shape rule of :func:`lu_pass_vmem`: whole blocks of rows,
+    whole lane tiles of columns, a strip that lies in one lane tile."""
+    return (n % _LU_PASS_ROWS == 0 and nb % LU_PASS_TILE == 0
+            and LU_PASS_TILE % w == 0 and w % 8 == 0
+            and _lu_pass_vmem_bytes(w, nb) <= _LU_STRIP_VMEM_MAX)
+
+
+def _lu_pass_kernel(d0_ref, rows_ref, x_ref, st_ref, new_ref, out_ref, *rest,
+                    w: int, off: int):
+    rows_blk, width = x_ref.shape
+    tile, ch = LU_PASS_TILE, _LU_PASS_CHUNK
+    chunks = rows_blk // ch
+    *nxt_ref, wide_ref, head_ref, next_ref = rest   # no next strip after the last
+    i = pl.program_id(0)
+    d0 = d0_ref[0]
+    first = d0 // rows_blk          # the block of rows that holds row d0
+
+    @pl.when(i == first)
+    def _():
+        # the rows that moved, listed by the block of rows that holds
+        # them, each list in the order of ``rows``: once a pass
+        def clear(b, carry):
+            head_ref[b] = -1
+            return carry
+
+        def push(k, carry):
+            j = 2 * w - 1 - k
+            b = rows_ref[j] // rows_blk
+            next_ref[j] = head_ref[b]
+            head_ref[b] = j
+            return carry
+
+        jax.lax.fori_loop(0, pl.num_programs(0), clear, 0)
+        jax.lax.fori_loop(0, 2 * w, push, 0)
+
+    @pl.when(i >= first)            # above it nothing moves: see the index maps
+    def _():
+        r0 = i * rows_blk
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        right = col >= off + w
+        outside = right | (col < off)
+        # the block is brought up to date where it came in, then walked
+        # once.  The strip goes back into its columns: transposed on the
+        # way, as w rows of a lane tile's worth (the other rows are
+        # never read)
+        wide_ref[off:off + w, :] = st_ref[...]
+        strip = (col[:, :tile] >= off) & (col[:, :tile] < off + w)
+        x_ref[:, :tile] = jnp.where(strip, wide_ref[...].T, x_ref[:, :tile])
+
+        def put(j):
+            # one moved row: the pivots' rows first, then the block row
+            # (a pivot row inside the block row ends as its row of U)
+            at = pl.ds(rows_ref[j] - r0, 1)
+            x_ref[at, :] = jnp.where(outside, new_ref[pl.ds(j, 1), :],
+                                     x_ref[at, :])
+            return next_ref[j]
+
+        jax.lax.while_loop(lambda j: j >= 0, put, head_ref[i])
+        if not nxt_ref:             # the panel's last strip: nothing to update
+            out_ref[...] = x_ref[...]
+            return
+        u = new_ref[w:, :]
+        pos = jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0) + r0
+
+        def keep(c, carry):
+            rs = pl.ds(pl.multiple_of(c * ch, ch), ch)
+            out_ref[rs, :] = x_ref[rs, :]
+            return carry
+
+        def update(c, carry):
+            rs = pl.ds(pl.multiple_of(c * ch, ch), ch)
+            t = x_ref[rs, :]
+            prod = jnp.dot(t[:, off:off + w], u,
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+            out_ref[rs, :] = jnp.where((pos + c * ch >= d0 + w) & right,
+                                       t - prod, t)
+            return carry
+
+        # the chunks wholly above the block row's last row hold no row to update
+        under = jnp.minimum(jnp.maximum(d0 + w - r0, 0) // ch, chunks)
+        jax.lax.fori_loop(0, under, keep, 0)
+        jax.lax.fori_loop(under, chunks, update, 0)
+        # the next strip comes out as the strip kernel takes it, transposed
+        at = (off + w) // tile * tile
+        nxt = (off + w) % tile
+        nxt_ref[0][...] = out_ref[:, at:at + tile].T[nxt:nxt + w]
+
+
+def lu_pass_vmem(x: Any, st: Any, rows: Any, new: Any, d0: Any, *, c0: int,
+                 c1: int, interpret: bool = False) -> Any:
+    """``ops.linalg._lu_pass`` as ONE Mosaic kernel over the panel in
+    place: what a strip ``[c0, c1)`` changed, read once and written
+    once.
+
+    ``x`` is the (N, nb) panel, aliased in and out; ``st`` the factored
+    strip, transposed.  A grid step holds a block of ``_LU_PASS_ROWS``
+    rows of the columns from the strip's lane tile to the right edge
+    (``new`` has these columns).  Where the block came in it puts the
+    strip into its columns and stores the rows that moved (``new[j]``
+    into row ``rows[j]``, in order, outside the strip's own columns: the
+    first step of a pass lists the moved rows by block in SMEM, so a
+    step walks its own and not all 2 w); then it walks the block once,
+    chunk by chunk, to where it goes out: rows under the block row take
+    ``- L @ U`` right of the strip (L the strip's columns of the chunk,
+    U = the last w rows of ``new``; K = w on the MXU at ``highest``),
+    every other entry goes as it is.  Last it hands out the NEXT
+    strip's columns of the block, transposed.  The blocks of rows above
+    the one holding ``d0`` are never brought in: their grid steps name
+    the first block that is and do nothing, so those rows of the next
+    strip hold nothing (no strip reads a row above its first).  The
+    columns left of the strip's lane tile are not touched.  Returns (the
+    panel, the next strip or None after the panel's last).  Shapes:
+    :func:`lu_pass_fits`.  Reads no parameter; ``interpret`` is for the
+    tests on the CPU."""
+    n, nb = x.shape
+    w = c1 - c0
+    lo = lu_pass_window(c0)
+    width = nb - lo
+    blk = _LU_PASS_ROWS
+
+    def first(i, d0_ref):
+        return jnp.maximum(i, d0_ref[0] // blk)
+
+    def window(i, d0_ref, rows_ref):
+        # every dimension by its first element: a window's first column
+        # is no multiple of its width
+        return first(i, d0_ref) * blk, lo
+
+    def strip(i, d0_ref, rows_ref):
+        return 0, first(i, d0_ref)
+
+    def whole(i, d0_ref, rows_ref):
+        return 0, 0
+
+    panel = pl.BlockSpec((pl.Element(blk), pl.Element(width)), window)
+    out_shape = [jax.ShapeDtypeStruct((n, nb), x.dtype)]
+    out_specs = [panel]
+    if c1 < nb:
+        out_shape.append(jax.ShapeDtypeStruct((w, n), x.dtype))
+        out_specs.append(pl.BlockSpec((w, blk), strip))
+    kernel = functools.partial(_lu_pass_kernel, w=w, off=c0 - lo)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n // blk,),
+            in_specs=[panel, pl.BlockSpec((w, blk), strip),
+                      pl.BlockSpec((2 * w, width), whole)],
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((LU_PASS_TILE, blk), x.dtype),
+                            pltpu.SMEM((n // blk,), jnp.int32),
+                            pltpu.SMEM((2 * w,), jnp.int32)]),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_lu_pass_vmem_bytes(w, nb)),
+        name="lu_pass_vmem", interpret=interpret,
+    )(jnp.reshape(d0, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      x, st, new)
+    return (out[0], out[1]) if c1 < nb else (out[0], None)

@@ -11,6 +11,7 @@ topology is described inside a fixture: on-chip-measurement guide,
 section 2).  Values and counts only: no time is asserted.
 """
 import os
+import re
 import sys
 
 import jax
@@ -210,15 +211,31 @@ def one_chip():
 
 
 def test_panel_program_for_the_v5e_is_sixteen_kernels_and_no_loop(one_chip):
+    """PANEL at cell 6's shape: a strip kernel and a pass kernel a strip
+    and no loop (no carry: neither the strip nor the panel); beside the
+    pass kernels, which walk the panel in place, a handful of
+    operations whose result is a whole panel (its copy in, the lane tiles' gathers joined at the end,
+    one move out of VMEM and back that XLA schedules).  Before a pass
+    moved only what the strip changed there were 76: a gather, two
+    copies and a product a strip."""
     n, nb = 16384, 512
     a = jax.ShapeDtypeStruct((n, nb), jnp.float32, sharding=one_chip)
     q = jax.ShapeDtypeStruct((linalg.PIV_ROWS, n), jnp.int32,
                              sharding=one_chip)
     text = linalg.getrf_1d_panel.lower(a, q).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') \
-        == nb // linalg.LU_STRIP == 16
-    assert "lu_strip_vmem" in text
+    strips = nb // linalg.LU_STRIP
+    assert strips == 16
+    assert len(re.findall(r"%lu_strip_vmem[.\d]* = ", text)) == strips
+    assert len(re.findall(r"%lu_pass_vmem[.\d]* = ", text)) == strips
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * strips
     assert " while(" not in text
+    entry = text[text.index("\nENTRY"):]
+    whole = re.findall(r"^\s*(?:ROOT )?%(\S+) = f32\[16384,512\]\{[^}]*\} "
+                       r"([\w\-]+)\(", entry, re.M)
+    others = [name for name, op in whole
+              if op not in ("parameter", "get-tuple-element")
+              and not name.startswith("lu_pass_vmem")]
+    assert len(others) <= 6, others
 
 
 def test_strip_alone_compiles_for_the_v5e_at_a_full_chip_height(one_chip):
@@ -229,6 +246,34 @@ def test_strip_alone_compiles_for_the_v5e_at_a_full_chip_height(one_chip):
     d0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     text = jax.jit(pk.lu_strip_vmem).lower(st, d0).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("n,nb,c0", [
+    (16384, 512, 0), (16384, 512, 96), (16384, 512, 480),
+    (32768, 1024, 32), (32768, 1024, 992), (57344, 1024, 128)])
+def test_pass_alone_compiles_for_the_v5e_in_place(one_chip, n, nb, c0):
+    """The pass kernel at both LU cells' shapes and at the height at
+    which f32 LU fills 16 GB: a strip at the left edge of its lane tile,
+    at its right edge, the panel's last (nothing right of it): one
+    Mosaic call, the panel aliased in and out."""
+    import functools
+    w = linalg.LU_STRIP
+    assert pk.lu_pass_fits(w, n, nb)
+    lo = pk.lu_pass_window(c0)
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        functools.partial(pk.lu_pass_vmem, c0=c0, c1=c0 + w),
+        donate_argnums=0).lower(
+            on((n, nb), jnp.float32), on((w, n), jnp.float32),
+            on((2 * w,), jnp.int32), on((2 * w, nb - lo), jnp.float32),
+            on((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lu_pass_vmem" in text
+    assert compiled.memory_analysis().alias_size_in_bytes == n * nb * 4
 
 
 @pytest.mark.parametrize("kernel,operands", [
