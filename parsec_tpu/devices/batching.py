@@ -214,6 +214,35 @@ def kernel_named_for(name: str, obj: Any) -> Any:
     return clone
 
 
+def kernel_with_constants(name: str, obj: Any, template: Tuple) -> Any:
+    """The clone of the plainly jitted kernel ``obj`` that runs as
+    ``jit_<name>`` with the arguments ``template`` marks ``("v", value)``
+    bound as constants and its ``None`` entries taken as the call's
+    arrays, in order: what a stacked program makes of a DTD task's
+    VALUE parameters (``DeviceBatchSpec.call`` with its static key), for
+    a task dispatched alone, so that a task's result does not depend on
+    how many tasks its call held.  None where ``obj`` is not plainly
+    jitted or marks arguments of its own static or donated.  Built once
+    per process per (name, kernel, constants)."""
+    info = getattr(obj, "_jit_info", None)
+    if info is None or not hasattr(obj, "__wrapped__") \
+            or info.static_argnums or info.static_argnames \
+            or info.donate_argnums or info.donate_argnames:
+        return None
+    key = (name, (obj, template))
+    clone = _class_kernels.get(key)
+    if clone is None:
+        import jax
+        fun = obj.__wrapped__
+
+        def kernel(*arrays):
+            it = iter(arrays)
+            return fun(*[next(it) if s is None else s[1] for s in template])
+
+        clone = _class_kernels[key] = jax.jit(_named(kernel, name))
+    return clone
+
+
 class KernelsNamedFor:
     """A module as a per-task device body sees it: every plainly jitted
     kernel it holds (``ops.potrf``, ...) comes back as a clone named for

@@ -6,9 +6,10 @@ memory_register, data_advise, ...} with per-device capability weights and
 device.h:77-125): a task runs on the accelerator that OWNS the data it
 writes; a written tile no accelerator owns yet goes where
 ``data_advise(.., "preferred_device")`` said, else to the least loaded
-device (``device_load`` + the task's estimate).  So load decides only a
-tile's first touch, and every later update of the tile finds it where
-it is: what still crosses between chips is what a task only READS.
+device (``device_load`` + the task's estimate).  So advice or load
+decides only a tile's first touch, and every later update of the tile
+finds it where it is: what still crosses between chips is what a task
+only READS.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import threading
 from typing import List, Optional
 
 from ..data.data import FlowAccess
+from ..obs.phases import PLACED_BY  # noqa: F401  (the rules' counters)
 
 
 class Device:
@@ -95,8 +97,9 @@ def get_best_device(task, devices: List[Device],
        else the least ``device_load`` + estimate.
 
     The chosen device's ``stats`` count which rule placed the task
-    (``placed_by_owner`` / ``placed_by_load``).  With one eligible
-    device there is nothing to decide and nothing is counted.
+    (``PLACED_BY``: ``placed_by_owner`` / ``placed_by_advice`` /
+    ``placed_by_load``).  With one eligible device there is nothing to
+    decide and nothing is counted.
     """
     if eligible_types is not None:
         devices = [d for d in devices if d.device_type in eligible_types]
@@ -119,7 +122,7 @@ def get_best_device(task, devices: List[Device],
         if advised is None:
             advised = by_index.get(data.preferred_device)
     if advised is not None:
-        return _placed(advised, "placed_by_load")
+        return _placed(advised, "placed_by_advice")
     estimate = tc.time_estimate
 
     def score(dev: Device) -> float:

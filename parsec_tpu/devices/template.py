@@ -28,7 +28,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, List, Optional
 
-from .device import Device
+from .device import PLACED_BY, Device
 
 
 class TemplateDevice(Device):
@@ -43,8 +43,7 @@ class TemplateDevice(Device):
         # load balancer prefers them for tasks that have a chore here
         self.time_estimate_default = 1.0
         self._executor = executor or (lambda fn, *args: fn(*args))
-        self.stats = {"tasks": 0,
-                      "placed_by_owner": 0, "placed_by_load": 0}
+        self.stats = dict.fromkeys(("tasks",) + PLACED_BY, 0)
 
     def kernel_scheduler(self, es, task) -> Any:
         """Entry point called by the chore hook (the

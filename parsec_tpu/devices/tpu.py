@@ -215,8 +215,9 @@ class JaxDevice(Device):
                       "retired_calls": 0,
                       # which rule of get_best_device sent a task here:
                       # the device owned a tile the task writes, or
-                      # first touch (advice, else load)
-                      "placed_by_owner": 0, "placed_by_load": 0,
+                      # first touch (where advised, else least load)
+                      "placed_by_owner": 0, "placed_by_advice": 0,
+                      "placed_by_load": 0,
                       # parts of compound taskpools (runtime/compound.py)
                       # whose first device call left from here
                       "compound_parts": 0,

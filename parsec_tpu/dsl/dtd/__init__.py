@@ -103,7 +103,9 @@ class DTDTile:
         self.sent_to: set = set()
         self.recv_proxy: Optional["_DTDRecord"] = None
         self.recv_proxy_seq = -1
-        self.flushed_at_seq = -1  # SPMD-consistent (set at insertion time)
+        # SPMD-consistent (set at insertion time); a tile no task has
+        # written is at home already and is never flushed
+        self.flushed_at_seq = 0
 
 
 class _DTDRecord:
@@ -388,17 +390,7 @@ class DTDTaskpool(Taskpool):
             "add_chore before create_task_class or the first insert_task " \
             "of this body"
         from ...devices.batching import (DeviceBatchSpec, kernel_named_for,
-                                         program_name)
-        # a task dispatched alone runs under its class's name too
-        # (jit_<CLASS>, as its stacked programs are jit_<CLASS>_x<n>)
-        alone = kernel_named_for(program_name(tc.name, 1), fn)
-
-        def wrapped(task: Task, arrays: List[Any]) -> Any:
-            args = [arrays[p.flow_index] if p.tile is not None else p.value
-                    for p in task.user
-                    if p.tile is not None or (p.mode & VALUE)]
-            return alone(*args)
-
+                                         kernel_with_constants, program_name)
         # batched-dispatch recipe (devices/batching.py): tile args are
         # the batch axis, VALUE params are static (part of the group
         # key, so only tasks passing EQUAL values stack together)
@@ -432,6 +424,24 @@ class DTDTaskpool(Taskpool):
             if out is None:
                 return ()
             return tuple(out) if isinstance(out, (tuple, list)) else (out,)
+
+        # a task dispatched alone runs under its class's name too
+        # (jit_<CLASS>, as its stacked programs are jit_<CLASS>_x<n>),
+        # and a jitted kernel takes its VALUE params as the constants
+        # they are in a stacked program
+        lone_name = program_name(tc.name, 1)
+        alone = kernel_named_for(lone_name, fn)
+
+        def wrapped(task: Task, arrays: List[Any]) -> Any:
+            got = extract(task, arrays)
+            if got is not None and any(got[2]):
+                bound = kernel_with_constants(lone_name, fn, got[2])
+                if bound is not None:
+                    return bound(*got[0])
+            args = [arrays[p.flow_index] if p.tile is not None else p.value
+                    for p in task.user
+                    if p.tile is not None or (p.mode & VALUE)]
+            return alone(*args)
 
         # cache_token=fn: ``call`` reassembles its args from the static
         # key and invokes only the user kernel, so the compiled stacked
@@ -792,6 +802,10 @@ class DTDTaskpool(Taskpool):
         tile.flushed_at_seq = tile.writers_seq
 
     def data_flush_all(self) -> None:
+        """Flush every tile written since its last flush.  A tile that
+        was only read gets no flush task: its home copy is the newest,
+        and a flush task (INOUT on the host) would bump that copy's
+        version under the readers' device copies for nothing."""
         for _, tile in self._tiles.items():
             if tile.flushed_at_seq != tile.writers_seq:
                 self.data_flush(tile)

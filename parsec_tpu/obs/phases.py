@@ -39,7 +39,9 @@ bracket ``wall_ns`` and ``count``, summed over the accelerator devices
 (``manager``) and for each (``by_device``).  The brackets of one device
 are disjoint, so their sum is at most the call's root span.  Each
 ``by_device`` entry also holds, under ``reshape``, what the reshape
-engine's counters of that device moved by (``RESHAPE_COUNTERS``).
+engine's counters of that device moved by (``RESHAPE_COUNTERS``), and
+under ``placement`` the tasks the device ran and which rule of
+``get_best_device`` sent them there (``PLACEMENT_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -55,7 +57,8 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PhaseClock", "root_span",
+__all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PLACED_BY",
+           "PLACEMENT_COUNTERS", "PhaseClock", "root_span",
            "session_recording", "completed", "clear_completed",
            "format_report"]
 
@@ -78,6 +81,13 @@ BRACKETS = ("set_stage", "group", "dispatch", "chip_wait", "epilog",
 #: the passes through the engine (``reshape`` is also a phase)
 RESHAPE_COUNTERS = ("conversions", "conversion_bytes", "reshape_hits",
                     "reshape_ns", "reshape_n")
+
+#: the counters of a device's ``stats`` that say which rule of
+#: ``devices.device.get_best_device`` sent a task there (they add up to
+#: the tasks placed among several accelerators), and with the tasks the
+#: device ran what a record's ``placement`` holds
+PLACED_BY = ("placed_by_owner", "placed_by_advice", "placed_by_load")
+PLACEMENT_COUNTERS = ("tasks",) + PLACED_BY
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -312,7 +322,9 @@ def _bracket_counters(devices: List[Any]) -> List[tuple]:
                             "count": dev.stats[b + "_n"]}
                         for b in BRACKETS},
                        reshape={c: dev.stats.get(c, 0)
-                                for c in RESHAPE_COUNTERS}))
+                                for c in RESHAPE_COUNTERS},
+                       placement={c: dev.stats.get(c, 0)
+                                  for c in PLACEMENT_COUNTERS}))
             for dev in devices
             if BRACKETS[0] + "_ns" in getattr(dev, "stats", ())]
 
@@ -322,7 +334,8 @@ def _manager_block(before: List[tuple]) -> Dict[str, Any]:
     counters moved by since ``before``."""
     after = _bracket_counters([dev for dev, _was in before])
     by_device = [dict({b: {f: now[b][f] - was[b][f] for f in now[b]}
-                       for b in BRACKETS + ("reshape",)}, device=dev.name)
+                       for b in BRACKETS + ("reshape", "placement")},
+                      device=dev.name)
                  for (dev, was), (_dev, now) in zip(before, after)]
     return {"manager": {b: {f: sum(e[b][f] for e in by_device)
                             for f in ("wall_ns", "count")}

@@ -1,5 +1,6 @@
 """Tile kernels (XLA/Pallas executables for task BODYs) and tile
-algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm)."""
+algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm,
+pdgemm_dtd)."""
 from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt, gemm_nt_lo,
                      gemm_nt_mid,
                      gemm_tn, gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
@@ -22,6 +23,7 @@ from .dgetrf import (dgetrf, dgetrf_factory, dgetrf_nopiv, dgetrf_nopiv_taskpool
                      make_diag_dominant)
 from .dgetrf_1d import dgetrf_1d, dgetrf_1d_factory, dgetrf_1d_taskpool
 from .pdgemm import pdgemm, pdgemm_factory, pdgemm_taskpool
+from .pdgemm_dtd import pdgemm_dtd
 from .dtrsm import (dposv, dtrsm_lower_taskpool, dtrsm_lower_trans_taskpool)
 
 from . import pallas_kernels
@@ -43,7 +45,7 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "trmm_lower_trans", "lauum_lower", "syrk_lt", "gemm_tn",
            "dgetrs", "dgesv",
            "make_diag_dominant",
-           "pdgemm", "pdgemm_factory", "pdgemm_taskpool",
+           "pdgemm", "pdgemm_factory", "pdgemm_taskpool", "pdgemm_dtd",
            "dposv", "dtrsm_lower_taskpool", "dtrsm_lower_trans_taskpool",
            "trsm_lower", "trsm_lower_trans", "gemm_tn_sub",
            "pallas_kernels", "flash_attention"]

@@ -1,0 +1,16 @@
+"""Scheduler and dependency release (``devices/device.py:
+get_best_device``): tasks per call that rule ``placed_by_load`` placed
+among several accelerators:
+no accelerator owned a tile the task writes and none was
+advised: a first touch the least ``device_load`` decided.
+The counter of that name in the chosen device's ``stats``, all devices.
+The three rules add up to the tasks placed; all read 0 with one
+accelerator (nothing to decide).  A count, so a rehearsal shows it.
+None where the program has no such counter."""
+from perfbench import counters
+
+COUNT = True
+
+
+def read(obs):
+    return counters.per_call(obs, "placed_by_load")

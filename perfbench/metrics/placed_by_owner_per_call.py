@@ -1,0 +1,16 @@
+"""Scheduler and dependency release (``devices/device.py:
+get_best_device``): tasks per call that rule ``placed_by_owner`` placed
+among several accelerators:
+an accelerator already OWNED a tile the task writes, so the
+task ran there: every update of a tile after its first.
+The counter of that name in the chosen device's ``stats``, all devices.
+The three rules add up to the tasks placed; all read 0 with one
+accelerator (nothing to decide).  A count, so a rehearsal shows it.
+None where the program has no such counter."""
+from perfbench import counters
+
+COUNT = True
+
+
+def read(obs):
+    return counters.per_call(obs, "placed_by_owner")

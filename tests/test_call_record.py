@@ -63,10 +63,17 @@ def _moved(dev, before):
 def _check_device_entry(entry, root_ns):
     """One device's six brackets: all there, disjoint (they add to no
     more than the root span); beside them the reshape engine's
-    counters, still where nothing declares a type."""
-    assert set(entry) == set(phases.BRACKETS) | {"device", "reshape"}
+    counters, still where nothing declares a type, and the tasks the
+    device ran with the rule that placed each."""
+    assert set(entry) == set(phases.BRACKETS) | {"device", "reshape",
+                                                 "placement"}
     assert set(entry["reshape"]) == set(phases.RESHAPE_COUNTERS)
     assert all(v >= 0 for v in entry["reshape"].values())
+    assert set(entry["placement"]) == set(phases.PLACEMENT_COUNTERS)
+    assert all(v >= 0 for v in entry["placement"].values())
+    placed = sum(entry["placement"].values()) - entry["placement"]["tasks"]
+    # one accelerator: nothing to decide, nothing counted
+    assert placed in (0, entry["placement"]["tasks"])
     for b in phases.BRACKETS:
         e = entry[b]
         assert set(e) == FIELDS

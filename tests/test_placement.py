@@ -13,7 +13,7 @@ from parsec_tpu import ops
 from parsec_tpu.collections import BlockColumnCyclic, TwoDimBlockCyclic
 from parsec_tpu.data.data import FlowAccess
 from parsec_tpu.devices import get_best_device
-from parsec_tpu.devices.device import Device
+from parsec_tpu.devices.device import PLACED_BY, Device
 from parsec_tpu.devices.template import template_chore_hook
 from parsec_tpu.devices.tpu import JaxDevice
 from parsec_tpu.utils.params import params
@@ -90,8 +90,9 @@ def test_every_writer_of_a_tile_ran_on_one_accelerator(ctx4, writers,
     assert not hopped
     # and the tiles are spread: placement did not collapse on one chip
     assert len({next(iter(w)) for _, w in writers.values()}) > 1
-    assert _stat(devs, "placed_by_owner") + _stat(devs, "placed_by_load") \
+    assert sum(_stat(devs, rule) for rule in PLACED_BY) \
         == _stat(devs, "tasks")
+    assert _stat(devs, "placed_by_advice") == 0     # nobody advised
 
 
 # --------------------------------------------------------------------- #
@@ -126,19 +127,47 @@ def test_no_tile_is_pulled_because_a_task_wrote_it_elsewhere(
 # --------------------------------------------------------------------- #
 # (c) the counters add up                                               #
 # --------------------------------------------------------------------- #
-def test_placement_counters_add_up_to_the_tasks_placed(ctx4):
+def _advise_none(devs, A):
+    return 0
+
+
+def _advise_lower(devs, A):
+    """Every tile dpotrf writes advised to a device: no first touch is
+    left to load."""
+    for m in range(NT):
+        for n in range(m + 1):
+            devs[(m + 2 * n) % len(devs)].data_advise(
+                A.data_of(m, n), "preferred_device")
+    return N_TILES
+
+
+def _advise_diagonal(devs, A):
+    for m in range(NT):
+        devs[m % len(devs)].data_advise(A.data_of(m, m), "preferred_device")
+    return NT
+
+
+@pytest.mark.parametrize("advise", [_advise_none, _advise_lower,
+                                    _advise_diagonal],
+                         ids=["no-advice", "all-advised", "some-advised"])
+def test_placement_counters_add_up_to_the_tasks_placed(ctx4, advise):
     devs = _accel(ctx4)
-    ops.dpotrf(ctx4, _spd())
+    A = _spd()
+    advised = advise(devs, A)
+    ops.dpotrf(ctx4, A)
     assert _stat(devs, "tasks") == N_TASKS
-    assert _stat(devs, "placed_by_owner") + _stat(devs, "placed_by_load") \
-        == N_TASKS
-    # every dpotrf task writes one tile, and load decides only a tile's
-    # first touch
-    assert _stat(devs, "placed_by_load") == N_TILES
+    assert sum(_stat(devs, rule) for rule in PLACED_BY) == N_TASKS
+    # every dpotrf task writes one tile; advice, else load, decides only
+    # a tile's first touch, and each is counted under its own rule
+    assert _stat(devs, "placed_by_advice") == advised
+    assert _stat(devs, "placed_by_load") == N_TILES - advised
+    assert _stat(devs, "placed_by_owner") == N_TASKS - N_TILES
     # a second factorization of a refilled matrix touches each tile
-    # first again: from_numpy hands the tiles back to the host
-    ops.dpotrf(ctx4, _spd())
-    assert _stat(devs, "placed_by_load") == 2 * N_TILES
+    # first again: from_numpy hands the tiles back to the host, and the
+    # advice stays with the tile
+    ops.dpotrf(ctx4, A.from_numpy(ops.make_spd(N)))
+    assert _stat(devs, "placed_by_advice") == 2 * advised
+    assert _stat(devs, "placed_by_load") == 2 * (N_TILES - advised)
 
 
 # --------------------------------------------------------------------- #
@@ -152,8 +181,7 @@ def test_one_accelerator_in_the_context_is_the_chosen_one():
         assert len(devs) == 1
         ops.dpotrf(ctx, _spd())
         assert devs[0].stats["tasks"] == N_TASKS
-        assert devs[0].stats["placed_by_owner"] == 0
-        assert devs[0].stats["placed_by_load"] == 0
+        assert [devs[0].stats[rule] for rule in PLACED_BY] == [0, 0, 0]
     finally:
         ctx.fini()
 
@@ -172,7 +200,8 @@ def test_an_advised_tile_gets_its_first_writer_where_advised(ctx4, writers):
             want[A.data_of(m, n).key] = {dev.device_index}
     ops.dpotrf(ctx4, A)
     assert {data.key: where for data, where in writers.values()} == want
-    assert _stat(devs, "placed_by_load") == N_TILES
+    assert _stat(devs, "placed_by_advice") == N_TILES
+    assert _stat(devs, "placed_by_load") == 0
 
 
 # --------------------------------------------------------------------- #
@@ -194,7 +223,7 @@ class _Dev(Device):
     def __init__(self, index, load=0.0):
         super().__init__("tpu", index)
         self.device_load = load
-        self.stats = {"placed_by_owner": 0, "placed_by_load": 0}
+        self.stats = dict.fromkeys(PLACED_BY, 0)
 
 
 def _task(*flows, time_estimate=None):
@@ -232,7 +261,7 @@ R, W, RW = FlowAccess.READ, FlowAccess.WRITE, FlowAccess.RW
     ([(RW, 2), (RW, 3)], [0, 9, 0], 2, "placed_by_owner"),
     ([(RW, 0), (RW, 3)], [0, 0, 9], 3, "placed_by_owner"),
     # advice decides a first touch, whatever the load
-    ([(R, 1), (RW, 0, 3)], [0, 0, 9], 3, "placed_by_load"),
+    ([(R, 1), (RW, 0, 3)], [0, 0, 9], 3, "placed_by_advice"),
     # ... but an owner comes before advice
     ([(RW, 0, 3), (RW, 2)], [0, 0, 0], 2, "placed_by_owner"),
     # advice that names no eligible device is no advice
@@ -250,9 +279,9 @@ def test_rule(flows, loads, chosen, rule):
     devs = [_Dev(i + 1, load) for i, load in enumerate(loads)]
     got = get_best_device(_task(*flows), devs, eligible_types={"tpu"})
     assert got.device_index == chosen
-    other = ({"placed_by_owner", "placed_by_load"} - {rule}).pop()
-    assert [(d.stats[rule], d.stats[other]) for d in devs] \
-        == [(int(d is got), 0) for d in devs]
+    assert [d.stats for d in devs] \
+        == [dict(dict.fromkeys(PLACED_BY, 0), **{rule: int(d is got)})
+            for d in devs]
 
 
 def test_rule_uses_the_class_estimate_on_a_first_touch():
@@ -268,11 +297,12 @@ def test_rule_skips_devices_of_another_type():
     assert get_best_device(_task((RW, 0)), devs,
                            eligible_types={"tpu"}).device_index == 2
     # the host owning the tile is first touch, not an owner to follow
-    assert devs[2].stats == {"placed_by_owner": 0, "placed_by_load": 1}
+    assert devs[2].stats == {"placed_by_owner": 0, "placed_by_advice": 0,
+                             "placed_by_load": 1}
     # one eligible device: returned as it is, nothing counted
     only = get_best_device(_task((RW, 2)), devs[:2], eligible_types={"tpu"})
     assert only is devs[1]
-    assert devs[1].stats == {"placed_by_owner": 0, "placed_by_load": 0}
+    assert devs[1].stats == dict.fromkeys(PLACED_BY, 0)
 
 
 def test_a_device_selector_overrides_the_rule():
