@@ -11,8 +11,9 @@ panel ``k``:
   column the entry of largest magnitude on or below the diagonal (exact
   partial pivoting: every ``|l_ij| <= 1``); yields the pivot tile.
 - ``UPDATE(k, n)``, ``n > k``: the panel's interchanges applied to block
-  column ``n`` (a gather by the pivot tile inside the kernel: which rows
-  move is known only when ``PANEL(k)`` has run, the DAG is static),
+  column ``n`` (a gather of the at most 2 NB rows they moved, by the
+  pivot tile inside the kernel: which rows move is known only when
+  ``PANEL(k)`` has run, the DAG is static),
   ``U_kn = L_kk^-1 A[r_k : r_k + NB, c_n]``, ``A[r_k + NB :, c_n] -=
   L_*k U_kn``.
 - ``LASWP(n)``, ``n < NT-1``: the interchanges of every later panel
@@ -33,8 +34,9 @@ panel wrote, the updates and ``LASWP`` from their panel's tile), the
 active rows are taken by ``dynamic_slice`` and a row mask.  No
 body reads a task local, so the stacked programs of a class
 (devices/batching.py) and the kernel a lone task runs are the same for
-every ``k``: the programs held do not grow with ``NT``.  Masked rows are
-computed and never counted as work.
+every ``k``: the programs held do not grow with ``NT``.  Where a kernel
+still computes masked rows (the XLA lowerings) they are never counted
+as work.
 
 The pivot tile is int32, ``(4, N)`` (``ops.linalg``): a WRITE-only NEW
 flow of ``PANEL(k)`` read by the ``NT-1-k`` updates, by ``PANEL(k+1)``

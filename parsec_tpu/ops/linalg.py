@@ -276,8 +276,11 @@ def trsm_upper_right(t: Any, c: Any) -> Any:
 #
 # Every kernel below works on a WHOLE block column at one static shape,
 # (N, nb): which rows are active is an operand, never a Python int, so
-# one program serves every panel index.  The pivot tile that travels
-# between them is int32, (4, N):
+# one program serves every panel index.  What a kernel MOVES and
+# multiplies is the active part: the panel's strip passes and the update
+# walk blocks of rows from the panel's first row down and store the at
+# most 2 nb rows the pivots moved; only LASWP gathers a whole column.
+# The pivot tile that travels between them is int32, (4, N):
 #   row 0: [first row of the NEXT panel, first row of THIS panel, 0...]
 #   row 1: g, the panel's interchanges as a gather: after[i] = before[g[i]]
 #   row 2: perm, every interchange so far: row i is original row perm[i]
@@ -501,23 +504,57 @@ def getrf_1d_panel(a: Any, q: Any) -> Any:
     return a, jnp.stack([meta, g, q[2][g], ipiv])
 
 
+def _lu_update(l: Any, c: Any, rows: Any, new: Any, r: Any) -> Any:
+    """What panel k changes in one block column ``c`` right of it, in
+    XLA.  ``new`` holds the 2 nb rows that moved, ``new[j]`` for row
+    ``rows[j]``: the pivots' rows, then the block row ``r .. r + nb -
+    1``, which is U already.  The pivots' rows are stored as one small
+    scatter (a row named twice carries equal values), the rows under
+    the block row take ``- L @ U`` (the rows of ``l`` at or above it
+    masked out of the product, not sliced away), and the block row goes
+    in last, which a pivot row inside it must not outlive.  The
+    lowering for every platform but the TPU and for the shapes the
+    kernel does not take (``pallas_kernels.lu_update_vmem``)."""
+    n, nb = l.shape
+    c = c.at[rows[:nb]].set(new[:nb])
+    under = jnp.arange(n, dtype=jnp.int32)[:, None] >= r + nb
+    c = gemm_nn_sub(c, jnp.where(under, l, 0), new[nb:])
+    return jax.lax.dynamic_update_slice(c, new[nb:], (r, 0))
+
+
+def _lu_update_lowered(l: Any, c: Any, rows: Any, new: Any, r: Any) -> Any:
+    """:func:`_lu_update` in the lowering the program's platform and the
+    column's shape call for: on the TPU, for a column the kernel takes
+    (``pallas_kernels.lu_update_fits``), the Mosaic kernel that walks
+    the column once; XLA anywhere else."""
+    if not pallas_kernels.lu_update_fits(*l.shape, c.shape[1]):
+        return _lu_update(l, c, rows, new, r)
+    return jax.lax.platform_dependent(
+        l, c, rows, new, r,
+        tpu=pallas_kernels.lu_update_vmem, default=_lu_update)
+
+
 @jax.jit
 def getrf_1d_update(l: Any, p: Any, c: Any) -> Any:
     """One block column right of panel k: interchange its rows by the
-    panel's pivots (a gather by p[1]: which rows move is known only
-    now), solve the block row U_kn = L_kk^-1 C[r:r+nb] and update the
-    rows below it, C -= L_*k U_kn.  ``l`` is the factored panel column,
-    ``p`` its pivot tile (r = p[0, 1]); the rows of ``l`` at or above
-    the block row are masked out of the product, not sliced away."""
-    n = c.shape[0]
+    panel's pivots, solve the block row U_kn = L_kk^-1 C[r:r+nb] and
+    update the rows below it, C -= L_*k U_kn.  ``l`` is the factored
+    panel column, ``p`` its pivot tile (r = p[0, 1]).  Only what the
+    panel changes is touched: the interchange moves the at most 2 nb
+    rows the panel's pivots moved (its nb pivot rows ``p[3][r:r+nb]``
+    and its block row, from the rows ``p[1]`` names: which rows move is
+    known only now), not the column, and ONE walk
+    (:func:`_lu_update_lowered`: a Mosaic kernel on the TPU, XLA
+    anywhere else) stores them and subtracts the product over the rows
+    under the block row, none over the rows above it; a row above ``r``
+    keeps its bits."""
     nb = l.shape[1]
     r = p[0, 1]
-    c = jnp.take(c, p[1], axis=0, unique_indices=True, mode="clip")
-    u = trsm_lower_unit(jax.lax.dynamic_slice(l, (r, 0), (nb, nb)),
-                        jax.lax.dynamic_slice(c, (r, 0), (nb, c.shape[1])))
-    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
-    c = gemm_nn_sub(c, jnp.where(rows >= r + nb, l, 0), u)
-    return jax.lax.dynamic_update_slice(c, u, (r, 0))
+    rows = jnp.concatenate([jax.lax.dynamic_slice(p[3], (r,), (nb,)),
+                            r + jnp.arange(nb, dtype=jnp.int32)])
+    new = jnp.take(c, p[1][rows], axis=0, mode="clip")
+    u = trsm_lower_unit(jax.lax.dynamic_slice(l, (r, 0), (nb, nb)), new[nb:])
+    return _lu_update_lowered(l, c, rows, new.at[nb:].set(u), r)
 
 
 @jax.jit

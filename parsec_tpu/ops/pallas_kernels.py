@@ -4,10 +4,10 @@ The reference keeps its hot math in hand-tuned native kernels (CUDA chores
 generated per task class, ref: parsec/interfaces/ptg/ptg-compiler/jdf2c.c:6557;
 the lone .cu kernel tests/dsl/dtd/dtd_test_new_tile_cuda_kernels.cu). The
 TPU-native analog is Pallas: Mosaic kernels that tile onto MXU/VPU with
-explicit VMEM residency. Four kernels live here; ``lu_strip_vmem`` and
-``lu_pass_vmem`` are the ones a task body of the runtime runs, the other
-two serve the transformer model and the ring-attention layer and no task
-class:
+explicit VMEM residency. Five kernels live here; ``lu_strip_vmem``,
+``lu_pass_vmem`` and ``lu_update_vmem`` are the ones a task body of the
+runtime runs, the other two serve the transformer model and the
+ring-attention layer and no task class:
 
 - ``flash_attention``: blockwise online-softmax attention (fwd is a single
   Pallas kernel with grid (BH, q_blocks, k_blocks); m/l/acc live in VMEM
@@ -25,13 +25,19 @@ class:
   tile to the right edge, blocks of rows from the one holding the
   strip's first row down; the rows that moved stored from VMEM, the
   product subtracted under the block row.
-  ``ops.linalg._lu_strip_lowered`` and ``_lu_pass_lowered`` pick the two
-  by the platform a program is lowered for and by the shapes; they read
-  no parameter, ``use_pallas`` and ``_on_tpu`` below are not asked.
+- ``lu_update_vmem``: what a panel changes in one block column right of
+  it (``ops.linalg.getrf_1d_update``, task class UPDATE): the column
+  walked ONCE, the rows the panel's pivots moved stored from VMEM, the
+  product subtracted from the rows under the block row and from no
+  other.
+  ``ops.linalg._lu_strip_lowered``, ``_lu_pass_lowered`` and
+  ``_lu_update_lowered`` pick the three by the platform a program is
+  lowered for and by the shapes; they read no parameter, ``use_pallas``
+  and ``_on_tpu`` below are not asked.
 
 Off-TPU (the virtual-CPU test mesh) ``flash_attention`` and ``matmul``
 run with ``interpret=True``, so tests validate the exact kernel code
-path; the two LU kernels are not lowered there at all (XLA is) and
+path; the three LU kernels are not lowered there at all (XLA is) and
 their tests pass ``interpret=True`` themselves.
 """
 from __future__ import annotations
@@ -781,3 +787,135 @@ def lu_pass_vmem(x: Any, st: Any, rows: Any, new: Any, d0: Any, *, c0: int,
     )(jnp.reshape(d0, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
       x, st, new)
     return (out[0], out[1]) if c1 < nb else (out[0], None)
+
+
+# ---------------------------------------------------------------------------
+# LU's UPDATE of one block column right of a panel: the rows the panel's
+# pivots moved and the product under the block row, in one walk
+# ---------------------------------------------------------------------------
+
+#: rows of the column (and of the panel) one grid step of an update holds
+_LU_UPDATE_ROWS = 512
+
+
+def _lu_update_vmem_bytes(nb: int, width: int) -> int:
+    """The VMEM one update kernel asks for: a block of rows of the
+    column coming in and one going out and a block of the panel, each
+    twice (the pipeline's two buffers), the moved rows likewise, the
+    product and its operands split for the MXU's passes, and room for
+    Mosaic's own scratch."""
+    blk = _LU_UPDATE_ROWS
+    return (4 * (4 * blk * width + 2 * blk * nb + 2 * 2 * nb * width)
+            + 4 * blk * width + 6 * (blk * nb + nb * width) + (4 << 20))
+
+
+def lu_update_fits(n: int, nb: int, width: int) -> bool:
+    """The shape rule of :func:`lu_update_vmem` for a (n, width) column
+    right of a (n, nb) panel: whole blocks of rows, whole lanes of
+    columns, a working set the kernel may ask for."""
+    return (n % _LU_UPDATE_ROWS == 0 and nb % 128 == 0 and width % 128 == 0
+            and n >= 2 * nb
+            and _lu_update_vmem_bytes(nb, width) <= _LU_STRIP_VMEM_MAX)
+
+
+def _lu_update_kernel(r_ref, rows_ref, c_ref, l_ref, new_ref, out_ref,
+                      head_ref, next_ref, *, nb: int):
+    blk = c_ref.shape[0]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        # the rows that moved, listed by the block of rows that holds
+        # them, each list in the order of ``rows``: once an update
+        def clear(b, carry):
+            head_ref[b] = -1
+            return carry
+
+        def push(k, carry):
+            j = 2 * nb - 1 - k
+            b = rows_ref[j] // blk
+            next_ref[j] = head_ref[b]
+            head_ref[b] = j
+            return carry
+
+        jax.lax.fori_loop(0, pl.num_programs(0), clear, 0)
+        jax.lax.fori_loop(0, 2 * nb, push, 0)
+
+    def put(j):
+        # one moved row: the pivots' rows first, then the block row (a
+        # pivot row inside the block row ends as its row of U)
+        c_ref[pl.ds(rows_ref[j] - i * blk, 1), :] = new_ref[pl.ds(j, 1), :]
+        return next_ref[j]
+
+    jax.lax.while_loop(lambda j: j >= 0, put, head_ref[i])
+    # the block's first row under the block row
+    under = r_ref[0] + nb - i * blk
+
+    @pl.when(under >= blk)          # none: the block goes out as it is
+    def _():
+        out_ref[...] = c_ref[...]
+
+    @pl.when(under < blk)
+    def _():
+        t = c_ref[...]
+        prod = jnp.dot(l_ref[...], new_ref[nb:, :],
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        pos = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+        out_ref[...] = jnp.where(pos >= under, t - prod, t)
+
+
+def lu_update_vmem(l: Any, c: Any, rows: Any, new: Any, r: Any, *,
+                   interpret: bool = False) -> Any:
+    """``ops.linalg._lu_update`` as ONE Mosaic kernel: what panel k
+    changes in one block column right of it, the column read once and
+    written once.
+
+    ``c`` is the (N, width) column; ``l`` the (N, nb) factored panel;
+    ``new`` the 2 nb rows that moved, ``new[j]`` for row ``rows[j]``:
+    the panel's pivot rows, then its block row ``r .. r + nb - 1``,
+    which is U already (solved against L_kk by the caller).  A grid
+    step holds a block of ``_LU_UPDATE_ROWS`` rows of the column and of
+    the panel.  Where the block came in it stores the rows that moved,
+    in order (the first step lists them by block in SMEM, so a step
+    walks its own and not all 2 nb); then the rows under the block row
+    take ``- L @ U`` (K = nb on the MXU at ``highest``) and every other
+    row goes out as it is.  ``new`` stays in VMEM for the whole walk.
+    The blocks above the one holding ``r`` hold no row that moved and
+    go through with their bits, and no block of the panel is brought in
+    for them (their steps name the first block that is).  The column is
+    NOT aliased to the result: the runtime does not donate a task's
+    column, so a kernel that wrote its operand would have XLA copy the
+    whole column first, which costs more than passing the upper blocks
+    through (PERF.md section 5).  Shapes: :func:`lu_update_fits`.
+    Reads no parameter; ``interpret`` is for the tests on the CPU."""
+    n, width = c.shape
+    nb = l.shape[1]
+    blk = _LU_UPDATE_ROWS
+
+    def block(i, r_ref, rows_ref):
+        return i, 0
+
+    def active(i, r_ref, rows_ref):
+        return jnp.maximum(i, r_ref[0] // blk), 0
+
+    def whole(i, r_ref, rows_ref):
+        return 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_lu_update_kernel, nb=nb),
+        out_shape=jax.ShapeDtypeStruct((n, width), c.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n // blk,),
+            in_specs=[pl.BlockSpec((blk, width), block),
+                      pl.BlockSpec((blk, nb), active),
+                      pl.BlockSpec((2 * nb, width), whole)],
+            out_specs=pl.BlockSpec((blk, width), block),
+            scratch_shapes=[pltpu.SMEM((n // blk,), jnp.int32),
+                            pltpu.SMEM((2 * nb,), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_lu_update_vmem_bytes(nb, width)),
+        name="lu_update_vmem", interpret=interpret,
+    )(jnp.reshape(r, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      c, l, new)

@@ -276,6 +276,56 @@ def test_pass_alone_compiles_for_the_v5e_in_place(one_chip, n, nb, c0):
     assert compiled.memory_analysis().alias_size_in_bytes == n * nb * 4
 
 
+@pytest.mark.parametrize("n,nb", [(16384, 512), (32768, 1024)])
+def test_update_program_for_the_v5e_is_one_kernel_and_no_gather(one_chip, n,
+                                                                nb):
+    """UPDATE at both LU cells' shapes: the Mosaic call ``lu_update_vmem``
+    and NOTHING else whose result is a whole column: no gather (the
+    interchange moves 2 NB rows), no product over all N rows, and no
+    copy before the call (the kernel writes a new column: the runtime
+    does not donate a task's, and a call that wrote its operand would
+    be handed a copy of it)."""
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pk.lu_update_fits(n, nb, nb)
+    compiled = linalg.getrf_1d_update.lower(
+        on((n, nb), jnp.float32), on((linalg.PIV_ROWS, n), jnp.int32),
+        on((n, nb), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%lu_update_vmem[.\d]* = ", text)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    entry = text[text.index("\nENTRY"):]
+    column = rf"f32\[{n},{nb}\]" + r"\{[^}]*\} "
+    whole = re.findall(r"^\s*(?:ROOT )?%(\S+) = " + column + r"([\w\-]+)\(",
+                       entry, re.M)
+    others = [(name, op) for name, op in whole
+              if op not in ("parameter", "get-tuple-element")
+              and not name.startswith("lu_update_vmem")]
+    assert others == [], others
+    assert not re.search(column + r"gather\(", text)
+    # the column goes in as it is and a new one comes out: no second copy
+    assert compiled.memory_analysis().temp_size_in_bytes < n * nb * 4 // 8
+
+
+@pytest.mark.parametrize("n,nb", [(16384, 512), (32768, 1024),
+                                  (57344, 1024)])
+def test_update_kernel_alone_compiles_for_the_v5e(one_chip, n, nb):
+    """The update kernel at both LU cells' shapes and at the height at
+    which f32 LU fills 16 GB: one Mosaic call, inside the VMEM it asks
+    for."""
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pk.lu_update_fits(n, nb, nb)
+    text = jax.jit(pk.lu_update_vmem).lower(
+        on((n, nb), jnp.float32), on((n, nb), jnp.float32),
+        on((2 * nb,), jnp.int32), on((2 * nb, nb), jnp.float32),
+        on((), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "lu_update_vmem" in text
+
+
 @pytest.mark.parametrize("kernel,operands", [
     ("gemm_nt_mid", "fff"), ("gemm_nt_lo", "fhh"), ("trsm_panel_mid", "ff"),
 ])
