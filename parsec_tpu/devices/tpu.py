@@ -28,11 +28,13 @@ import contextlib
 import threading
 import time
 from collections import OrderedDict
+from operator import itemgetter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.lists import Dequeue
 from ..data.data import Coherency, Data, DataCopy, FlowAccess
 from ..obs.phases import BRACKETS, _now
+from ..runtime.scheduling import hand_over_kept
 from ..runtime.taskpool import HookReturn, Task
 from ..utils import logging as plog
 from ..utils.params import params
@@ -57,7 +59,34 @@ _GUARDED_BY = {
     "JaxDevice._window": "_manager_lock",
     "JaxDevice._window_tasks": "_manager_lock",
     "JaxDevice._eager_done": "_manager_lock",
+    "JaxDevice._backlog": "_manager_lock",
+    "JaxDevice._landing": "_manager_lock",
 }
+
+#: the host bytes at which a chunk of a drained ready set closes
+#: (``JaxDevice._dispatch_ready``): the manager copies that much in ONE
+#: list ``device_put``, dispatches the tasks that waited for it, and
+#: only then copies on, so the chip computes under the rest of a wide
+#: front's copy instead of after it (the tiles of one list put become
+#: ready together, at its end: a front in one put hides nothing).  The
+#: v5e's host copies 13.8 GB/s to the chip, so 128 MiB keeps the
+#: chip's wait for a front's first kernel under 10 ms: eight NB = 2048
+#: tiles (one x8 call), one (32768, 1024) block column (larger than any
+#: bound tried: one a chunk), 128 tiles of 1 MB, so a 16-task bucket of
+#: small tiles (48 at most) never splits.  Chosen among 32 / 64 / 128
+#: MiB (PERF.md section 5).  The probe (``perfbench/checks/
+#: stage_front_probe.py``), two puts in flight: the three read the
+#: copy's own time within 5% at large tiles (146.8 / 148.7 / 153.2 ms
+#: for 120 x 16.8 MB, the copy alone 146.0) and within 1% of each
+#: other at 1 MB tiles.  The cells decided between 64 and 128: every
+#: chunk is a device call or more (0.28 ms + 74 us a task of the
+#: manager), and where the manager is the bound
+#: (``dpotrf-mp.n32768-nb2048``) 64 MiB LOST 2.7% to the pass before
+#: chunks and 128 gains 4.8%; where the chip is (``dgemm.n24576-
+#: nb2048``) 128 gains 12.7% and 64 gained 15.7% (the chip waits 5 ms
+#: longer for each k-step's first tiles); the others read the same.
+#: Observed in ``src.payload.nbytes``; not a parameter.
+STAGE_CHUNK_BYTES = 128 << 20
 
 
 def _arr_device(arr: Any):
@@ -197,6 +226,12 @@ class JaxDevice(Device):
                       # one call of a set's list is one), and the tiles
                       # they carried
                       "stage_in_transfers": 0, "stage_in_tiles": 0,
+                      # list puts the set pass issued, one a chunk of a
+                      # drained ready set; and tasks dispatched while
+                      # host tiles of their own set were still to be
+                      # copied (resident tasks sent ahead of a set over
+                      # ``STAGE_CHUNK_BYTES``, every chunk but its last)
+                      "stage_chunks": 0, "tasks_ahead_of_copy": 0,
                       # tiles ``prestage_many`` staged ahead of the
                       # per-task stage-in / found there by it
                       "prefetch_issued": 0, "prefetch_hits": 0,
@@ -232,8 +267,9 @@ class JaxDevice(Device):
         # per place it works under ``_manager_lock`` and none per task:
         # wall ns (``time.monotonic_ns``, a vDSO read) and how many.
         # Disjoint: a bracket inside another is taken out of it.
-        # ``set_stage``: the set pass, once a drain; ``group``: the rest
-        # of ``_dispatch_ready`` less the two inside it; ``dispatch``:
+        # ``set_stage``: the set pass, once a chunk of a drained set;
+        # ``group``: the rest of that pass of ``_dispatch_ready`` less
+        # the two inside it; ``dispatch``:
         # the device call (``first_call_ns`` is its part);
         # ``chip_wait``: ``_retire``'s wait for the call's outputs;
         # ``epilog``: ``_epilog`` up to ``complete_executions``;
@@ -250,6 +286,14 @@ class JaxDevice(Device):
         self._window: List[_InFlight] = []
         self._window_tasks = 0      # tasks the window's calls hold
         self._eager_done: List[_InFlight] = []
+        # drained tasks that wait for host tiles a later chunk of their
+        # set brings, with the bytes each waited for when it arrived,
+        # least first (``_dispatch_ready``)
+        self._backlog: List[Tuple[Task, float, int]] = []
+        # the last tile of each of the set pass's last two puts: a put
+        # waits until the one before last has landed
+        # (``_stage_in_set``)
+        self._landing: List[Any] = []
         # batched dispatch (the task-stream pipeline; ISSUE 5):
         # same-class ready tasks accumulate in ``pending`` and are
         # stacked into one jitted call per (class, shapes, dtypes,
@@ -257,7 +301,8 @@ class JaxDevice(Device):
         self.batch_max = int(params.get("device_batch_max"))
         self.batch_mode = str(params.get("device_batch_mode"))
         # read by the stage compiler's prestager and the tuner only:
-        # the manager stages a drained set whole (``prestage_many``)
+        # the manager stages a drained set by chunks of bytes
+        # (``_dispatch_ready``)
         self.prefetch_depth = int(params.get("device_prefetch_depth"))
         self.donate = bool(params.get("device_donate"))
         # segmented flush (ISSUE 7), read only when the context spans
@@ -322,56 +367,73 @@ class JaxDevice(Device):
             clock.push("manager")
         try:
             n = 0
-            # push phase: drain everything pending and dispatch it —
-            # same-class/same-shape tasks as stacked batches, the rest
-            # per task.  Submissions count as progress (they advance
-            # the pipeline even when no completion is ready yet).
-            drained: List[Tuple[Task, float]] = []
             while True:
-                item = self.pending.pop_front()
-                if item is None:
-                    break
-                drained.append(item)
-            if drained:
-                n += self._dispatch_ready(es, drained)
-            # poll phase: complete the in-flight calls that are ready
-            if self._eager_done:
-                done, self._eager_done = self._eager_done, []
-                for rec in done:
-                    self._epilog(es, rec)
-                    n += len(rec.tasks)
-            now = time.monotonic_ns()
-            if self._window:
-                # retire finished window entries so device_load drains on
-                # idle devices and async errors surface during the run
-                still_w = []
-                for rec in self._window:
-                    if rec.ready():
-                        rec.done_est = (rec.last_poll + now) // 2
-                        self._window_tasks -= len(rec.tasks)
-                        self._retire(rec, es)
-                    else:
-                        rec.last_poll = now
-                        still_w.append(rec)
-                self._window = still_w
-            still: List[_InFlight] = []
-            done = []
-            for rec in self._inflight:
-                if rec.ready():
-                    rec.done_est = (rec.last_poll + now) // 2
-                    done.append(rec)
-                else:
-                    rec.last_poll = now
-                    still.append(rec)
-            self._inflight = still
-            for rec in done:
-                self._epilog(es, rec)
-                n += len(rec.tasks)
-            return n
+                # push phase: drain everything pending and dispatch it —
+                # same-class/same-shape tasks as stacked batches, the
+                # rest per task.  Submissions count as progress (they
+                # advance the pipeline even when no completion is ready
+                # yet).
+                drained: List[Tuple[Task, float]] = []
+                while True:
+                    item = self.pending.pop_front()
+                    if item is None:
+                        break
+                    drained.append(item)
+                if drained or self._backlog:
+                    n += self._dispatch_ready(es, drained)
+                n += self._poll(es)
+                if not self._backlog:
+                    return n
+                # a set over ``STAGE_CHUNK_BYTES`` left tasks waiting
+                # for their chunk: what the poll released and what
+                # arrived under the chunk's copy is drained first.  The
+                # best of what this thread released was kept for it to
+                # run next, and it is not going back to its loop: the
+                # other workers get it
+                hand_over_kept(es)
         finally:
             if clock is not None:
                 clock.pop("manager")
             self._manager_lock.release()
+
+    def _poll(self, es) -> int:  # holds: self._manager_lock
+        """The poll phase: complete the in-flight calls that are ready
+        (the epilogs release their successors).  Returns the tasks
+        completed."""
+        n = 0
+        if self._eager_done:
+            done, self._eager_done = self._eager_done, []
+            for rec in done:
+                self._epilog(es, rec)
+                n += len(rec.tasks)
+        now = time.monotonic_ns()
+        if self._window:
+            # retire finished window entries so device_load drains on
+            # idle devices and async errors surface during the run
+            still_w = []
+            for rec in self._window:
+                if rec.ready():
+                    rec.done_est = (rec.last_poll + now) // 2
+                    self._window_tasks -= len(rec.tasks)
+                    self._retire(rec, es)
+                else:
+                    rec.last_poll = now
+                    still_w.append(rec)
+            self._window = still_w
+        still: List[_InFlight] = []
+        done = []
+        for rec in self._inflight:
+            if rec.ready():
+                rec.done_est = (rec.last_poll + now) // 2
+                done.append(rec)
+            else:
+                rec.last_poll = now
+                still.append(rec)
+        self._inflight = still
+        for rec in done:
+            self._epilog(es, rec)
+            n += len(rec.tasks)
+        return n
 
     def _book(self, bracket: str, wall_ns: int) -> None:
         """Close one always-on bracket of the manager."""
@@ -572,36 +634,119 @@ class JaxDevice(Device):
     # batched dispatch: stack same-class ready tasks into ONE jitted     #
     # call (devices/batching.py; ISSUE 5 tentpole)                       #
     # ------------------------------------------------------------------ #
-    def _dispatch_ready(self, es, items: List[Tuple[Task, float]]) -> int:
-        """Dispatch a drained ready set: group by (class, static context,
-        shapes, dtypes, donate mask), stack each group into power-of-two
-        buckets, fall back per-task for singletons / shape-divergent /
-        unbatchable tasks.  Returns the number of tasks submitted.
+    def _dispatch_ready(self, es, items: List[Tuple[Task, float]]) -> int:  # holds: self._manager_lock
+        """Dispatch a drained ready set, a CHUNK of its host bytes at a
+        time, and return the number of tasks submitted; what it leaves
+        in ``_backlog`` the caller's loop hands back after its poll
+        phase (``progress``).
 
-        Two always-on brackets: ``set_stage`` around the set pass,
-        ``group`` around the rest less the ``dispatch`` and
-        ``chip_wait`` brackets closed inside it."""
+        A set whose host tiles stay under ``STAGE_CHUNK_BYTES`` (and
+        no backlog before it) is one chunk: ONE list ``device_put``
+        (``_stage_in_set``), then ``_dispatch_groups`` over the whole
+        set: group by (class, static context, shapes, dtypes, donate
+        mask), stack each group into power-of-two buckets, fall back
+        per-task for singletons / shape-divergent / unbatchable tasks.
+
+        A set over it: the tasks that wait for no host tile are
+        dispatched first (they cost no bytes and never queue behind a
+        copy); the others join the backlog, which is kept by the host
+        bytes a task waited for when it arrived, least first (arrival
+        order, the scheduler's, among equals: a front of equal tiles
+        keeps it).  Then the head of the backlog
+        up to the bound, closed at a task boundary, is looked at again
+        (a tile an earlier chunk brought is not brought again), its
+        tiles go in ONE put and its tasks are dispatched right behind
+        it: the chip starts on this chunk while the next pass copies
+        the next.  Same tasks, same kernels, same per-tile order;
+        ``unroll`` stacking is bit-exact however tasks are grouped.
+
+        Two always-on brackets, each booked once a pass: ``set_stage``
+        (the looks and the put) and ``group`` (the rest, less the
+        ``dispatch`` and ``chip_wait`` brackets closed inside it)."""
+        st = self.stats
+        backlog = self._backlog
+        wall = [0, 0]   # ns of the set pass, ns of the grouping
+        n = 0
         try:
-            self._stage_in_set(items)
-        except Exception as exc:
-            plog.warning("tpu set stage-in failed: %s", exc)
-            # nothing was dispatched: the whole set goes BACK to
-            # pending, for the abort path's drain() to credit its load
-            for item in items:
-                self.pending.push_back(item)
-            raise
+            with self._set_pass(wall, len(items)):
+                need: Dict[int, Tuple] = {}
+                looks = [self._host_need(task, need) for task, _e in items]
+                whole = not backlog and sum(
+                    new for _w, new in looks) < STAGE_CHUNK_BYTES
+                first = [] if whole else [
+                    it for it, (w, _new) in zip(items, looks) if not w]
+                backlog.extend((task, est, w) for (task, est), (w, _new)
+                               in zip(items, looks) if whole or w)
+                if not whole:
+                    # least bytes first (stable: arrival order, the
+                    # scheduler's, among equals): a later arrival that
+                    # waits for little (LU's PANEL(k + 1): its 0.5 MB
+                    # pivot tile) does not queue behind the rest of a
+                    # front of 134 MB columns
+                    backlog.sort(key=itemgetter(2))
+            if first:
+                n = self._grouped(es, first, wall)
+                st["tasks_ahead_of_copy"] += n
+            with self._set_pass(wall, len(backlog)):
+                k = len(backlog)
+                if not whole:
+                    need, held, k = {}, 0, 0
+                    for task, _e, _w in backlog:
+                        k += 1
+                        held += self._host_need(task, need)[1]
+                        if held >= STAGE_CHUNK_BYTES:
+                            break
+                chunk = backlog[:k]
+                del backlog[:k]
+                try:
+                    self._stage_in_set(need)
+                except Exception as exc:
+                    plog.warning("tpu set stage-in failed: %s", exc)
+                    # nothing of the chunk was dispatched: it goes BACK
+                    # to the head of the backlog, for the next pass or
+                    # for the abort path's drain() to credit its load
+                    backlog[:0] = chunk
+                    raise
+            k = self._grouped(es, [it[:2] for it in chunk], wall)
+            if backlog:
+                st["tasks_ahead_of_copy"] += k
+            return n + k
+        finally:
+            self._book("set_stage", wall[0])
+            self._book("group", wall[1])
+
+    @contextlib.contextmanager
+    def _set_pass(self, wall: List[int], n: int):
+        """A stretch of the set pass: its wall ns join ``wall[0]``;
+        with a phase clock, a ``stage_in`` span of class ``set``."""
+        clock = self._phases
+        t0 = _now()
+        if clock is not None:
+            clock.push("stage_in", t0, cls="set", n=n)
+        try:
+            yield
+        finally:
+            t1 = _now()
+            if clock is not None:
+                clock.pop("stage_in", at_ns=t1)
+            wall[0] += t1 - t0
+
+    def _grouped(self, es, items: List[Tuple[Task, float]],
+                 wall: List[int]) -> int:
+        """``_dispatch_groups`` with its wall ns, less the ``dispatch``
+        and ``chip_wait`` brackets closed inside, joining ``wall[1]``."""
         st = self.stats
         t0 = _now()
         inside = st["dispatch_ns"] + st["chip_wait_ns"]
         try:
             return self._dispatch_groups(es, items)
         finally:
-            self._book("group", _now() - t0 - (
-                st["dispatch_ns"] + st["chip_wait_ns"] - inside))
+            wall[1] += _now() - t0 - (
+                st["dispatch_ns"] + st["chip_wait_ns"] - inside)
 
     def _dispatch_groups(self, es, items: List[Tuple[Task, float]]) -> int:
-        """``_dispatch_ready`` after the set pass: the per-task
-        stage-in, the grouping and every dispatch."""
+        """``_dispatch_ready`` behind a chunk's put: the per-task
+        stage-in, the grouping and every dispatch of ``items``."""
         from .batching import bucket_size, settle
         groups: Dict[Any, List[Tuple]] = {}
         order: List[Any] = []   # dispatch groups in arrival order
@@ -811,35 +956,73 @@ class JaxDevice(Device):
                          sum(entry[1] for entry in chunk), waits)
 
     # ------------------------------------------------------------------ #
-    # set stage-in: the host tiles a drained ready set needs go to the   #
-    # chip in ONE call, ahead of the per-task stage-in                   #
+    # set stage-in: the host tiles a chunk of a drained ready set needs  #
+    # go to the chip in ONE call, ahead of the per-task stage-in         #
     # ------------------------------------------------------------------ #
-    def _stage_in_set(self, items: List[Tuple[Task, float]]) -> None:
-        """Stage every input tile of a drained set that is still on the
-        host (``prestage_many``), so that the per-task stage-in finds
-        it resident.  Semantics never depend on this pass: what it
+    def _host_need(self, task: Task, need: Dict[int, Tuple]) -> Tuple[int, int]:
+        """The input tiles of ``task`` that wait for bytes from the
+        host (no current copy here, the newest one in host memory):
+        their bytes, and the bytes of those not yet in ``need``, which
+        they join as id(data) -> (data, the task's stage target, its
+        bytes).  A tile whose copy here is the owner (every tile a task
+        has written: the common case) costs one ``get_copy`` and one
+        compare."""
+        index = self.device_index
+        OWNED = Coherency.OWNED
+        waited = new = 0
+        target = None
+        for data in self._input_datas((task,)):
+            copy = data.get_copy(index)
+            if copy is not None and copy.coherency == OWNED:
+                continue   # the one newest copy is here
+            known = need.get(id(data))
+            if known is not None:
+                waited += known[2]
+                continue
+            found = self._host_source(data)
+            if found is None:
+                continue
+            if target is None:
+                target = self._stage_target(task)
+            nbytes = getattr(found[1].payload, "nbytes", 0)
+            need[id(data)] = (data, target, nbytes)
+            waited += nbytes
+            new += nbytes
+        return waited, new
+
+    def _stage_in_set(self, need: Dict[int, Tuple]) -> None:  # holds: self._manager_lock
+        """Stage the host tiles ``need`` holds (``_host_need``: those of
+        ONE chunk of a drained set) in ONE list ``device_put`` a stage
+        target (``prestage_many``), so that the per-task stage-in finds
+        them resident.  Semantics never depend on this pass: what it
         leaves (a source on another chip, detached scratch, a lost
-        race) the per-task stage-in stages.  The always-on bracket
-        ``set_stage``."""
-        clock = self._phases
-        t0 = _now()
-        if clock is not None:
-            clock.push("stage_in", t0, cls="set", n=len(items))
-        try:
-            by_target: Dict[Any, List[Task]] = {}
-            for task, _est in items:
-                by_target.setdefault(self._stage_target(task),
-                                     []).append(task)
-            for target, tasks in by_target.items():
-                self.prestage_many(self._input_datas(tasks), target)
-        finally:
-            t1 = _now()
-            if clock is not None:
-                clock.pop("stage_in", at_ns=t1)
-            self._book("set_stage", t1 - t0)
+        race) the per-task stage-in stages.  Inside the caller's
+        ``set_stage`` bracket; counter ``stage_chunks``: the puts."""
+        by_target: Dict[Any, List[Data]] = {}
+        for data, target, _nbytes in need.values():
+            by_target.setdefault(target, []).append(data)
+        st = self.stats
+        before = st["stage_in_transfers"]
+        landing = self._landing
+        for target, datas in by_target.items():
+            if len(landing) > 1:
+                # at most TWO puts in flight: a put of large tiles hands
+                # the thread back at 40% of its copy, and puts issued
+                # without a bound land late, each behind all the others
+                # (PERF.md section 5: two in flight read the copy's own
+                # time, none and one 20-50% more).  Small tiles have
+                # landed when their put returns: nothing to wait for
+                tile = landing.pop(0)
+                if not tile.is_deleted():
+                    tile.block_until_ready()
+            committed = self.prestage_many(datas, target)
+            if committed:
+                landing.append(
+                    committed[-1].get_copy(self.device_index).payload)
+        st["stage_chunks"] += st["stage_in_transfers"] - before
 
     @staticmethod
-    def _input_datas(tasks: List[Task]):
+    def _input_datas(tasks: Sequence[Task]):
         """The Data behind every non-CTL flow of ``tasks`` that carries
         one, in flow order, duplicates included."""
         for task in tasks:
@@ -851,6 +1034,29 @@ class JaxDevice(Device):
                 if copy_in is not None and copy_in.data is not None:
                     yield copy_in.data
 
+    def _host_source(self, data: Data) -> Optional[Tuple]:
+        """(copy here or None, the newest copy elsewhere, its version)
+        if ``data`` has no current copy here and its newest bytes are in
+        host memory; None otherwise.  Looked at under the Data's lock,
+        the version snapshotted WITH the payload decision: a commit
+        must stamp the version these bytes had, not whatever the source
+        advanced to meanwhile (an eviction writeback bumping the host
+        copy between a device_put and its commit must not get its new
+        version pinned onto old bytes)."""
+        from ..data.data import is_device_array
+        index = self.device_index
+        with data._lock:
+            copy = data.get_copy(index)
+            if copy is not None and copy.coherency != Coherency.INVALID \
+                    and copy.version >= data.newest_version():
+                return None
+            src = data.newest_copy(exclude_device=index)
+            src_version = src.version if src is not None else -1
+        if src is None or src.payload is None \
+                or is_device_array(src.payload):
+            return None   # nothing to pull, or the source is a chip
+        return copy, src, src_version
+
     def prestage_data(self, data: Data) -> bool:
         """``prestage_many`` of one Data: was its payload committed?"""
         return bool(self.prestage_many((data,)))
@@ -859,12 +1065,12 @@ class JaxDevice(Device):
         """Stage the newest HOST payload of every Data of ``datas`` that
         has no current copy here, ahead of the stage-in that needs it:
         the one function that issues the host-to-device stage-in of a
-        set (the manager's drained ready set; the stage compiler's
-        prestager).  Plan: a Data whose copy here is the owner (every
+        set (a chunk of the manager's drained ready set; the stage
+        compiler's prestager).  Plan: a Data whose copy here is the owner (every
         tile a task has written, the common case of a drained set)
         costs one ``get_copy`` and one compare; any other is looked at
         under its lock and, unless current, its newest host copy taken
-        with the version those bytes have.  Transfer: ``_reserve`` once, ONE
+        with the version those bytes have (``_host_source``).  Transfer: ``_reserve`` once, ONE
         ``jax.device_put`` of the list.  jax walks the list and issues
         a copy per array, so this saves jax's Python a tile and not the
         copy: packing the tiles into one array first (``np.stack``, one
@@ -877,7 +1083,6 @@ class JaxDevice(Device):
         Datas whose payloads committed (resident tiles and lost races
         are excluded, so a caller's hit accounting is exact)."""
         import jax
-        from ..data.data import is_device_array
         if target is None:
             target = self.jax_device
         index = self.device_index
@@ -890,23 +1095,9 @@ class JaxDevice(Device):
                 continue   # the one newest copy is here
             if id(data) in plan:
                 continue
-            with data._lock:
-                copy = data.get_copy(index)
-                if copy is not None and copy.coherency != INVALID \
-                        and copy.version >= data.newest_version():
-                    continue
-                src = data.newest_copy(exclude_device=index)
-                # snapshot the version WITH the payload decision: the
-                # commit below must stamp the version these bytes had,
-                # not whatever the source advanced to meanwhile (an
-                # eviction writeback bumping the host copy between our
-                # device_put and the commit must not get its new
-                # version pinned onto old bytes)
-                src_version = src.version if src is not None else -1
-            if src is None or src.payload is None \
-                    or is_device_array(src.payload):
-                continue   # nothing to pull, or the source is a chip
-            plan[id(data)] = (data, copy, src, src_version)
+            found = self._host_source(data)
+            if found is not None:
+                plan[id(data)] = (data,) + found
         if not plan:
             return []
         entries = list(plan.values())
@@ -1014,15 +1205,20 @@ class JaxDevice(Device):
         raise_pending_error surfaces them instead of a
         silently-successful wait().
 
-        Undispatched ``pending`` entries are DISCARDED: they can only
-        exist here when the DAG aborted mid-accumulation (batched
-        dispatch defers the flush), and executing them against a
-        poisoned run would be wrong — drop their load contribution and
-        let the abort path settle the taskpools."""
+        Undispatched entries, in ``pending`` or in the manager's
+        ``_backlog`` (a chunked set whose pass raised mid-way), are
+        DISCARDED: they can only exist here when the DAG aborted
+        mid-accumulation (batched dispatch defers the flush), and
+        executing them against a poisoned run would be wrong — drop
+        their load contribution and let the abort path settle the
+        taskpools."""
         if not self._manager_lock.acquire(blocking=True):
             return  # pragma: no cover - Lock.acquire(True) returns True
         try:
-            discarded = 0
+            discarded = len(self._backlog)
+            for _task, est, _waited in self._backlog:
+                self.load_sub(est)
+            self._backlog = []
             while True:
                 item = self.pending.pop_front()
                 if item is None:
@@ -1037,6 +1233,7 @@ class JaxDevice(Device):
             self._window = []
             self._window_tasks = 0
             self._prefetched.clear()
+            self._landing = []
         finally:
             self._manager_lock.release()
 
@@ -1281,6 +1478,7 @@ class JaxDevice(Device):
         self._window.clear()
         self._window_tasks = 0
         self._prefetched.clear()
+        self._landing = []
 
 
 def parse_mesh_shape(shape: Any) -> Tuple[int, int]:

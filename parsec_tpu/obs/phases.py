@@ -41,7 +41,9 @@ are disjoint, so their sum is at most the call's root span.  Each
 ``by_device`` entry also holds, under ``reshape``, what the reshape
 engine's counters of that device moved by (``RESHAPE_COUNTERS``), and
 under ``placement`` the tasks the device ran and which rule of
-``get_best_device`` sent them there (``PLACEMENT_COUNTERS``).
+``get_best_device`` sent them there (``PLACEMENT_COUNTERS``), and under
+``stage`` the puts of its chunked set pass and the tasks it sent ahead
+of a copy (``STAGE_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -58,7 +60,7 @@ from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PLACED_BY",
-           "PLACEMENT_COUNTERS", "PhaseClock", "root_span",
+           "PLACEMENT_COUNTERS", "STAGE_COUNTERS", "PhaseClock", "root_span",
            "session_recording", "completed", "clear_completed",
            "format_report"]
 
@@ -88,6 +90,17 @@ RESHAPE_COUNTERS = ("conversions", "conversion_bytes", "reshape_hits",
 #: device ran what a record's ``placement`` holds
 PLACED_BY = ("placed_by_owner", "placed_by_advice", "placed_by_load")
 PLACEMENT_COUNTERS = ("tasks",) + PLACED_BY
+
+#: what the chunked set pass of a device counts (``devices/tpu.py::
+#: _dispatch_ready``): the list puts it issued, one a chunk of a drained
+#: ready set, and the tasks it dispatched while host tiles of their own
+#: set were still to be copied; a record's ``stage``
+STAGE_COUNTERS = ("stage_chunks", "tasks_ahead_of_copy")
+
+#: the counter groups a record's ``by_device`` entry holds beside the
+#: brackets, by the key each goes under
+_GROUPS = {"reshape": RESHAPE_COUNTERS, "placement": PLACEMENT_COUNTERS,
+           "stage": STAGE_COUNTERS}
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -321,10 +334,8 @@ def _bracket_counters(devices: List[Any]) -> List[tuple]:
     return [(dev, dict({b: {"wall_ns": dev.stats[b + "_ns"],
                             "count": dev.stats[b + "_n"]}
                         for b in BRACKETS},
-                       reshape={c: dev.stats.get(c, 0)
-                                for c in RESHAPE_COUNTERS},
-                       placement={c: dev.stats.get(c, 0)
-                                  for c in PLACEMENT_COUNTERS}))
+                       **{group: {c: dev.stats.get(c, 0) for c in counters}
+                          for group, counters in _GROUPS.items()}))
             for dev in devices
             if BRACKETS[0] + "_ns" in getattr(dev, "stats", ())]
 
@@ -334,7 +345,7 @@ def _manager_block(before: List[tuple]) -> Dict[str, Any]:
     counters moved by since ``before``."""
     after = _bracket_counters([dev for dev, _was in before])
     by_device = [dict({b: {f: now[b][f] - was[b][f] for f in now[b]}
-                       for b in BRACKETS + ("reshape", "placement")},
+                       for b in BRACKETS + tuple(_GROUPS)},
                       device=dev.name)
                  for (dev, was), (_dev, now) in zip(before, after)]
     return {"manager": {b: {f: sum(e[b][f] for e in by_device)
@@ -435,6 +446,12 @@ def format_report(record: Dict[str, Any]) -> str:
         lines.append(f"in no bracket: {(root - inside / managers) / 1e9:.6f} "
                      f"s of the root span (the brackets' mean over "
                      f"{managers} manager(s) taken out)")
+        chunks, ahead = (sum(e.get("stage", {}).get(c, 0)
+                             for e in record["by_device"])
+                         for c in STAGE_COUNTERS)
+        if ahead:
+            lines.append(f"set pass: {chunks} list puts, {ahead} tasks "
+                         f"dispatched ahead of a copy of their own set")
         conv = {c: sum(e.get("reshape", {}).get(c, 0)
                        for e in record["by_device"])
                 for c in RESHAPE_COUNTERS}

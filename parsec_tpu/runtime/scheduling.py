@@ -137,6 +137,23 @@ def schedule_keep_best(es: ExecutionStream, tasks: List[Task], distance: int = 0
     schedule(es, tasks, distance)
 
 
+def hand_over_kept(es: ExecutionStream) -> None:
+    """Give the task :func:`schedule_keep_best` kept for this thread
+    (``es.next_task``) to the scheduler after all: the thread is not
+    going back to its loop soon (a device manager between two chunks of
+    a wide ready set), and the kept task is the best of what it just
+    released.  Counted as enabled when it was kept."""
+    task = es.next_task
+    if task is None:
+        return
+    es.next_task = None
+    ctx = es.context
+    PINS(es, PinsEvent.SCHEDULE_BEGIN, [task])
+    ctx.scheduler.schedule(es, [task], 0)
+    ctx.wake_workers(1)
+    PINS(es, PinsEvent.SCHEDULE_END, [task])
+
+
 def execute(es: ExecutionStream, task: Task) -> HookReturn:
     """ref: __parsec_execute (scheduling.c:124-203) — walk incarnations by
     chore mask; evaluate() may veto a chore; the first willing hook runs."""

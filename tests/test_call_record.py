@@ -66,7 +66,11 @@ def _check_device_entry(entry, root_ns):
     counters, still where nothing declares a type, and the tasks the
     device ran with the rule that placed each."""
     assert set(entry) == set(phases.BRACKETS) | {"device", "reshape",
-                                                 "placement"}
+                                                 "placement", "stage"}
+    assert set(entry["stage"]) == set(phases.STAGE_COUNTERS)
+    # small tiles: every set under the bound, a put a pass at most
+    assert entry["stage"]["tasks_ahead_of_copy"] == 0
+    assert 0 < entry["stage"]["stage_chunks"] <= entry["set_stage"]["count"]
     assert set(entry["reshape"]) == set(phases.RESHAPE_COUNTERS)
     assert all(v >= 0 for v in entry["reshape"].values())
     assert set(entry["placement"]) == set(phases.PLACEMENT_COUNTERS)
@@ -121,7 +125,7 @@ def test_untraced_call_leaves_one_record(one_device_ctx, records, op):
     # every call filed is retired, waited for and completed once
     assert count["chip_wait"] == moved["retired_calls"] == count["dispatch"]
     assert count["epilog"] == count["complete"] == count["dispatch"]
-    # one set pass and one grouping pass a drained ready set
+    # one set pass and one grouping pass a chunk of a drained ready set
     assert count["set_stage"] == count["group"] >= 1
     if op == "dpoinv":
         assert [p["name"] for p in rec["parts"]] \
