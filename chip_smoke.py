@@ -146,8 +146,8 @@ def report_devices(devs):
 
 def block_on_tiles(A):
     """End of every timed region: ``wait()`` returns at dispatch
-    (tpu_eager_complete), the work is done when every tile's newest
-    copy is ready."""
+    (dependencies release there), the work is done when every tile's
+    newest copy is ready."""
     import jax
     jax.block_until_ready([A.data_of(*c).newest_copy().payload
                            for c in A.tiles()])

@@ -22,7 +22,7 @@ Finding codes (BDY2xx):
   dispatch (``spec.batchable = False``).
 - ``BDY203`` nondeterminism: a device body reads wall-clock time or an
   unseeded random stream — stacked executions lose the bit-exact
-  batched-vs-per-task guarantee of ``device_batch_mode=unroll``.
+  batched-vs-per-task guarantee of a stacked call.
 - ``BDY204`` aliased-args (warn): two flows of one class read the same
   memory tile — at dispatch the same buffer sits at two argument
   slots, so buffer donation (``device_donate``) is suppressed for

@@ -16,27 +16,19 @@ as a pure function of those arrays).  The device module groups ready
 tasks whose (spec, static context, shapes, dtypes) agree and dispatches
 each group through one jitted callable built here.
 
-Two stacking modes (``device_batch_mode``):
-
-- ``unroll`` (default): the batched program contains one per-example
-  subgraph per task — N independent copies of exactly the graph the
-  per-task path traces, returned from ONE dispatch.  Results are
-  bit-exact vs per-task execution (each op lowers identically; measured
-  for cholesky / triangular-solve / matmul on the CPU backend — note
-  vmap is NOT bit-exact there for triangular solve), at the cost of
-  program size growing with the bucket.
-- ``vmap``: inputs are stacked and the body is vmapped — smaller
-  programs and batched kernels (MXU-friendly on TPU), but XLA may pick
-  a *different batched algorithm* (e.g. blocked triangular solve), so
-  results are only approximately equal to per-task execution.
+A stacked program is UNROLLED: it contains one per-example subgraph
+per task — N independent copies of exactly the graph the per-task path
+traces, returned from ONE dispatch.  Results are bit-exact vs per-task
+execution (each op lowers identically; measured for cholesky /
+triangular-solve / matmul on the CPU backend, where a vmapped body is
+NOT bit-exact for triangular solve: XLA picks a different batched
+algorithm), at the cost of program size growing with the bucket.
 
 Batch sizes are bucketed to powers of two so the set of programs stays
-small: 2, 4, 8, ... up to ``device_batch_max`` (16).  On a single rank
-a bucket is ONE call; only a context of more than one rank carves it
-into ``device_flush_segments`` sub-calls (:func:`segment_plan`), whose
-early outputs let dependency sends start under the later segments.  A
-program is keyed by (bucket, static, shapes/dtypes, donate
-mask, mode) and built ONCE PER PROCESS for every spec that can say what
+small: 2, 4, 8, ... up to ``device_batch_max`` (16); a bucket is ONE
+call, on one rank and across ranks.  A program is keyed by (bucket,
+static, shapes/dtypes, donate mask) and built ONCE PER PROCESS for
+every spec that can say what
 its ``call`` traces to (``cache_token``): a DTD kernel's token is the
 user function, a PTG body's is made from what the body reads
 (dsl/ptg/body_token.py) at its first stacked dispatch.  A fresh
@@ -63,7 +55,7 @@ import threading
 import weakref
 from typing import Any, Callable, Dict, Optional, Set, Tuple
 
-__all__ = ["DeviceBatchSpec", "bucket_size", "segment_plan",
+__all__ = ["DeviceBatchSpec", "bucket_size",
            "stacked_callable_key", "program_name", "KernelsNamedFor",
            "kernel_named_for", "programs_held",
            "settle", "downgrade",
@@ -86,8 +78,7 @@ class DeviceBatchSpec:
 
     ``call(bargs, static) -> tuple`` — the body as a traceable pure
         function: per-task outputs for the written flows, in flow
-        order.  Invoked under jit (and under vmap in ``vmap`` mode), so
-        it must be jax-traceable; an untraceable body is detected at
+        order.  Invoked under jit, so it must be jax-traceable; an untraceable body is detected at
         the first batched dispatch and the spec permanently falls back
         (``batchable = False``).
 
@@ -138,32 +129,6 @@ def bucket_size(navail: int, batch_max: int) -> int:
     while b * 2 <= n:
         b *= 2
     return b
-
-
-def segment_plan(n: int, requested: int) -> int:
-    """Segments a flush group of ``n`` tasks splits into where the
-    device module segments at all, that is in a context of more than
-    one rank (ISSUE 7 segmented flush; on one rank ``_dispatch_batch``
-    never asks): the largest power of two <= min(requested, n // 2),
-    so every segment keeps >= 2 tasks (amortization survives) and the
-    per-segment sizes are themselves powers of two sharing the stacked-
-    callable cache with ordinary buckets.  1 = whole-batch flush.
-
-    Splitting an ``unroll``-mode group is BIT-EXACT vs the whole-batch
-    dispatch: each task's per-example subgraph lowers identically
-    whether its siblings share the executable or not — what changes is
-    *when* each task's outputs materialize.  A segment's outputs become
-    ready as soon as ITS sub-call finishes, so dependency sends (the
-    D2H + wire time the T3 overlap story hides) start while the later
-    segments are still executing instead of at the batch boundary.
-    The price is the host's fixed cost of each extra call, which only
-    a send to overlap can pay for."""
-    if requested <= 1 or n < 4:
-        return 1
-    s, limit = 1, min(requested, n // 2)
-    while s * 2 <= limit:
-        s *= 2
-    return s
 
 
 def program_name(spec_name: str, n: int) -> str:
@@ -261,8 +226,8 @@ class KernelsNamedFor:
 
 
 def stacked_callable_key(n: int, nargs: int, static: Any,
-                         shapes: Tuple, donate: Tuple, mode: str) -> Tuple:
-    return (n, nargs, static, shapes, donate, mode)
+                         shapes: Tuple, donate: Tuple) -> Tuple:
+    return (n, nargs, static, shapes, donate)
 
 
 #: process-wide stacked-callable cache for specs with a ``cache_token``
@@ -364,21 +329,22 @@ def cached_stage_callable(token: Any, key: Any, build: Callable) -> Any:
 
 
 def cached_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
-                            static: Any, shapes: Tuple, mode: str,
+                            static: Any, shapes: Tuple,
                             donate: Tuple[bool, ...] = ()) -> Callable:
     """The AOT-cached stacked callable for this signature: per-token
     process-wide when the spec has a token (a new taskpool over the
     same kernel/shapes skips tracing, lowering AND loading), else
     per-spec (dies with the taskpool)."""
     return _cached(
-        spec, stacked_callable_key(n, nargs, static, shapes, donate, mode),
-        lambda: build_stacked_callable(spec, n, nargs, static, mode, donate))
+        spec, stacked_callable_key(n, nargs, static, shapes, donate),
+        lambda: build_stacked_callable(spec, n, nargs, static, donate))
 
 
 def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
-                           static: Any, mode: str,
+                           static: Any,
                            donate: Tuple[bool, ...] = ()) -> Callable:
-    """One jitted callable executing ``n`` same-signature tasks.
+    """One jitted callable executing ``n`` same-signature tasks, as
+    per-example subgraphs: bit-exact vs per-task dispatch.
 
     Flat calling convention (grouped by arg so donation maps to whole
     arg groups): ``flat[j * n + i]`` is batch-arg ``j`` of task ``i``;
@@ -392,24 +358,12 @@ def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     import jax
     call = spec.call
 
-    if mode == "vmap":
-        import jax.numpy as jnp
-
-        def stacked(*flat):
-            cols = tuple(jnp.stack(flat[j * n:(j + 1) * n])
-                         for j in range(nargs))
-            outs = jax.vmap(lambda *b: call(b, static))(*cols)
-            return tuple(outs[k][i] for k in range(len(outs))
-                         for i in range(n))
-    else:   # unroll: per-example subgraphs, bit-exact vs per-task
-
-        def stacked(*flat):
-            rows = [call(tuple(flat[j * n + i] for j in range(nargs)),
-                         static)
-                    for i in range(n)]
-            n_out = len(rows[0])
-            return tuple(rows[i][k] for k in range(n_out)
-                         for i in range(n))
+    def stacked(*flat):
+        rows = [call(tuple(flat[j * n + i] for j in range(nargs)), static)
+                for i in range(n)]
+        n_out = len(rows[0])
+        return tuple(rows[i][k] for k in range(n_out)
+                     for i in range(n))
 
     donate_argnums = tuple(j * n + i for j, d in enumerate(donate) if d
                            for i in range(n))
@@ -419,7 +373,7 @@ def build_stacked_callable(spec: DeviceBatchSpec, n: int, nargs: int,
 
 
 def cached_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
-                            static: Any, shapes: Tuple, mode: str,
+                            static: Any, shapes: Tuple,
                             mesh: Any) -> Callable:
     """The AOT-cached mesh-sharded stacked callable for this signature.
     The Mesh OBJECT joins the key (jax meshes hash by devices + axis
@@ -429,13 +383,13 @@ def cached_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     context rebuilding the SAME mesh over the same chips hits the
     token-cached callable."""
     return _cached(
-        spec, ("mesh", mesh, n, nargs, static, shapes, mode),
+        spec, ("mesh", mesh, n, nargs, static, shapes),
         lambda: build_sharded_callable(spec, n, nargs, static, shapes,
-                                       mode, mesh))
+                                       mesh))
 
 
 def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
-                           static: Any, shapes: Tuple, mode: str,
+                           static: Any, shapes: Tuple,
                            mesh: Any) -> Callable:
     """One jitted shard_map call executing ``n`` same-signature tasks
     SPREAD ACROSS the chip mesh.
@@ -443,10 +397,9 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     Calling convention: one GLOBAL array per batch arg, shape
     ``(n,) + row_shape``, sharded over every mesh axis on the leading
     (batch) dim — chip ``c`` holds rows ``[c*n/k, (c+1)*n/k)``.  Each
-    chip's shard_map body runs its local rows; ``unroll`` mode emits
-    one per-example subgraph per local row (bit-exact vs the
-    single-chip stacked path: the SAME per-example graph lowers on one
-    chip either way), ``vmap`` vmaps the body over the local block.
+    chip's shard_map body runs its local rows as one per-example
+    subgraph per local row (bit-exact vs the single-chip stacked path:
+    the SAME per-example graph lowers on one chip either way).
     Outputs come back as global arrays with the same leading-axis
     sharding; the device module slices per-task rows from the
     addressable shards so results stay chip-resident.
@@ -469,15 +422,11 @@ def build_sharded_callable(spec: DeviceBatchSpec, n: int, nargs: int,
     out_avals = jax.eval_shape(lambda *r: call(r, static), *row_avals)
     n_out = len(out_avals)
 
-    if mode == "vmap":
-        def local_fn(*blocks):
-            return jax.vmap(lambda *b: call(b, static))(*blocks)
-    else:   # unroll: per-example subgraphs per local row, bit-exact
-        def local_fn(*blocks):
-            rows = [call(tuple(b[i] for b in blocks), static)
-                    for i in range(n_local)]
-            return tuple(jnp.stack([rows[i][o] for i in range(n_local)])
-                         for o in range(n_out))
+    def local_fn(*blocks):
+        rows = [call(tuple(b[i] for b in blocks), static)
+                for i in range(n_local)]
+        return tuple(jnp.stack([rows[i][o] for i in range(n_local)])
+                     for o in range(n_out))
 
     sharded = shard_map_compat(local_fn, mesh,
                             in_specs=(batch_spec,) * nargs,

@@ -55,7 +55,6 @@ _GUARDED_BY = {
     "JaxDevice.mem_highwater": "_mem_lock",
     "JaxDevice._lru_clean": "_mem_lock",
     "JaxDevice._lru_owned": "_mem_lock",
-    "JaxDevice._inflight": "_manager_lock",
     "JaxDevice._window": "_manager_lock",
     "JaxDevice._window_tasks": "_manager_lock",
     "JaxDevice._eager_done": "_manager_lock",
@@ -87,6 +86,11 @@ _GUARDED_BY = {
 #: longer for each k-step's first tiles); the others read the same.
 #: Observed in ``src.payload.nbytes``; not a parameter.
 STAGE_CHUNK_BYTES = 128 << 20
+
+#: tasks the window's calls may hold after dispatch before the manager
+#: blocks on the oldest call: bounds how far the chip's queue runs ahead
+#: (the reference bounds in-flight work per stream)
+EAGER_WINDOW = 32
 
 
 def _arr_device(arr: Any):
@@ -199,7 +203,6 @@ class JaxDevice(Device):
         # device manager state (ref: gpu_device->mutex + pending)
         self.pending = Dequeue()
         self._manager_lock = threading.Lock()
-        self._inflight: List[_InFlight] = []
         # memory accounting + LRU (ref: zone_malloc + gpu_mem_lru/_owned_lru)
         self.mem_budget = self._probe_budget()
         self.mem_used = 0
@@ -240,11 +243,6 @@ class JaxDevice(Device):
                       # prove it stayed on the fast path asserts zero):
                       # stacked -> per-task, donated -> undonated retry
                       "batch_downgrades": 0, "donate_retries": 0,
-                      # segmented flush (ISSUE 7; across ranks only):
-                      # flush groups that were carved into pipelined
-                      # sub-calls, and the total sub-calls dispatched
-                      # for them
-                      "segmented_flushes": 0, "flush_segments": 0,
                       # call records retired: tasks / retired_calls is
                       # the tasks a record held
                       "retired_calls": 0,
@@ -279,10 +277,9 @@ class JaxDevice(Device):
         # fresh value costs 0.6 ms (PERF.md section 6, PR 35).
         for b in BRACKETS:
             self.stats.update({b + "_ns": 0, b + "_n": 0})
-        # eager completion (async dispatch IS completion; XLA orders the
-        # dataflow) with a bounded in-flight window
-        self.eager_complete = bool(params.get("tpu_eager_complete"))
-        self.eager_window = int(params.get("tpu_eager_window"))
+        # completion: dependencies release at dispatch (XLA orders the
+        # dataflow); the calls dispatched and not yet waited for are the
+        # window, bounded in tasks (``EAGER_WINDOW``)
         self._window: List[_InFlight] = []
         self._window_tasks = 0      # tasks the window's calls hold
         self._eager_done: List[_InFlight] = []
@@ -299,17 +296,11 @@ class JaxDevice(Device):
         # stacked into one jitted call per (class, shapes, dtypes,
         # bucket) at the next manager flush
         self.batch_max = int(params.get("device_batch_max"))
-        self.batch_mode = str(params.get("device_batch_mode"))
         # read by the stage compiler's prestager and the tuner only:
         # the manager stages a drained set by chunks of bytes
         # (``_dispatch_ready``)
         self.prefetch_depth = int(params.get("device_prefetch_depth"))
         self.donate = bool(params.get("device_donate"))
-        # segmented flush (ISSUE 7), read only when the context spans
-        # more than one rank: carve a flush group into pipelined jitted
-        # sub-calls so early segments' outputs retire (and their
-        # dependency sends start) while later segments still execute
-        self.flush_segments = int(params.get("device_flush_segments"))
         # copies ``prestage_many`` staged: id(copy) -> version; a
         # per-task stage-in that finds its copy here still valid is a
         # HIT
@@ -397,8 +388,9 @@ class JaxDevice(Device):
             self._manager_lock.release()
 
     def _poll(self, es) -> int:  # holds: self._manager_lock
-        """The poll phase: complete the in-flight calls that are ready
-        (the epilogs release their successors).  Returns the tasks
+        """The poll phase: the epilogs of the calls dispatched since the
+        last one (they release their successors), and the retirement of
+        the window's calls that have become ready.  Returns the tasks
         completed."""
         n = 0
         if self._eager_done:
@@ -406,8 +398,8 @@ class JaxDevice(Device):
             for rec in done:
                 self._epilog(es, rec)
                 n += len(rec.tasks)
-        now = time.monotonic_ns()
         if self._window:
+            now = time.monotonic_ns()
             # retire finished window entries so device_load drains on
             # idle devices and async errors surface during the run
             still_w = []
@@ -420,19 +412,6 @@ class JaxDevice(Device):
                     rec.last_poll = now
                     still_w.append(rec)
             self._window = still_w
-        still: List[_InFlight] = []
-        done = []
-        for rec in self._inflight:
-            if rec.ready():
-                rec.done_est = (rec.last_poll + now) // 2
-                done.append(rec)
-            else:
-                rec.last_poll = now
-                still.append(rec)
-        self._inflight = still
-        for rec in done:
-            self._epilog(es, rec)
-            n += len(rec.tasks)
         return n
 
     def _book(self, bracket: str, wall_ns: int) -> None:
@@ -607,28 +586,24 @@ class JaxDevice(Device):
             # the first device call of a part of a compound taskpool
             part["first_call_ns"] = time.monotonic_ns()
             self.stats["compound_parts"] += 1
-        if self.eager_complete:
-            # TPU-native completion model: jax dispatch is async and XLA's
-            # execution queue already orders consumers after producers, so
-            # dependency release need not wait for the kernel — successors
-            # chain their jit calls on the in-flight arrays. Host-side
-            # reads still block on conversion (device->host sync point).
-            # A bounded window keeps the queue from running unboundedly
-            # ahead (ref: the CUDA module bounds in-flight per stream):
-            # it holds calls and bounds the TASKS they hold.
-            self._window.append(rec)
-            self._window_tasks += n
-            while self._window_tasks > self.eager_window \
-                    and len(self._window) > 1:
-                # backpressure: block on the oldest call (never on the
-                # one just filed: a call larger than the window waits
-                # for nothing but its predecessors)
-                old = self._window.pop(0)
-                self._window_tasks -= len(old.tasks)
-                self._retire(old, es)
-            self._eager_done.append(rec)
-        else:
-            self._inflight.append(rec)
+        # TPU-native completion model: jax dispatch is async and XLA's
+        # execution queue already orders consumers after producers, so
+        # dependency release need not wait for the kernel — successors
+        # chain their jit calls on the in-flight arrays. Host-side
+        # reads still block on conversion (device->host sync point).
+        # A bounded window keeps the queue from running unboundedly
+        # ahead (ref: the CUDA module bounds in-flight per stream):
+        # it holds calls and bounds the TASKS they hold.
+        self._window.append(rec)
+        self._window_tasks += n
+        while self._window_tasks > EAGER_WINDOW and len(self._window) > 1:
+            # backpressure: block on the oldest call (never on the
+            # one just filed: a call larger than the window waits
+            # for nothing but its predecessors)
+            old = self._window.pop(0)
+            self._window_tasks -= len(old.tasks)
+            self._retire(old, es)
+        self._eager_done.append(rec)
 
     # ------------------------------------------------------------------ #
     # batched dispatch: stack same-class ready tasks into ONE jitted     #
@@ -807,8 +782,8 @@ class JaxDevice(Device):
                 while len(g) >= 2 and spec.batchable:
                     b = bucket_size(len(g), self.batch_max)
                     chunk, g = g[:b], g[b:]
-                    self._dispatch_batch(es, spec, static, shapes, donate,
-                                         chunk)
+                    self._dispatch_stacked(es, spec, static, shapes,
+                                           donate, chunk)
                     n += b
                 while g:   # singleton / post-downgrade remainder
                     task, est, inputs, _ = g.pop(0)
@@ -824,45 +799,6 @@ class JaxDevice(Device):
                         self.pending.push_back((t2, e2))
                 raise
         return n
-
-    def _dispatch_batch(self, es, spec, static, shapes, donate,
-                        chunk: List[Tuple]) -> None:
-        """Dispatch one flush group: as ONE stacked call on a single
-        rank, and across ranks (segmented flush, ISSUE 7) as up to
-        ``device_flush_segments`` pipelined stacked sub-calls.
-
-        Segmentation exists to overlap dependency SENDS: sub-calls queue
-        back to back on the async dispatch stream, but each segment's
-        outputs materialize when ITS executable finishes, so the
-        epilog's release for the first segment (eager sends, D2H for
-        the wire) overlaps the later segments' execution instead of
-        waiting for the batch boundary.  A context of one rank makes no
-        send: the chip runs the sub-calls in the same order either way,
-        and every extra call costs the manager its fixed host time, so
-        the group goes out whole.  In ``unroll`` mode segmentation is
-        bit-exact vs the whole-batch dispatch (identical per-example
-        subgraphs, just grouped differently)."""
-        n = len(chunk)
-        segs = 1
-        if es.context.nb_ranks > 1:
-            from .batching import segment_plan
-            segs = segment_plan(n, self.flush_segments)
-        if segs <= 1:
-            return self._dispatch_stacked(es, spec, static, shapes, donate,
-                                          chunk)
-        self.stats["segmented_flushes"] += 1
-        size = n // segs
-        for i in range(0, n, size):
-            if not spec.batchable:
-                # an earlier segment's trace failure downgraded the
-                # class (and already fell back per-task for itself):
-                # finish the group per-task without re-tracing
-                for task, est, inputs, _ in chunk[i:]:
-                    self._submit_prepared(es, task, est, inputs)
-                return
-            self.stats["flush_segments"] += 1
-            self._dispatch_stacked(es, spec, static, shapes, donate,
-                                   chunk[i:i + size])
 
     def _dispatch_stacked(self, es, spec, static, shapes, donate,
                           chunk: List[Tuple]) -> None:
@@ -884,7 +820,7 @@ class JaxDevice(Device):
             # `f(donate(a), a)` error — keep the batch, drop donation
             donate = tuple(False for _ in donate)
         fn = cached_stacked_callable(spec, n, nargs, static, shapes,
-                                     self.batch_mode, donate)
+                                     donate)
         first = fn.first_call_on(self.name)
         span = "first_call" if first else "dispatch"
         clock = self._phases
@@ -901,8 +837,7 @@ class JaxDevice(Device):
                 try:
                     donate = tuple(False for _ in donate)
                     fn = cached_stacked_callable(
-                        spec, n, nargs, static, shapes,
-                        self.batch_mode, donate)
+                        spec, n, nargs, static, shapes, donate)
                     first = fn.first_call_on(self.name) or first
                     outs = fn(*flat)
                     self.stats["donate_retries"] += 1
@@ -1299,14 +1234,6 @@ class JaxDevice(Device):
         t0 = _now()
         if clock is not None:
             clock.push("epilog", t0, cls=tasks[0].task_class.name, n=n)
-        if not self.eager_complete:
-            # non-eager: the poll loop just observed the call ready —
-            # note the device-busy interval (eager mode notes at window
-            # retire instead, where readiness is actually observed)
-            obs = self._obs
-            if obs is not None and obs.tracker is not None:
-                obs.tracker.note("compute", rec.t0,
-                                 rec.done_est or time.monotonic_ns())
         outs = rec.outs
         index = self.device_index
         # the outputs of one slot share shape and dtype across the
@@ -1341,11 +1268,6 @@ class JaxDevice(Device):
                         ref.data_in.data.release_reader(index)
         if delta:
             self._account(delta)
-        if not self.eager_complete:
-            # eager mode releases at window exit; here the epilog is
-            # the record's retirement
-            self.load_sub(rec.est)
-            self.stats["retired_calls"] += 1
         self.executed_tasks += n
         t1 = _now()
         if clock is not None:
@@ -1453,26 +1375,11 @@ class JaxDevice(Device):
 
     def data_advise(self, data: Data, advice: str) -> None:
         if advice == "prefetch":
-            import jax
-            copy = data.get_copy(self.device_index)
-            src = data.newest_copy(exclude_device=self.device_index)
-            if src is None:
-                return
-            if copy is None:
-                copy = DataCopy(data, self.device_index, payload=None, dtt=src.dtt)
-                data.attach_copy(copy)
-            if copy.payload is None:
-                self._reserve(getattr(src.payload, "nbytes", 0))
-                copy.payload = jax.device_put(
-                    src.payload, self._placement(data, self.jax_device))
-                copy.version = src.version
-                copy.coherency = Coherency.SHARED
-                self._lru_touch(copy, owned=False)
+            self.prestage_data(data)
         elif advice == "preferred_device":
             data.preferred_device = self.device_index
 
     def fini(self) -> None:  # lock: exempt(teardown: workers joined, managers quiesced)
-        assert not self._inflight, "device finalized with in-flight tasks"
         for rec in self._window:
             self._retire(rec)  # teardown: must finalize every device
         self._window.clear()
@@ -1650,8 +1557,11 @@ class JaxMeshDevice(JaxDevice):
     # ------------------------------------------------------------------ #
     # sharded batched dispatch                                           #
     # ------------------------------------------------------------------ #
-    def _dispatch_batch(self, es, spec, static, shapes, donate,
-                        chunk: List[Tuple]) -> None:
+    def _dispatch_stacked(self, es, spec, static, shapes, donate,
+                          chunk: List[Tuple]) -> None:
+        """A flush group on the mesh: the sharded program where the
+        group divides the chips, else the base's one stacked call on
+        the first task's chip."""
         n = len(chunk)
         k = len(self.chips)
         if spec.mesh_ok and spec.batchable and k > 1 and n >= k \
@@ -1671,7 +1581,7 @@ class JaxMeshDevice(JaxDevice):
         target = self._stage_target(chunk[0][0])
         chunk = [(t, e, inp, tuple(self._move(a, target) for a in ba))
                  for (t, e, inp, ba) in chunk]
-        super()._dispatch_batch(es, spec, static, shapes, donate, chunk)
+        super()._dispatch_stacked(es, spec, static, shapes, donate, chunk)
 
     def _dispatch_sharded(self, es, spec, static, shapes,
                           chunk: List[Tuple]) -> None:
@@ -1691,7 +1601,7 @@ class JaxMeshDevice(JaxDevice):
         clock, span = self._phases, None
         try:
             fn = cached_sharded_callable(spec, n, nargs, static, shapes,
-                                         self.batch_mode, self.mesh)
+                                         self.mesh)
             order = sorted(range(n), key=lambda i: self._chip_pos.get(
                 self._stage_target(chunk[i][0]), 0))
             first = fn.first_call_on(self.name)

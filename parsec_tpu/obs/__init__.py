@@ -252,7 +252,7 @@ class ContextObs:
         # closed-loop self-tuning (ISSUE 17, tune/controller.py): the
         # controller rides the monitor's window-tick subscriber seam —
         # constructed ONLY under tune_auto, after every actuation
-        # target (transport, devices, overlap tracker) exists, before
+        # target (transport, devices) exists, before
         # the monitor thread starts ticking
         self.tuner = None
         if tune_on and self.live is not None:
@@ -271,8 +271,6 @@ class ContextObs:
                 hysteresis=params.get_or("tune_hysteresis_windows",
                                          "int", 2),
                 z_thresh=self.live.z_thresh,
-                overlap_fn=(self.overlap.fraction
-                            if self.overlap is not None else None),
                 stage_classes_fn=lambda c=ctx: _compiled_stage_classes(c))
             register_tune_gauges(ctx.sde, self.tuner)
             self.live.subscribe(self.tuner.on_window)

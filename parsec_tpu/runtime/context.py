@@ -358,7 +358,7 @@ class Context:
         if tp.startup_hook is not None:
             startup = list(tp.startup_hook(self, tp) or ())
             if startup:
-                # chunked hand-off (ref: task_startup_iter/chunk,
+                # chunked hand-off (``task_startup_chunk``; ref:
                 # parsec.c:688-694): the first chunk lands in the local
                 # queues, the rest overflow to the system queue so a huge
                 # startup set cannot flood per-thread buffers

@@ -8,9 +8,9 @@ monitor's window ticks and adapts three knob families at runtime —
   ``tune_residual_budget``, escalating on bandwidth-bound links and
   de-escalating when compression shows no win, renegotiated live over
   the K_TUNE control frame toward "tn"-capable peers;
-- device pipeline shape (``batch_max`` / ``prefetch_depth`` /
-  ``flush_segments``), hill-climbed per device from batch occupancy,
-  prefetch hit rate and the overlap fraction, with hysteresis and
+- device pipeline shape (``batch_max`` / ``prefetch_depth``),
+  hill-climbed per device from batch occupancy and prefetch hit
+  rate, with hysteresis and
   revert-on-regress against a us/task dispatch objective;
 - stage-compile exclusion: a class whose compiled stage keeps firing
   the straggler detector is fed to ``stage_compile_exclude`` so the

@@ -20,8 +20,8 @@ codec   The wire-codec ladder ``(None, qbf16, qint8)`` with declared
         worse than ``no_win_ratio``, shows no win and steps back down.
         Mixed-version peers (no "tn" HELLO capability) are never
         renegotiated.
-device  Hill-climb on ``batch_max`` / ``prefetch_depth`` /
-        ``flush_segments`` from per-window deltas of the device stats.
+device  Hill-climb on ``batch_max`` / ``prefetch_depth`` from
+        per-window deltas of the device stats.
         One move per device at a time; a move's effect is judged after
         ``hysteresis`` windows against the us/task dispatch-objective
         EWMA and ROLLED BACK if the objective regressed by more than
@@ -60,7 +60,6 @@ CODEC_COST: Dict[Optional[str], float] = {None: 0.0,
 # device knob bounds the hill-climber may not leave
 _BATCH_MAX_CAP = 1024
 _PREFETCH_CAP = 16
-_FLUSH_SEG_CAP = 16
 _EXCLUDE_CAP = 4       # never exclude more classes than this
 
 
@@ -85,10 +84,8 @@ class Controller:
                  occupancy_hi: float = 0.85,
                  occupancy_lo: float = 0.3,
                  prefetch_lo: float = 0.5,
-                 overlap_lo: float = 0.5,
                  regress_pct: float = 0.05,
                  straggler_windows: int = 3,
-                 overlap_fn: Optional[Callable[[], float]] = None,
                  stage_classes_fn: Optional[Callable[[], List[str]]] = None,
                  ) -> None:
         self.rank = int(rank)
@@ -105,10 +102,8 @@ class Controller:
         self.occupancy_hi = float(occupancy_hi)
         self.occupancy_lo = float(occupancy_lo)
         self.prefetch_lo = float(prefetch_lo)
-        self.overlap_lo = float(overlap_lo)
         self.regress_pct = float(regress_pct)
         self.straggler_windows = max(1, int(straggler_windows))
-        self.overlap_fn = overlap_fn
         self.stage_classes_fn = stage_classes_fn
         # the highest ladder rung the residual budget admits
         budget = max(0.0, float(residual_budget))
@@ -286,8 +281,7 @@ class Controller:
             last = st["last"]
             d = {k: stats.get(k, 0) - last.get(k, 0) for k in
                  ("batches", "batched_tasks", "dispatch_ns",
-                  "dispatch_tasks", "prefetch_issued", "prefetch_hits",
-                  "segmented_flushes")}
+                  "dispatch_tasks", "prefetch_issued", "prefetch_hits")}
             st["last"] = dict(stats)
             tot_ns += d["dispatch_ns"]
             tot_tasks += d["dispatch_tasks"]
@@ -372,15 +366,6 @@ class Controller:
             if hit < self.prefetch_lo and depth < _PREFETCH_CAP:
                 return ("prefetch_depth", depth + 1,
                         f"prefetch hit-rate {hit:.2f}")
-        if d["segmented_flushes"] > 0 and self.overlap_fn is not None:
-            try:
-                ov = float(self.overlap_fn())
-            except Exception:   # noqa: BLE001
-                ov = 1.0
-            segs = int(getattr(dev, "flush_segments", 1))
-            if ov < self.overlap_lo and segs < _FLUSH_SEG_CAP:
-                return ("flush_segments", segs + 1,
-                        f"overlap fraction {ov:.2f}")
         return None
 
     # ------------------------------------------------------------------ #

@@ -338,11 +338,9 @@ def register_core_params() -> None:
                      "also count against comm_send_buffer_bytes)")
     params.reg_int("arena_max_used", -1, "cap on arena allocated buffers (-1 off)")
     params.reg_int("arena_max_cached", -1, "cap on arena cached buffers (-1 off)")
-    params.reg_int("task_startup_iter", 64, "startup enumerator chunk iterations")
     params.reg_int("task_startup_chunk", 256, "startup enumerator chunk size")
     params.reg_bool("runtime_keep_highest_priority_task", True,
                     "keep best ready task on releasing thread, bypass scheduler")
-    params.reg_int("verbose", 0, "global debug verbosity")
     params.reg_string("profile", "", "enable profiling; path prefix for traces")
     params.reg_bool("metrics", False,
                     "collect runtime metrics (latency histograms + comm/"
@@ -383,11 +381,11 @@ def register_core_params() -> None:
                     "quantized codec choice (runtime K_TUNE "
                     "renegotiation toward peers that advertised the "
                     "HELLO \"tn\" capability), the device pipeline "
-                    "shape (device_batch_max / device_prefetch_depth / "
-                    "device_flush_segments, hill-climbed with "
-                    "revert-on-regress), and stagec exclude decisions "
-                    "(stage_compile_exclude fed from repeat straggler "
-                    "firings). Every move emits a tune:* annotation on "
+                    "shape (device_batch_max / device_prefetch_depth, "
+                    "hill-climbed with revert-on-regress), and stagec "
+                    "exclude decisions (stage_compile_exclude fed from "
+                    "repeat straggler firings). Every move emits a "
+                    "tune:* annotation on "
                     "the health stream plus PARSEC::TUNE::* gauges. "
                     "Implies obs_live; off (default) constructs "
                     "nothing and is bit-for-bit inert on the wire")
@@ -407,12 +405,6 @@ def register_core_params() -> None:
                       "capture the executed DAG; path prefix for DOT files "
                       "(ref: --parsec_dot)")
     params.reg_string("termdet", "local", "termination detection module")
-    params.reg_int("gpu_max_streams", 4, "per-accelerator concurrent exec lanes")
-    params.reg_bool("tpu_eager_complete", True,
-                    "release deps at async dispatch (XLA orders the "
-                    "dataflow); off = wait for buffer readiness")
-    params.reg_int("tpu_eager_window", 32,
-                   "max in-flight eager submissions before blocking")
     params.reg_sizet("tpu_memory_fraction_pct", 85,
                      "percent of HBM managed by the arena")
     params.reg_int("device_batch_max", 16,
@@ -420,13 +412,6 @@ def register_core_params() -> None:
                    "device dispatch (<=1 disables batching: every task "
                    "is its own XLA submission, the pre-batching "
                    "behavior)")
-    params.reg_string("device_batch_mode", "unroll",
-                      "how batched tasks are stacked: unroll (one "
-                      "per-example subgraph per task inside one "
-                      "dispatch; bit-exact vs per-task) | vmap "
-                      "(stack + jax.vmap; smaller programs and "
-                      "MXU-friendly batched kernels, but batched "
-                      "algorithms may differ numerically)")
     params.reg_string("device_mesh_shape", "",
                       "attach this rank's XLA chips as ONE mesh device "
                       "(\"PxQ\" grid or a chip count, e.g. \"2x2\" or "
@@ -449,15 +434,6 @@ def register_core_params() -> None:
                    "pending stages hold outstanding early stage-ins "
                    "(0 = none).  The classic path reads it no more: "
                    "its manager stages a drained ready set whole")
-    params.reg_int("device_flush_segments", 4,
-                   "across ranks only (a context of one rank makes no "
-                   "send and flushes every group as ONE stacked call, "
-                   "whatever this says): carve each batched flush group "
-                   "into up to this many pipelined jitted sub-calls so "
-                   "a segment's written tiles retire (and their "
-                   "dependency sends start) while the rest of the batch "
-                   "is still executing (<=1 = whole-batch flush; "
-                   "segments never shrink below 2 tasks)")
     params.reg_bool("stage_compile", False,
                     "whole-stage DAG->XLA compilation (stagec/, ISSUE "
                     "12): lower verified PTG stages into fused jitted "
@@ -540,7 +516,6 @@ def register_core_params() -> None:
                     "donate stale device input buffers of WRITE flows "
                     "to the batched call (jax donate_argnums) to cut "
                     "HBM churn; see the guide's donation caveats")
-    params.reg_int("comm_max_inflight", 16, "max concurrent gets/puts in comm thread")
     params.reg_string("sde_push", "",
                       "host:port of a live counter aggregator to push SDE "
                       "snapshots to (ref: tools/aggregator_visu)")

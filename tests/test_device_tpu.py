@@ -504,48 +504,65 @@ def _assert_burst_result(cs, nb=16, chain=False):
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_stacked_call_retires_as_one_record():
-    """A stacked call of 8 tasks: ONE record retired, one ``epilog``
+@pytest.mark.parametrize("nb_ranks,n", [(1, 8), (2, 16)])
+def test_stacked_call_retires_as_one_record(nb_ranks, n):
+    """A stacked call of n tasks, on one rank and across ranks (a flush
+    group is one call there too): ONE record retired, one ``epilog``
     span from ``_epilog`` and one ``chip_wait`` span from ``_retire``
     (the always-on brackets count the same), and every task still has
     its own ``complete`` span."""
+    from conftest import spmd
+    from parsec_tpu.comm import RemoteDepEngine
     from parsec_tpu.obs import phases
     from parsec_tpu.utils.params import params
+
+    def body(ctx, rank):
+        try:
+            dev = _jax_devices(ctx)[0]
+            with phases.root_span(ctx, "burst", 29 + rank):
+                tp, cs = _one_core_burst(ctx, n)
+                tp.wait()
+                ctx.wait()      # its exit drains the window
+            if rank:    # keyless tiles live on rank 0: it ran them all
+                return
+            rec = [r for r in phases.completed() if r["id"] == 29][-1]
+            assert rec["op"] == "burst"
+            assert dev.stats["tasks"] == n
+            assert dev.stats["batches"] == 1
+            assert dev.stats["batched_tasks"] == n
+            assert dev.stats["retired_calls"] == 1
+            assert rec["phases"]["epilog"]["count"] == 1
+            assert rec["phases"]["chip_wait"]["count"] == 1
+            assert [rec["manager"][b]["count"] for b in phases.BRACKETS] \
+                == [1, 1, 1, 1, 1, 1]
+            assert rec["phases"]["complete"]["count"] == n
+            assert rec["phases"]["release_deps"]["count"] == n
+            assert dev._window == [] and dev._window_tasks == 0
+            _assert_burst_result(cs)
+        finally:
+            ctx.fini()
+
     with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", "8"):
-        ctx = parsec_tpu.init(nb_cores=1, profile=True)
-    try:
-        dev = _jax_devices(ctx)[0]
-        with phases.root_span(ctx, "burst", 29):
-            tp, cs = _one_core_burst(ctx, 8)
-            tp.wait()
-            ctx.wait()      # its exit drains the window
-        rec = phases.completed()[-1]
-        assert rec["op"] == "burst" and rec["id"] == 29
-        assert dev.stats["tasks"] == 8
-        assert dev.stats["batches"] == 1 and dev.stats["batched_tasks"] == 8
-        assert dev.stats["retired_calls"] == 1
-        assert rec["phases"]["epilog"]["count"] == 1
-        assert rec["phases"]["chip_wait"]["count"] == 1
-        assert [rec["manager"][b]["count"] for b in phases.BRACKETS] \
-            == [1, 1, 1, 1, 1, 1]
-        assert rec["phases"]["complete"]["count"] == 8
-        assert rec["phases"]["release_deps"]["count"] == 8
-        assert dev._window == [] and dev._window_tasks == 0
-        _assert_burst_result(cs)
-    finally:
-        ctx.fini()
+         params.cmdline_override("device_batch_max", str(n)):
+        if nb_ranks == 1:
+            body(parsec_tpu.init(nb_cores=1, profile=True), 0)
+        else:
+            spmd(nb_ranks, lambda rank, fabric: body(parsec_tpu.Context(
+                nb_cores=1, profile=True,
+                comm=RemoteDepEngine(fabric.engine(rank))), rank))
 
 
 @pytest.mark.parametrize("batch_max,want_calls", [(4, 4), (16, 1)])
 def test_window_bounds_tasks_in_flight(monkeypatch, batch_max, want_calls):
-    """``tpu_eager_window`` bounds TASKS in flight with calls as the
+    """``EAGER_WINDOW`` bounds TASKS in flight with calls as the
     entries: at window 4 with calls of 4 the oldest call is waited for
     as the next is filed (never more than two calls' tasks in flight,
     one call's once filed), and a single call larger than the window
     waits for nothing: the window is never emptied under it."""
+    from parsec_tpu.devices import tpu
     from parsec_tpu.devices.tpu import JaxDevice, _InFlight
     from parsec_tpu.utils.params import params
+    monkeypatch.setattr(tpu, "EAGER_WINDOW", 4)
     # only backpressure (and the drain at wait()'s exit) retires
     monkeypatch.setattr(_InFlight, "ready", lambda self: False)
     after_filing, at_retire = [], []
@@ -565,8 +582,7 @@ def test_window_bounds_tasks_in_flight(monkeypatch, batch_max, want_calls):
     monkeypatch.setattr(JaxDevice, "_finish_submit", filing)
     monkeypatch.setattr(JaxDevice, "_retire", retiring)
     with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", str(batch_max)), \
-         params.cmdline_override("tpu_eager_window", "4"):
+         params.cmdline_override("device_batch_max", str(batch_max)):
         ctx = parsec_tpu.init(nb_cores=1)
     try:
         dev = _jax_devices(ctx)[0]
@@ -620,32 +636,6 @@ def test_stacked_call_async_failure_is_one_task_error(monkeypatch):
         ctx.fini()
 
 
-@pytest.mark.parametrize("batch_max", [1, 8])
-def test_non_eager_mode_takes_the_same_record(batch_max):
-    """``tpu_eager_complete`` off: the call's record waits in
-    ``_inflight`` until its outputs are ready and its epilog is its
-    retirement; lone tasks (batch_max 1) and a stacked call alike."""
-    from parsec_tpu.utils.params import params
-    with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", str(batch_max)), \
-         params.cmdline_override("tpu_eager_complete", "0"):
-        ctx = parsec_tpu.init(nb_cores=1)
-    try:
-        dev = _jax_devices(ctx)[0]
-        assert not dev.eager_complete
-        tp, cs = _one_core_burst(ctx, 8, chain=True)
-        tp.wait()
-        ctx.wait()
-        assert dev.stats["tasks"] == 8
-        assert dev.stats["batches"] == (1 if batch_max == 8 else 0)
-        assert dev.stats["retired_calls"] == (1 if batch_max == 8 else 8)
-        assert dev._inflight == [] and dev._window == []
-        assert dev.device_load == 0.0
-        _assert_burst_result(cs, chain=True)
-    finally:
-        ctx.fini()
-
-
 class _Recorder(PinsModule):
     """The PINS ``events`` in order, from every thread, as (event,
     payload) entries of ``log``."""
@@ -688,65 +678,3 @@ def test_ready_tasks_of_one_call_reach_the_scheduler_once():
     done = [i for i, (ev, p) in enumerate(mod.log)
             if ev == PinsEvent.COMPLETE_EXEC_END and name(p) == "GEMM"]
     assert len(done) == 8 and max(done) < at
-
-
-def test_two_ranks_each_segment_is_its_own_record(monkeypatch):
-    """Across ranks a flush group of 16 goes out as four sub-calls, each
-    its own record, and the first segment's successors reach the
-    scheduler before the last segment is retired (its sends can start
-    while later segments still run)."""
-    from conftest import spmd
-    from parsec_tpu.comm import RemoteDepEngine
-    from parsec_tpu.devices.tpu import JaxDevice
-    from parsec_tpu.utils.params import params
-    log = []
-    filed, retire = JaxDevice._finish_submit, JaxDevice._retire
-
-    def filing(self, es, rec):
-        log.append(("file", [id(t) for t in rec.tasks]))
-        filed(self, es, rec)
-
-    def retiring(self, rec, es=None, context=None):
-        log.append(("retire", [id(t) for t in rec.tasks]))
-        retire(self, rec, es, context)
-
-    monkeypatch.setattr(JaxDevice, "_finish_submit", filing)
-    monkeypatch.setattr(JaxDevice, "_retire", retiring)
-    mod = _Recorder([PinsEvent.SCHEDULE_BEGIN], log)
-
-    def rank_fn(rank, fabric):
-        ctx = parsec_tpu.Context(nb_cores=1,
-                                 comm=RemoteDepEngine(fabric.engine(rank)))
-        try:
-            tp, cs = _one_core_burst(ctx, 16, chain=True)
-            tp.wait()
-            ctx.wait()      # its exit drains the window
-            devs = _jax_devices(ctx)
-            return {k: sum(d.stats[k] for d in devs)
-                    for k in ("tasks", "batches", "flush_segments",
-                              "segmented_flushes", "retired_calls")}
-        finally:
-            ctx.fini()
-
-    with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", "16"), \
-         params.cmdline_override("device_flush_segments", "4"):
-        mod.enable()
-        try:
-            stats = spmd(2, rank_fn)[0]
-        finally:
-            mod.disable()
-    st = stats[0]     # keyless tiles live on rank 0: it ran them all
-    assert st["tasks"] == 16 and st["segmented_flushes"] == 1
-    assert st["flush_segments"] == 4 and st["batches"] == 4
-    assert st["retired_calls"] == 4
-    files = [e for e in log if e[0] == "file"]
-    assert [len(ids) for _k, ids in files] == [4, 4, 4, 4]
-    first_next = next(i for i, (what, p) in enumerate(log)
-                      if what == PinsEvent.SCHEDULE_BEGIN
-                      and any(t.task_class.name == "NEXT" for t in p))
-    last_retired = next(i for i, e in enumerate(log)
-                        if e == ("retire", files[-1][1]))
-    assert first_next < last_retired
-
-

@@ -1,4 +1,4 @@
-"""Overlap-aware execution (ISSUE 7): segmented flush bit-exactness +
+"""Overlap-aware execution (ISSUE 7): whole-group flush bit-exactness +
 fallback, remote-GET prefetch for early activations, and the live
 overlap tracker's interval algebra."""
 import os
@@ -24,11 +24,10 @@ def _tpu_devs(ctx):
 
 
 # --------------------------------------------------------------------- #
-# one stacked call per flush group on one rank; segmented flush across  #
-# ranks: bit-exact differentials + counters + fallback                  #
+# one stacked call per flush group, on one rank and across ranks:       #
+# bit-exact against per-task dispatch + counters + fallback             #
 # --------------------------------------------------------------------- #
-SEGMENT_STATS = ("segmented_flushes", "flush_segments", "batches",
-                 "batched_tasks")
+STACK_STATS = ("batches", "batched_tasks")
 
 
 def _on_ranks(nb_ranks, body):
@@ -53,20 +52,19 @@ def _on_ranks(nb_ranks, body):
     return spmd(nb_ranks, rank_fn)[0]
 
 
-def _segment_stats(ctx):
+def _stack_stats(ctx):
     devs = _tpu_devs(ctx)
-    return {k: sum(d.stats[k] for d in devs) for k in SEGMENT_STATS}
+    return {k: sum(d.stats[k] for d in devs) for k in STACK_STATS}
 
 
 def _sum_stats(per_rank):
-    return {k: sum(st[k] for st in per_rank) for k in SEGMENT_STATS}
+    return {k: sum(st[k] for st in per_rank) for k in STACK_STATS}
 
 
-def _run_dpotrf(segments: int, nb_ranks: int = 1, batch_max: int = 16):
+def _run_dpotrf(nb_ranks: int = 1, batch_max: int = 16):
     """One classic-runtime dpotrf (POTRF/TRSM/SYRK/GEMM classes, N=256,
-    NB=32) over ``nb_ranks`` in-process ranks with the given
-    device_flush_segments; returns (L, segment stats summed over the
-    ranks)."""
+    NB=32) over ``nb_ranks`` in-process ranks; returns (L, stacked-call
+    stats summed over the ranks)."""
     n, nb = 256, 32
     M = make_spd(n)
 
@@ -79,11 +77,10 @@ def _run_dpotrf(segments: int, nb_ranks: int = 1, batch_max: int = 16):
         ctx.wait()
         owned = {c: np.asarray(A.data_of(*c).sync_to_host().payload)
                  for c in A.tiles() if A.rank_of(*c) == rank}
-        return owned, _segment_stats(ctx)
+        return owned, _stack_stats(ctx)
 
     with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", str(batch_max)), \
-         params.cmdline_override("device_flush_segments", str(segments)):
+         params.cmdline_override("device_batch_max", str(batch_max)):
         results = _on_ranks(nb_ranks, body)
     L = np.zeros((n, n), np.float32)
     for owned, _st in results:
@@ -93,58 +90,31 @@ def _run_dpotrf(segments: int, nb_ranks: int = 1, batch_max: int = 16):
     return L, _sum_stats([st for _owned, st in results])
 
 
-def test_single_rank_dpotrf_flushes_each_group_whole(call_sizes):
-    """One rank makes no dependency send, so nothing is segmented
-    whatever device_flush_segments says: a flush group is ONE stacked
-    call of up to device_batch_max tasks (segments cap a call at 4),
-    bit-identical to per-task dispatch."""
-    L, st = _run_dpotrf(4)
+@pytest.mark.parametrize("nb_ranks", [1, 2])
+def test_dpotrf_flushes_each_group_whole(call_sizes, nb_ranks):
+    """A flush group is ONE stacked call of up to device_batch_max
+    tasks, on one rank and across ranks (``batches`` counts exactly the
+    calls seen), bit-identical to per-task dispatch for the
+    cholesky/trsm/syrk/gemm classes."""
+    L, st = _run_dpotrf(nb_ranks)
     sizes = list(call_sizes)
-    assert st["segmented_flushes"] == 0 and st["flush_segments"] == 0
     assert st["batches"] == len(sizes)
     assert st["batched_tasks"] == sum(sizes)
-    assert max(sizes) >= 8, sizes
+    assert max(sizes) >= 8, sizes   # the deleted segments capped at 4
     assert set(sizes) <= {2, 4, 8, 16}, sizes
-    L_each, st_each = _run_dpotrf(4, batch_max=1)
+    L_each, st_each = _run_dpotrf(nb_ranks, batch_max=1)
     assert st_each["batches"] == 0
     assert np.array_equal(L, L_each), \
         "a stacked call is not bit-identical to per-task dispatch"
-
-
-def test_segmented_flush_bit_exact_dpotrf():
-    """Acceptance: across ranks the segmented flush is BIT-EXACT vs
-    whole-batch unroll dispatch for the cholesky/trsm/syrk/gemm classes,
-    and the segment counters prove the pipelined path really ran."""
-    L_whole, st_whole = _run_dpotrf(1, nb_ranks=2)
-    L_seg, st_seg = _run_dpotrf(4, nb_ranks=2)
-    assert st_whole["segmented_flushes"] == 0
-    assert st_whole["flush_segments"] == 0
-    assert st_seg["segmented_flushes"] > 0
-    # every carved group produced >= 2 sub-calls
-    assert st_seg["flush_segments"] >= 2 * st_seg["segmented_flushes"]
-    assert st_seg["batches"] > st_whole["batches"]  # more, smaller calls
-    assert np.array_equal(L_whole, L_seg), \
-        "segmented flush is not bit-exact vs whole-batch dispatch"
-    Lt = np.tril(L_seg).astype(np.float64)
+    Lt = np.tril(L).astype(np.float64)
     M = make_spd(256)
     assert np.abs(Lt @ Lt.T - M).max() / np.abs(M).max() < 1e-5
 
 
-def test_two_rank_dpotrf_caps_a_call_at_the_segment(call_sizes):
-    """Two ranks dispatch as before the single-rank rule: a bucket of n
-    tasks goes out as min(4, n // 2) sub-calls, so no call holds more
-    than device_batch_max / device_flush_segments = 4 tasks."""
-    _L, st = _run_dpotrf(4, nb_ranks=2)
-    assert st["segmented_flushes"] > 0
-    assert max(call_sizes) <= 4, call_sizes
-    assert st["batches"] == len(call_sizes)
-
-
-def _run_dtd_burst(segments: int, kern, burst=32, nb=48, nb_ranks=1,
-                   batch_max=16):
+def _run_dtd_burst(kern, burst=32, nb=48, nb_ranks=1, batch_max=16):
     """A burst of independent same-class tasks on rank 0's device (a
     keyless tile's home is rank 0; every rank inserts the same stream,
-    SPMD); returns (rank 0's outputs, rank 0's segment stats)."""
+    SPMD); returns (rank 0's outputs, rank 0's stacked-call stats)."""
     def body(ctx, rank):
         tp = dtd.taskpool_new()
         ctx.add_taskpool(tp)
@@ -164,11 +134,10 @@ def _run_dtd_burst(segments: int, kern, burst=32, nb=48, nb_ranks=1,
         tp.wait()
         out = [np.asarray(c.data.sync_to_host().payload)
                for c, _a, _b in tiles]
-        return out, _segment_stats(ctx)
+        return out, _stack_stats(ctx)
 
     with params.cmdline_override("device_tpu_max", "1"), \
-         params.cmdline_override("device_batch_max", str(batch_max)), \
-         params.cmdline_override("device_flush_segments", str(segments)):
+         params.cmdline_override("device_batch_max", str(batch_max)):
         return _on_ranks(nb_ranks, body)[0]
 
 
@@ -179,42 +148,33 @@ def _gemm_kern():
                    c - jnp.dot(a, b.T, preferred_element_type=jnp.float32))
 
 
-def test_single_rank_dtd_burst_flushes_each_group_whole(call_sizes):
-    """A 32-task DTD burst on one rank: stacked calls of 16, none
-    segmented, bit-identical to per-task dispatch."""
+@pytest.mark.parametrize("nb_ranks", [1, 2])
+def test_dtd_burst_flushes_each_group_whole(call_sizes, nb_ranks):
+    """A 32-task DTD burst on rank 0's device: stacked calls of 16,
+    each ONE call on one rank and across ranks, bit-identical to
+    per-task dispatch."""
     kern = _gemm_kern()
-    out, st = _run_dtd_burst(4, kern)
+    out, st = _run_dtd_burst(kern, nb_ranks=nb_ranks)
     sizes = list(call_sizes)
-    assert st["segmented_flushes"] == 0 and st["flush_segments"] == 0
     assert 16 in sizes, sizes
     assert st["batches"] == len(sizes)
     assert st["batched_tasks"] == sum(sizes)
-    out_each, st_each = _run_dtd_burst(4, kern, batch_max=1)
+    out_each, st_each = _run_dtd_burst(kern, nb_ranks=nb_ranks, batch_max=1)
     assert st_each["batches"] == 0
     assert all(np.array_equal(a, b) for a, b in zip(out, out_each))
 
 
-def test_segmented_flush_bit_exact_dtd_burst():
-    kern = _gemm_kern()
-    out_whole, st_whole = _run_dtd_burst(1, kern, nb_ranks=2)
-    out_seg, st_seg = _run_dtd_burst(4, kern, nb_ranks=2)
-    assert st_seg["segmented_flushes"] > 0 >= st_whole["segmented_flushes"]
-    assert st_seg["batches"] > st_whole["batches"]
-    assert all(np.array_equal(a, b) for a, b in zip(out_whole, out_seg))
-
-
 @pytest.mark.parametrize("nb_ranks", [1, 2])
-def test_segmented_flush_untraceable_falls_back_per_task(nb_ranks):
-    """A trace failure inside the FIRST call of a group (across ranks:
-    its first segment) must downgrade the class and finish the whole
-    group per-task — same transparent fallback either way, results
+def test_untraceable_falls_back_per_task(nb_ranks):
+    """A trace failure inside the first call of a group must downgrade
+    the class and finish the whole group per-task — the same
+    transparent fallback on one rank and across ranks, results
     unchanged."""
     def kern(c, a, b):   # np.asarray on a tracer raises under jit
         return c - np.asarray(a) @ np.asarray(b).T
 
-    out, st = _run_dtd_burst(4, kern, burst=16, nb_ranks=nb_ranks)
+    out, st = _run_dtd_burst(kern, burst=16, nb_ranks=nb_ranks)
     assert st["batches"] == 0, "untraceable body must not batch"
-    assert st["segmented_flushes"] == nb_ranks - 1
     rng = np.random.RandomState(7)
     tiles = [[rng.rand(48, 48).astype(np.float32) for _ in range(3)]
              for _ in range(16)]

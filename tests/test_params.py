@@ -315,3 +315,39 @@ END
         np.testing.assert_allclose(arr[:, 0], [1, 2, 3, 4])
     finally:
         ctx.fini()
+
+
+def test_every_registered_param_has_a_reader():
+    """A registered name chooses something: each name a ``params.reg_*``
+    call registers appears as a quoted literal, outside its own
+    registration, somewhere in the program, its tools, examples or
+    benchmark.  A name nothing reads is documentation of a choice the
+    code does not offer."""
+    import ast
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = [os.path.join(repo, f)
+             for f in ("chip_smoke.py", "__graft_entry__.py")]
+    for top in ("parsec_tpu", "tools", "examples", "perfbench"):
+        for d, _dirs, names in os.walk(os.path.join(repo, top)):
+            files += [os.path.join(d, n) for n in names if n.endswith(".py")]
+    registered, literals = set(), set()
+    for path in files:
+        with open(path) as fh:
+            tree = ast.parse(fh.read(), path)
+        own = set()     # the literal nodes that ARE a registration
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Attribute) \
+                    and node.func.attr.startswith("reg_") \
+                    and isinstance(node.func.value, ast.Name) \
+                    and node.func.value.id == "params" and node.args \
+                    and isinstance(node.args[0], ast.Constant):
+                registered.add(node.args[0].value)
+                own.add(id(node.args[0]))
+        literals |= {node.value for node in ast.walk(tree)
+                     if isinstance(node, ast.Constant)
+                     and isinstance(node.value, str) and id(node) not in own}
+    assert len(registered) > 50, registered     # the parse found the registry
+    unread = sorted(registered - literals)
+    assert not unread, f"registered, read by nothing: {unread}"

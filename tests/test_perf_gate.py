@@ -185,8 +185,7 @@ def test_batched_dispatch_beats_per_task(call_sizes):
                 tp.wait()
                 st = {k: sum(d.stats[k] for d in devs)
                       for k in ("dispatch_tasks", "batches",
-                                "batched_tasks", "segmented_flushes")}
-                assert st["segmented_flushes"] == 0
+                                "batched_tasks")}
                 lone = st["dispatch_tasks"] - st["batched_tasks"]
                 return (st["batches"] + lone, st["dispatch_tasks"],
                         st["batches"])
@@ -203,7 +202,7 @@ def test_batched_dispatch_beats_per_task(call_sizes):
     assert b0 == 0 and calls0 == burst, (b0, calls0)
     assert b1 == len(call_sizes) > 0
     assert 16 in call_sizes, call_sizes
-    # a segmented flush would make 16 calls of 4; whole groups make 4
+    # calls of 4 would make 16; whole groups make 4
     assert calls1 * 8 <= burst, \
         f"{calls1} device calls for {burst} same-class ready tasks: " \
         f"the stacked path has regressed (sizes {call_sizes})"

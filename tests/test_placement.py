@@ -217,6 +217,101 @@ def test_no_accelerator_runs_most_of_a_dag(ctx4):
 
 
 # --------------------------------------------------------------------- #
+# (g) prefetch advice is the counted prestage                           #
+# --------------------------------------------------------------------- #
+PREFETCH_KEYS = ("stage_in_bytes", "stage_in_transfers", "stage_in_tiles")
+
+
+def _axpy_on(ctx, x, y):
+    """One device task ``y += x`` over two tiles of a fresh DTD pool,
+    inserted by the caller's ``insert()``: (the pool, x tile, y tile,
+    insert)."""
+    import jax
+    from parsec_tpu import dtd
+    from parsec_tpu.dsl.dtd import INOUT, INPUT, unpack_args
+    tp = dtd.taskpool_new()
+    ctx.add_taskpool(tp)
+
+    def host(es, task):
+        yy, xx = unpack_args(task)
+        yy += xx
+
+    tc = tp.create_task_class("AXPY", 2, host)
+    tp.add_chore(tc, "tpu", jax.jit(lambda yy, xx: yy + xx))
+    tx, ty = tp.tile_of_array(x), tp.tile_of_array(y)
+    return tp, tx, ty, lambda: tp.insert_task_with_task_class(
+        tc, (ty, INOUT), (tx, INPUT))
+
+
+def _coherency(data):
+    return sorted((c.device_id, c.coherency, c.version)
+                  for c in data.copies() if c.payload is not None)
+
+
+def test_prefetch_advice_is_a_counted_prestage():
+    """``data_advise(d, "prefetch")`` is ``prestage_data``: the copy
+    lands SHARED at the host copy's version, counted as one tile in one
+    transfer; a second advice moves nothing, and the task that then
+    reads the tiles stages no byte."""
+    from parsec_tpu.data.data import Coherency
+    with params.cmdline_override("device_tpu_max", "1"):
+        ctx = parsec_tpu.init(nb_cores=2)
+    try:
+        dev, = _accel(ctx)
+        x = np.full((NB, NB), 2.0, np.float32)
+        y = np.ones((NB, NB), np.float32)
+        tp, tx, ty, insert = _axpy_on(ctx, x, y)
+        before = {k: dev.stats[k] for k in PREFETCH_KEYS}
+        for n, tile in enumerate((tx, ty), 1):
+            dev.data_advise(tile.data, "prefetch")
+            copy = tile.data.get_copy(dev.device_index)
+            assert copy.coherency == Coherency.SHARED
+            assert copy.version == tile.data.get_copy(0).version
+            assert [dev.stats[k] - before[k] for k in PREFETCH_KEYS] \
+                == [n * TILE_BYTES, n, n]
+            dev.data_advise(tile.data, "prefetch")     # current: a no-op
+            assert [dev.stats[k] - before[k] for k in PREFETCH_KEYS] \
+                == [n * TILE_BYTES, n, n]
+        assert dev.mem_used == 2 * TILE_BYTES           # reserved
+        staged = {k: dev.stats[k] for k in PREFETCH_KEYS}
+        insert()
+        tp.wait()
+        assert dev.stats["tasks"] == 1
+        assert {k: dev.stats[k] for k in PREFETCH_KEYS} == staged
+        assert dev.stats["prefetch_hits"] == 2
+        np.testing.assert_array_equal(
+            np.asarray(ty.data.sync_to_host().payload), 3.0)
+    finally:
+        ctx.fini()
+
+
+def test_prefetch_advice_leaves_a_current_or_chip_held_tile_alone(ctx4):
+    """The advice on a tile whose copy here is current (the owner's),
+    or whose newest copy is another chip's, stages nothing and changes
+    no coherency state: only host bytes are prestaged."""
+    devs = _accel(ctx4)
+    here, other = devs[1], devs[0]
+    x = np.full((NB, NB), 2.0, np.float32)
+    y = np.ones((NB, NB), np.float32)
+    tp, tx, ty, insert = _axpy_on(ctx4, x, y)
+    here.data_advise(ty.data, "preferred_device")
+    insert()
+    tp.wait()
+    assert here.stats["tasks"] == 1     # y's newest copy is ``here``'s
+    for dev in (here, other):
+        state = _coherency(ty.data)
+        before = {k: dev.stats[k] for k in PREFETCH_KEYS + ("evictions",)}
+        used = dev.mem_used
+        dev.data_advise(ty.data, "prefetch")
+        assert _coherency(ty.data) == state
+        assert {k: dev.stats[k] for k in before} == before
+        assert dev.mem_used == used
+    assert ty.data.get_copy(other.device_index) is None
+    np.testing.assert_array_equal(
+        np.asarray(ty.data.sync_to_host().payload), 3.0)
+
+
+# --------------------------------------------------------------------- #
 # the rule itself, on hand-made tasks                                   #
 # --------------------------------------------------------------------- #
 class _Dev(Device):

@@ -376,7 +376,7 @@ def test_concurrent_managers_settle_once_and_build_once(no_programs):
         for spec, b in zip(specs, bad):
             assert batching.settle(spec)
             progs.append(batching.cached_stacked_callable(
-                spec, 2, 2, (), shapes, "unroll"))
+                spec, 2, 2, (), shapes))
             if not batching.settle(b):
                 gave_up.append(b)
 

@@ -51,14 +51,12 @@ class FakeDevice:
         self.name = "fake0"
         self.batch_max = batch_max
         self.prefetch_depth = 4
-        self.flush_segments = 4
         self.stats = {"batches": 0, "batched_tasks": 0,
                       "dispatch_ns": 0, "dispatch_tasks": 0,
-                      "prefetch_issued": 0, "prefetch_hits": 0,
-                      "segmented_flushes": 0}
+                      "prefetch_issued": 0, "prefetch_hits": 0}
 
     def window(self, batches=0, tasks=0, ns=0, n=0,
-               pf_issued=0, pf_hits=0, flushes=0):
+               pf_issued=0, pf_hits=0):
         """Advance the cumulative stats by one window's worth."""
         self.stats["batches"] += batches
         self.stats["batched_tasks"] += tasks
@@ -66,7 +64,6 @@ class FakeDevice:
         self.stats["dispatch_tasks"] += n
         self.stats["prefetch_issued"] += pf_issued
         self.stats["prefetch_hits"] += pf_hits
-        self.stats["segmented_flushes"] += flushes
 
 
 class FakeLive:
