@@ -20,7 +20,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from ..data.data import Data, data_new_with_payload
+from ..data.data import Coherency, Data, data_new_with_payload, to_host
 from ..data.datatype import Datatype
 from .collection import DataCollection
 
@@ -96,16 +96,40 @@ class TiledMatrix(DataCollection):
     # -- whole-matrix interop ----------------------------------------------
     def set_tile(self, m: int, n: int, values: np.ndarray) -> None:
         d = self.data_of(m, n)
-        np.copyto(d.get_copy(0).payload, values)
+        host = d.get_copy(0)
+        if host.payload is None:    # dropped as stale by ``to_numpy``
+            host.payload = np.array(values, dtype=self.dtype)
+        else:
+            np.copyto(host.payload, values, casting="unsafe")
         d.version_bump(0)
 
     def tile(self, m: int, n: int) -> np.ndarray:
         """Host view of the tile, synced from the newest device copy."""
         return self.data_of(m, n).sync_to_host().payload
 
+    def _snapshot(self, m: int, n: int) -> np.ndarray:
+        """The newest version of the tile on the host, to read from: the
+        host copy's payload where it is current; where a device holds a
+        newer version, that copied out, and the host copy's payload,
+        which the newer version made dead bytes, is dropped (``set_tile``
+        or a sync makes a new one): the caller's array is then the one
+        host copy of the tile, not the second."""
+        d = self.data_of(m, n)
+        with d._lock:
+            host, newest = d.host_copy(), d.newest_copy()
+            if newest is None or newest.device_id == 0 \
+                    or newest.version <= host.version:
+                return host.payload
+            host.payload = None
+            host.coherency = Coherency.INVALID
+            arr = newest.payload
+        return to_host(arr)
+
     def to_numpy(self) -> np.ndarray:
         """Assemble the full (local) matrix; missing symmetric tiles are
-        mirrored when uplo != full."""
+        mirrored when uplo != full.  A read: a tile whose newest version
+        is on a device is copied from there and NOT kept on the host as
+        well (``_snapshot``)."""
         out = np.zeros((self.lm, self.ln), dtype=self.dtype)
         for m in range(self.mt):
             for n in range(self.nt):
@@ -117,7 +141,7 @@ class TiledMatrix(DataCollection):
                 if self.uplo == "upper" and n < m:
                     out[sm:sm + tm, sn:sn + tn] = self.tile(n, m).T[:tm, :tn]
                     continue
-                out[sm:sm + tm, sn:sn + tn] = self.tile(m, n)
+                out[sm:sm + tm, sn:sn + tn] = self._snapshot(m, n)
         return out
 
     def from_numpy(self, a: np.ndarray) -> "TiledMatrix":
@@ -125,7 +149,8 @@ class TiledMatrix(DataCollection):
         for (m, n) in self.tiles():
             sm, sn = m * self.mb, n * self.nb
             tm, tn = self.tile_shape(m, n)
-            self.set_tile(m, n, a[sm:sm + tm, sn:sn + tn].astype(self.dtype))
+            # one pass: the copy into the tile's buffer casts as it goes
+            self.set_tile(m, n, a[sm:sm + tm, sn:sn + tn])
         return self
 
     def to_jax_array(self, device=None):

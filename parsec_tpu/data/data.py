@@ -14,6 +14,7 @@ polled via jax's async semantics by the device module).
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import threading
 from enum import IntEnum
@@ -39,6 +40,37 @@ def is_device_array(x: Any) -> bool:
         return isinstance(x, jax.Array)
     except Exception:  # pragma: no cover - jax always present in-tree
         return False
+
+
+def to_host(arr: Any) -> Any:
+    """A writable host copy of ``arr`` that is the ONLY one.  A jax
+    array keeps the host value of its first conversion for its own
+    lifetime (on the TPU ``np.array(arr)`` is one copy cached in the
+    array and a second handed out: for a pulled tile twice its bytes
+    of host memory as long as the device copy lives), so the
+    conversion is made through a second array object over the same
+    device buffer, which is dropped here with its cache.  On the TPU
+    that object and its cached value refer to each other, so only the
+    cycle collector frees them, and a loop over a matrix's tiles makes
+    none of the container allocations that wake it (6.7 GB of such
+    garbage after a 6.7 GB pull; read on the chip, PR 46): the young
+    generations, where the object still is, are collected here.  For
+    the reader of a whole matrix (``TiledMatrix.to_numpy``), outside
+    any timed path: the pulls inside a call (``pull_to_host``, an
+    eviction's writeback) stay plain ``np.array``."""
+    import numpy as np
+    if not is_device_array(arr):
+        return np.array(arr)
+    import jax
+    try:
+        twin = jax.make_array_from_single_device_arrays(
+            arr.shape, arr.sharding, [arr])
+    except Exception:   # a sharded array: converted as it is
+        return np.array(arr)
+    out = np.array(twin)
+    del twin
+    gc.collect(1)
+    return out
 
 
 class Coherency(IntEnum):

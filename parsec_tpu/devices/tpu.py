@@ -260,7 +260,14 @@ class JaxDevice(Device):
                       # an earlier conversion answered, and the wall ns
                       # and count of the passes through the engine
                       "conversions": 0, "conversion_bytes": 0,
-                      "reshape_hits": 0, "reshape_ns": 0, "reshape_n": 0}
+                      "reshape_hits": 0, "reshape_ns": 0, "reshape_n": 0,
+                      # runtime-made buffers that are nobody's Data (a
+                      # WRITE-only flow's, handed from the task that
+                      # writes it to its readers): bytes copied here
+                      # from host memory for a stage-in (0 unless a
+                      # host body made the buffer), and bytes tasks
+                      # here wrote into such buffers
+                      "scratch_stage_in_bytes": 0, "scratch_out_bytes": 0}
         # the manager's always-on brackets (obs.phases.BRACKETS), one
         # per place it works under ``_manager_lock`` and none per task:
         # wall ns (``time.monotonic_ns``, a vDSO read) and how many.
@@ -465,13 +472,25 @@ class JaxDevice(Device):
                 continue
             data = ref.data_in.data
             if data is None:
-                # detached copy (e.g. NEW tile scratch): move payload directly
+                # nobody's Data: a runtime-made buffer handed from task
+                # to task (a WRITE-only flow's, a converted tile)
+                payload = ref.data_in.payload
+                if payload is None:
+                    # not written yet: this task's output is its first
+                    # value, the body gets no argument for it
+                    arrays.append(None)
+                    continue
                 if donate_ok is not None and access & FlowAccess.WRITE:
                     donate_ok[flow.flow_index] = True
-                if not is_device_array(ref.data_in.payload):
+                from_host = not is_device_array(payload)
+                if from_host:   # a host body made it
                     self.stats["stage_in_transfers"] += 1
                     self.stats["stage_in_tiles"] += 1
-                arrays.append(jax.device_put(ref.data_in.payload, target))
+                    self.stats["scratch_stage_in_bytes"] += getattr(
+                        payload, "nbytes", 0)
+                if from_host or _arr_device(payload) is not target:
+                    payload = jax.device_put(payload, target)
+                arrays.append(payload)
                 continue
             copy = data.get_copy(self.device_index)
             if copy is None:
@@ -1242,7 +1261,7 @@ class JaxDevice(Device):
         # slot, and of a replaced payload only when it differs
         slots = [(getattr(a, "shape", None), getattr(a, "dtype", None),
                   getattr(a, "nbytes", 0)) for a in outs[::n]]
-        delta = 0
+        delta = scratch = 0
         for i, task in enumerate(tasks):
             for k, fidx in enumerate(rec.out_flows[i]):
                 ref = task.data[fidx]
@@ -1258,8 +1277,12 @@ class JaxDevice(Device):
                     data.version_bump(index)
                     ref.data_out = copy
                 else:
+                    # nobody's Data (a WRITE-only flow's buffer): the
+                    # output is the copy, its readers hold it alive
                     ref.data_in.payload = outs[k * n + i]
                     ref.data_in.version += 1
+                    ref.data_in.device_id = index
+                    scratch += slots[k][2]
             for flow in task.task_class.flows:
                 if task.access_of(flow) == FlowAccess.READ and not flow.ctl:
                     ref = task.data[flow.flow_index]
@@ -1268,6 +1291,8 @@ class JaxDevice(Device):
                         ref.data_in.data.release_reader(index)
         if delta:
             self._account(delta)
+        if scratch:
+            self.stats["scratch_out_bytes"] += scratch
         self.executed_tasks += n
         t1 = _now()
         if clock is not None:

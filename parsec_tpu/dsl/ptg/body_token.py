@@ -13,7 +13,8 @@ value gets no token and its programs stay with its taskpool.
 
 How a global goes in:
 
-- a scalar (``NT`` in ``k < NT - 1``): name, type and ``repr``;
+- a scalar (``NT`` in ``k < NT - 1``), or a tuple of them (a stencil's
+  weights): name, type and ``repr``;
 - a module (``ops``, ``jnp``): BY THE ATTRIBUTES THE BODY REACHES
   THROUGH IT, resolved to the objects — ``ops.potrf`` is
   (``"ops.potrf"``, the function), never the module, so replacing
@@ -72,8 +73,14 @@ def body_reads(tree: ast.AST) -> Optional[Reads]:
     return reads if visit(tree) else None
 
 
+def _by_value(obj: Any) -> bool:
+    """A scalar, or a tuple of values that go in by value."""
+    return isinstance(obj, SCALARS) or (
+        isinstance(obj, tuple) and all(_by_value(o) for o in obj))
+
+
 def _nameable(obj: Any) -> bool:
-    if isinstance(obj, SCALARS):
+    if _by_value(obj):
         return True
     if not callable(obj):
         return False
@@ -85,7 +92,7 @@ def _nameable(obj: Any) -> bool:
 
 
 def _entry(dotted: str, obj: Any) -> Tuple:
-    if isinstance(obj, SCALARS):
+    if _by_value(obj):
         return (dotted, type(obj), repr(obj))   # -0.0 is not 0.0, nan is nan
     return (dotted, obj)
 
@@ -100,7 +107,7 @@ def _resolve(reads: Reads, global_env: Dict[str, Any],
             continue    # a flow, a local, a temporary, a builtin
         val = global_env[name]
         if not isinstance(val, types.ModuleType):
-            if not isinstance(val, SCALARS):
+            if not _by_value(val):
                 return None
             out[name] = val
             continue

@@ -583,6 +583,12 @@ class PTGTaskClass(TaskClass):
                     payloads[f.name] = Data.materialize_host(host)
                     task.data[i].data_in = host
                 else:
+                    if copy.payload is None and copy.dtt is not None \
+                            and self.flows[i].access == FlowAccess.WRITE:
+                        # a WRITE-only flow's buffer, first touched by a
+                        # host body: it may fill it in place
+                        copy.payload = np.zeros(copy.dtt.shape,
+                                                copy.dtt.dtype)
                     payloads[f.name] = Data.materialize_host(copy)
             env = self._body_env(task, payloads)
             exec(code, env)
@@ -1002,6 +1008,16 @@ class PTGTaskpool(Taskpool):
             raise RuntimeError(
                 f"flow {f.name}: NEW target needs a [shape=...] property")
         dt = np.dtype(f_prop(f, "dtype", "float32"))
+        if f.access == "WRITE":
+            # nothing reads a WRITE-only flow before its task writes it:
+            # the body's output is the flow's first value.  No host
+            # buffer, no stage-in, nobody's Data (so no LRU keeps it):
+            # the copy says what it will hold (``dtt``), a host body
+            # that wants a buffer to fill gets one from it
+            # (``_cpu_hook_factory``), and it dies with its last reader
+            copy = DataCopy(None, 0, payload=None, dtt=Datatype(dt, shape))
+            copy.coherency = Coherency.OWNED
+            return copy
         data = Data(nb_elts=int(np.prod(shape)))
         copy = DataCopy(data, 0, payload=np.zeros(shape, dtype=dt))
         copy.coherency = Coherency.OWNED

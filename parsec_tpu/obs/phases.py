@@ -43,7 +43,9 @@ engine's counters of that device moved by (``RESHAPE_COUNTERS``), and
 under ``placement`` the tasks the device ran and which rule of
 ``get_best_device`` sent them there (``PLACEMENT_COUNTERS``), and under
 ``stage`` the puts of its chunked set pass and the tasks it sent ahead
-of a copy (``STAGE_COUNTERS``).
+of a copy (``STAGE_COUNTERS``), and under ``scratch`` the bytes of
+runtime-made buffers staged in from the host and written by its tasks
+(``SCRATCH_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -60,7 +62,8 @@ from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PLACED_BY",
-           "PLACEMENT_COUNTERS", "STAGE_COUNTERS", "PhaseClock", "root_span",
+           "PLACEMENT_COUNTERS", "STAGE_COUNTERS", "SCRATCH_COUNTERS",
+           "PhaseClock", "root_span",
            "session_recording", "completed", "clear_completed",
            "format_report"]
 
@@ -97,10 +100,17 @@ PLACEMENT_COUNTERS = ("tasks",) + PLACED_BY
 #: set were still to be copied; a record's ``stage``
 STAGE_COUNTERS = ("stage_chunks", "tasks_ahead_of_copy")
 
+#: runtime-made buffers that are nobody's ``Data`` (a WRITE-only flow's,
+#: handed from the task that writes it to its readers; ``devices/tpu.py``):
+#: bytes copied to the device from host memory for a stage-in (0 unless
+#: a host body made the buffer) and bytes the device's tasks wrote into
+#: such buffers; a record's ``scratch``
+SCRATCH_COUNTERS = ("scratch_stage_in_bytes", "scratch_out_bytes")
+
 #: the counter groups a record's ``by_device`` entry holds beside the
 #: brackets, by the key each goes under
 _GROUPS = {"reshape": RESHAPE_COUNTERS, "placement": PLACEMENT_COUNTERS,
-           "stage": STAGE_COUNTERS}
+           "stage": STAGE_COUNTERS, "scratch": SCRATCH_COUNTERS}
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -462,6 +472,12 @@ def format_report(record: Dict[str, Any]) -> str:
                 f"{conv['reshape_hits']} look-ups an earlier one answered; "
                 f"{conv['reshape_ns'] / 1e9:.6f} s in {conv['reshape_n']} "
                 f"passes")
+        staged, wrote = (sum(e.get("scratch", {}).get(c, 0)
+                             for e in record["by_device"])
+                         for c in SCRATCH_COUNTERS)
+        if staged or wrote:
+            lines.append(f"runtime-made buffers: {wrote} bytes written by "
+                         f"tasks, {staged} staged in from the host")
     t0 = record["t0_ns"]
     for i, part in enumerate(record.get("parts", ())):
         lines.append(

@@ -1,11 +1,12 @@
 """Tile kernels (XLA/Pallas executables for task BODYs) and tile
 algorithms (dpotrf, dgeqrf, dgetrf_nopiv, dgetrf_1d, pdgemm,
-pdgemm_dtd)."""
+pdgemm_dtd) and the 1D stencil mini-app (stencil_1d)."""
 from .linalg import (axpy, gemm, gemm_nn, gemm_nn_sub, gemm_nt, gemm_nt_lo,
                      gemm_nt_mid,
                      gemm_tn, gemm_tn_sub, geqrt, geqrt_r, getrf_1d_laswp,
                      getrf_1d_panel, getrf_1d_update, getrf_nopiv, lauum_lower,
-                     potrf, scal, syrk_ln, syrk_lt, transpose,
+                     potrf, scal, stencil_ghosts, stencil_tile, syrk_ln,
+                     syrk_lt, transpose,
                      trmm_lower_trans, trsm_lower, trsm_lower_right_neg,
                      trsm_lower_trans, trsm_lower_unit, trsm_panel,
                      trsm_panel_mid,
@@ -25,6 +26,7 @@ from .dgetrf_1d import dgetrf_1d, dgetrf_1d_factory, dgetrf_1d_taskpool
 from .pdgemm import pdgemm, pdgemm_factory, pdgemm_taskpool
 from .pdgemm_dtd import pdgemm_dtd
 from .dtrsm import (dposv, dtrsm_lower_taskpool, dtrsm_lower_trans_taskpool)
+from .stencil_1d import stencil_1d, stencil_1d_taskpool, stencil_weights
 
 from . import pallas_kernels
 from .pallas_kernels import flash_attention
@@ -48,4 +50,6 @@ __all__ = ["potrf", "trsm_panel", "syrk_ln", "gemm_nt", "gemm_nn",
            "pdgemm", "pdgemm_factory", "pdgemm_taskpool", "pdgemm_dtd",
            "dposv", "dtrsm_lower_taskpool", "dtrsm_lower_trans_taskpool",
            "trsm_lower", "trsm_lower_trans", "gemm_tn_sub",
+           "stencil_1d", "stencil_1d_taskpool", "stencil_weights",
+           "stencil_tile", "stencil_ghosts",
            "pallas_kernels", "flash_attention"]

@@ -566,6 +566,57 @@ def getrf_1d_laswp(a: Any, p: Any, f: Any) -> Any:
                     unique_indices=True, mode="clip")
 
 
+# The 1D stencil of ops/stencil_1d.py: every row of a tile is a piece
+# of an independent 1D problem along the column index.  A ghost region
+# is the ``radius`` columns of a neighbour that border the tile, handed
+# over as a (radius, rows) array: row r of it is column r of the region
+# (a column vector of f32 would occupy a whole lane tile a row on the
+# chip, 128 times its bytes).
+def _stencil_tile(x: Any, left: Any, right: Any, *, weights: tuple) -> Any:
+    """One Jacobi step of a tile in XLA: the lowering for every
+    platform but the TPU and for the shapes the kernel does not take
+    (``pallas_kernels.stencil_tile_vmem``)."""
+    r = len(weights) // 2
+    nb = x.shape[1]
+    e = jnp.concatenate([left.T, x, right.T], axis=1)
+    out = weights[0] * e[:, 0:nb]
+    for d in range(1, 2 * r + 1):
+        out = out + weights[d] * e[:, d:d + nb]
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("weights",))
+def stencil_tile(x: Any, left: Any = None, right: Any = None,
+                 weights: tuple = (0.25, 0.5, 0.25)) -> Any:
+    """One Jacobi step of a tile: out[:, j] = sum_d weights[d] *
+    e[:, j + d] over e = [left | x | right], the tile between the
+    ``len(weights) // 2`` columns of its two neighbours that border it
+    (``stencil_ghosts``'s form; None: no neighbour, zeros).  Every
+    product and sum in the tile's dtype, left to right.  On the TPU,
+    for a tile the kernel takes (``pallas_kernels.stencil_fits``), ONE
+    Mosaic kernel that reads the tile once and writes it once; XLA
+    anywhere else."""
+    r = len(weights) // 2
+    rows = x.shape[0]
+    zero = jnp.zeros((r, rows), x.dtype)
+    left = zero if left is None else left
+    right = zero if right is None else right
+    xla = functools.partial(_stencil_tile, weights=weights)
+    if not pallas_kernels.stencil_fits(rows, x.shape[1], r):
+        return xla(x, left, right)
+    return jax.lax.platform_dependent(
+        x, left, right, default=xla,
+        tpu=functools.partial(pallas_kernels.stencil_tile_vmem,
+                              weights=weights))
+
+
+@functools.partial(jax.jit, static_argnames=("radius",))
+def stencil_ghosts(x: Any, radius: int = 1) -> Any:
+    """The two ghost regions a tile hands to its neighbours: its first
+    and its last ``radius`` columns, each as a (radius, rows) array."""
+    return x[:, :radius].T, x[:, x.shape[1] - radius:].T
+
+
 @jax.jit
 def axpy(y: Any, x: Any, alpha: float = 1.0) -> Any:
     return y + alpha * x

@@ -919,3 +919,105 @@ def lu_update_vmem(l: Any, c: Any, rows: Any, new: Any, r: Any, *,
         name="lu_update_vmem", interpret=interpret,
     )(jnp.reshape(r, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
       c, l, new)
+
+
+# --------------------------------------------------------------------- #
+# the 1D stencil's step of one tile (ops/stencil_1d.py)                 #
+# --------------------------------------------------------------------- #
+#: bytes of one block of rows of the tile a grid step of
+#: :func:`stencil_tile_vmem` holds (the block in, the block out, each
+#: twice for the pipeline, and the shifted copies a step makes)
+_STENCIL_BLOCK_BYTES = 2 << 20
+_STENCIL_LANES = 128
+
+
+def _stencil_rows(rows: int, nb: int) -> int:
+    """Rows a grid step holds: whole lanes of the ghosts' row form (a
+    multiple of 128), about ``_STENCIL_BLOCK_BYTES`` of the tile."""
+    blk = max(_STENCIL_LANES, _STENCIL_BLOCK_BYTES // (4 * nb)
+              // _STENCIL_LANES * _STENCIL_LANES)
+    while rows % blk and blk > _STENCIL_LANES:
+        blk -= _STENCIL_LANES
+    return blk
+
+
+def _stencil_vmem_bytes(blk: int, nb: int) -> int:
+    """What a grid step may ask for: the block in and out, twice each
+    for the pipeline, the rolled copies and the sums of a step, and
+    room for Mosaic's own scratch."""
+    return 10 * blk * nb * 4 + (8 << 20)
+
+
+def stencil_fits(rows: int, nb: int, radius: int) -> bool:
+    """The shape rule of :func:`stencil_tile_vmem`: whole blocks of
+    rows, whole lanes of columns, two distinct edge slabs, ghosts that
+    stay inside a slab, and a working set the kernel may ask for."""
+    return (rows % _STENCIL_LANES == 0 and nb % _STENCIL_LANES == 0
+            and nb >= 2 * _STENCIL_LANES and radius <= _STENCIL_LANES
+            and _stencil_vmem_bytes(_stencil_rows(rows, nb), nb)
+            <= _LU_STRIP_VMEM_MAX)
+
+
+def _stencil_kernel(x_ref, l_ref, r_ref, o_ref, *, weights):
+    rad = len(weights) // 2
+    blk, nb = x_ref.shape
+    lanes = _STENCIL_LANES
+    x = x_ref[...]
+    diag = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0) \
+        == jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+
+    def column(row):
+        # a (1, blk) row of a ghost as the (blk, 1) column it is
+        return jnp.sum(jnp.where(diag, jnp.broadcast_to(row, (blk, blk)),
+                                 0.0), axis=1, keepdims=True)
+
+    left = [column(l_ref[k:k + 1, :]) for k in range(rad)]
+    right = [column(r_ref[k:k + 1, :]) for k in range(rad)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (blk, lanes), 1)
+    acc = head = tail = None
+    for d, w in enumerate(weights):
+        s = d - rad         # this term is column j + s of [left | x | right]
+        t = x if s == 0 else pltpu.roll(x, (-s) % nb, 1)
+        th, tt = t[:, :lanes], t[:, nb - lanes:]
+        for j in range(-s):             # columns the roll wrapped round
+            th = jnp.where(lane == j, left[rad + j + s], th)
+        for j in range(s):
+            tt = jnp.where(lane == lanes - s + j, right[j], tt)
+        acc = w * t if acc is None else acc + w * t
+        head = w * th if head is None else head + w * th
+        tail = w * tt if tail is None else tail + w * tt
+    o_ref[...] = acc
+    o_ref[:, :lanes] = head
+    o_ref[:, nb - lanes:] = tail
+
+
+def stencil_tile_vmem(x: Any, left: Any, right: Any, *, weights: tuple,
+                      interpret: bool = False) -> Any:
+    """``ops.linalg._stencil_tile`` as ONE Mosaic kernel: the tile read
+    once and written once.
+
+    ``x`` is the (rows, nb) tile, ``left`` and ``right`` its two ghost
+    regions as (radius, rows) arrays (zeros: no neighbour).  A grid step
+    holds a block of rows: every term of the sum is the block rolled
+    along the lanes (the XLU; no shifted copy goes to memory), summed
+    left to right as the XLA form does; the ``radius`` columns a roll
+    wrapped round are put right in the first and the last 128 lanes
+    only, from the ghosts, whose row form is turned into columns in
+    VMEM.  Shapes: :func:`stencil_fits`.  Reads no parameter;
+    ``interpret`` is for the tests on the CPU."""
+    rows, nb = x.shape
+    rad = len(weights) // 2
+    blk = _stencil_rows(rows, nb)
+    return pl.pallas_call(
+        functools.partial(_stencil_kernel, weights=weights),
+        out_shape=jax.ShapeDtypeStruct((rows, nb), x.dtype),
+        grid=(rows // blk,),
+        in_specs=[pl.BlockSpec((blk, nb), lambda i: (i, 0)),
+                  pl.BlockSpec((rad, blk), lambda i: (0, i)),
+                  pl.BlockSpec((rad, blk), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((blk, nb), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_stencil_vmem_bytes(blk, nb)),
+        name="stencil_tile_vmem", interpret=interpret,
+    )(x, left, right)

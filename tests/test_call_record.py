@@ -66,7 +66,11 @@ def _check_device_entry(entry, root_ns):
     counters, still where nothing declares a type, and the tasks the
     device ran with the rule that placed each."""
     assert set(entry) == set(phases.BRACKETS) | {"device", "reshape",
-                                                 "placement", "stage"}
+                                                 "placement", "stage",
+                                                 "scratch"}
+    assert set(entry["scratch"]) == set(phases.SCRATCH_COUNTERS)
+    # no host body made a buffer a device task read
+    assert entry["scratch"]["scratch_stage_in_bytes"] == 0
     assert set(entry["stage"]) == set(phases.STAGE_COUNTERS)
     # small tiles: every set under the bound, a put a pass at most
     assert entry["stage"]["tasks_ahead_of_copy"] == 0

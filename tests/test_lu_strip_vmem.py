@@ -357,3 +357,30 @@ def test_conversion_program_compiles_for_the_v5e(one_chip):
     out, = jax.tree_util.tree_leaves(compiled.out_info)
     assert out.dtype == jnp.bfloat16 and out.shape == (nb, nb)
     assert "jit_CONVERT" in compiled.as_text()
+
+
+@pytest.mark.parametrize("nb,radius", [(4096, 1), (2048, 1), (8192, 1),
+                                        (4096, 2)])
+def test_stencil_program_for_the_v5e_is_one_kernel(one_chip, nb, radius):
+    """The STENCIL body (``ops/stencil_1d.py``) at the cell's tile and
+    the two by-hand sizes: ONE Mosaic call for the step (no shifted
+    copy of the tile, no temporary), the ghosts as (radius, nb) arrays
+    of whole lanes."""
+    from parsec_tpu import ops
+    weights = ops.stencil_weights(radius)
+
+    def body(x, left, right):
+        x = linalg.stencil_tile(x, left, right, weights)
+        return (x,) + tuple(linalg.stencil_ghosts(x, radius))
+
+    tile = jax.ShapeDtypeStruct((nb, nb), jnp.float32, sharding=one_chip)
+    ghost = jax.ShapeDtypeStruct((radius, nb), jnp.float32,
+                                 sharding=one_chip)
+    compiled = jax.jit(body).lower(tile, ghost, ghost).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert "stencil_tile_vmem" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    x, gl, gr = jax.tree_util.tree_leaves(compiled.out_info)
+    assert x.shape == (nb, nb) and gl.shape == gr.shape == (radius, nb)

@@ -394,10 +394,10 @@ def test_reader_reads_its_number_and_nothing_where_there_is_none(name):
 def test_benchmark_lists_the_cell_and_its_readers():
     bench = spec.load_benchmark()
     cells = [w["name"] for w in bench["workloads"]]
-    assert cells[-1] == CELL and len(cells) == 11
+    assert cells.index(CELL) == 10 and len(cells) >= 11  # later PRs append
     assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
         == [CELL4, CELL]
-    assert bench["configs"][-1]["name"] == "dgemm-dtd-f32-4chip"
+    assert bench["configs"][8]["name"] == "dgemm-dtd-f32-4chip"
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"]
               if m["name"] not in READERS}
