@@ -49,7 +49,8 @@ _log = plog.device_stream
 #: CALL) and the running count of the tasks the window's calls hold
 #: belong to the manager lock (one manager at a time — the CAS-owner
 #: acquire in ``progress``; helpers on that path carry ``# holds:``
-#: annotations)
+#: annotations), and so does what the manager decides from at a
+#: ``wait()``'s exit
 _GUARDED_BY = {
     "JaxDevice.mem_used": "_mem_lock",
     "JaxDevice.mem_highwater": "_mem_lock",
@@ -60,6 +61,8 @@ _GUARDED_BY = {
     "JaxDevice._eager_done": "_manager_lock",
     "JaxDevice._backlog": "_manager_lock",
     "JaxDevice._landing": "_manager_lock",
+    "JaxDevice._stage_bound": "_manager_lock",
+    "JaxDevice._wait_mark": "_manager_lock",
 }
 
 #: the host bytes at which a chunk of a drained ready set closes
@@ -85,7 +88,53 @@ _GUARDED_BY = {
 #: nb2048``) 128 gains 12.7% and 64 gained 15.7% (the chip waits 5 ms
 #: longer for each k-step's first tiles); the others read the same.
 #: Observed in ``src.payload.nbytes``; not a parameter.
+#:
+#: This is the bound a set must pass to be cut ONLY where the chip has
+#: been making its manager wait (``CHIP_WAIT_SHARE``, below): a copy is
+#: worth hiding only where the chip's work is what the call waits for.
 STAGE_CHUNK_BYTES = 128 << 20
+
+#: what decides between the two bounds (``JaxDevice._note_wait``, once
+#: a ``wait()``): over the manager's last finished wait, the wall it
+#: was blocked on its chip (bracket ``chip_wait``) against the wall it
+#: WORKED (the five other brackets less ``first_call_ns``).  Under a
+#: tenth the manager is the bound: every chunk is a device call or
+#: more, smaller buckets and one more pass, and the chip catches up
+#: behind the manager either way, so a set is cut only over
+#: ``STAGE_WHOLE_FACTOR`` times the bound (1 GiB).  Not "never": a
+#: put's host staging copy lives until its DMA ends, and two puts in
+#: flight of 1 GiB stage 2 GiB at most where one put of a 6.7 GB front
+#: (``stencil1d.n40960-nb4096-i100``, whose process holds 34-35 of the
+#: machine's 40 GiB) could end the process.  What a manager reads, a
+#: call (chip runs, PR 47: least / median / largest over the window's
+#: calls, the number of readings in brackets; four managers in the
+#: four-chip cell), cutting at 128 MiB | at 1 GiB, and what PR 44's
+#: chunks had done to ``factor_s`` (ledger, PR 44):
+#:
+#:   dgetrf.n32768-nb1024  2.16 / 2.36 / 2.88 (28)     |        -17.4%
+#:   dpotrf.n32768-nb2048  0.43 / 0.55 / 0.72 (28)     |        -15.5%
+#:   dgetrf.n16384-nb512   0.34 / 0.41 / 0.49 (28)     |         -6.8%
+#:   dgemm.n24576-nb2048   0.22 / 0.29 / 0.46 (27; one 0.065) | -12.0%
+#:   dpotrf-mp.n32768      0.097 / 0.165 / 0.218 (82)  | 0.42, 0.51 (2)  -6.7%
+#:   ---- a tenth ----
+#:   dgemm-dtd-4chip .013 / .039 / .064 (56) | .047 / .075 / .127 (64) +18.2%
+#:   stencil1d.n40960 .052 / .066 / .070 (8) | .076 / .087 / .092 (8) PR 46's
+#:   dpotrf-dtd.n20480 .041 / .048 / .082 (9) | .039 / .043 / .048 (9) +1.8%
+#:   dpoinv.n16384 .030 / .036 / .038 (6) | .034 / .037 / .039 (6) +2.0%
+#:   dpotrf.n16384 .033 / .038 / .127 (28) | .010 / .037 / .079 (28) +4.9%
+#:
+#: The medians stand far apart whichever way the manager last decided
+#: (0.165 and more against 0.09 and less), so the answer does not
+#: flip-flop; single calls stray by a third of their reading and more:
+#: of ``dpotrf-mp``'s 82 chunked calls ten read under an eighth (the
+#: ledger's means had suggested an eighth) and two under a tenth; of
+#: the 64 readings of ``dgemm-dtd-4chip``'s managers one read over
+#: either (0.127).  Hence a tenth; a call that strays costs the next
+#: call of its device 3 to 8%, which then reads back on its own side
+#: (``dpotrf-mp``: one call in thirty takes one set whole).  Observed
+#: in the device's own ``stats``; not parameters.
+CHIP_WAIT_SHARE = 1 / 10
+STAGE_WHOLE_FACTOR = 8
 
 #: tasks the window's calls may hold after dispatch before the manager
 #: blocks on the oldest call: bounds how far the chip's queue runs ahead
@@ -233,8 +282,12 @@ class JaxDevice(Device):
                       # drained ready set; and tasks dispatched while
                       # host tiles of their own set were still to be
                       # copied (resident tasks sent ahead of a set over
-                      # ``STAGE_CHUNK_BYTES``, every chunk but its last)
+                      # the bound, every chunk but its last); and sets
+                      # over ``STAGE_CHUNK_BYTES`` that went whole all
+                      # the same, because the chip had not been making
+                      # this manager wait (``_note_wait``)
                       "stage_chunks": 0, "tasks_ahead_of_copy": 0,
+                      "sets_whole_by_wait": 0,
                       # tiles ``prestage_many`` staged ahead of the
                       # per-task stage-in / found there by it
                       "prefetch_issued": 0, "prefetch_hits": 0,
@@ -298,6 +351,14 @@ class JaxDevice(Device):
         # waits until the one before last has landed
         # (``_stage_in_set``)
         self._landing: List[Any] = []
+        # what a drained set's new host bytes must pass before it is
+        # cut into chunks, decided once a ``wait()`` (``_note_wait``)
+        # from ``wait_reading``: the (``chip_wait``, work) ns of this
+        # manager's last finished wait, None until one has retired a
+        # call; ``_wait_mark``: the counters as that wait left them
+        self._stage_bound = STAGE_CHUNK_BYTES
+        self.wait_reading: Optional[Tuple[int, int]] = None
+        self._wait_mark = (0, 0, 0)
         # batched dispatch (the task-stream pipeline; ISSUE 5):
         # same-class ready tasks accumulate in ``pending`` and are
         # stacked into one jitted call per (class, shapes, dtypes,
@@ -382,12 +443,12 @@ class JaxDevice(Device):
                 n += self._poll(es)
                 if not self._backlog:
                     return n
-                # a set over ``STAGE_CHUNK_BYTES`` left tasks waiting
-                # for their chunk: what the poll released and what
-                # arrived under the chunk's copy is drained first.  The
-                # best of what this thread released was kept for it to
-                # run next, and it is not going back to its loop: the
-                # other workers get it
+                # a set over the bound left tasks waiting for their
+                # chunk: what the poll released and what arrived under
+                # the chunk's copy is drained first.  The best of what
+                # this thread released was kept for it to run next, and
+                # it is not going back to its loop: the other workers
+                # get it
                 hand_over_kept(es)
         finally:
             if clock is not None:
@@ -634,7 +695,16 @@ class JaxDevice(Device):
         in ``_backlog`` the caller's loop hands back after its poll
         phase (``progress``).
 
-        A set whose host tiles stay under ``STAGE_CHUNK_BYTES`` (and
+        The bound is ``_stage_bound``: ``STAGE_CHUNK_BYTES`` where this
+        manager's last finished wait says its chip made it wait (and
+        before any has finished), ``STAGE_WHOLE_FACTOR`` times that
+        where it says the manager is the bound (``_note_wait``: chunks
+        hide a copy under the chip's work, and cost calls and passes
+        where nothing waits for the chip).  Counter
+        ``sets_whole_by_wait``: sets over the first that went whole
+        under the second.
+
+        A set whose host tiles stay under the bound (and
         no backlog before it) is one chunk: ONE list ``device_put``
         (``_stage_in_set``), then ``_dispatch_groups`` over the whole
         set: group by (class, static context, shapes, dtypes, donate
@@ -659,14 +729,17 @@ class JaxDevice(Device):
         ``dispatch`` and ``chip_wait`` brackets closed inside it)."""
         st = self.stats
         backlog = self._backlog
+        bound = self._stage_bound
         wall = [0, 0]   # ns of the set pass, ns of the grouping
         n = 0
         try:
             with self._set_pass(wall, len(items)):
                 need: Dict[int, Tuple] = {}
                 looks = [self._host_need(task, need) for task, _e in items]
-                whole = not backlog and sum(
-                    new for _w, new in looks) < STAGE_CHUNK_BYTES
+                new_bytes = sum(new for _w, new in looks)
+                whole = not backlog and new_bytes < bound
+                if whole and new_bytes >= STAGE_CHUNK_BYTES:
+                    st["sets_whole_by_wait"] += 1
                 first = [] if whole else [
                     it for it, (w, _new) in zip(items, looks) if not w]
                 backlog.extend((task, est, w) for (task, est), (w, _new)
@@ -688,7 +761,7 @@ class JaxDevice(Device):
                     for task, _e, _w in backlog:
                         k += 1
                         held += self._host_need(task, need)[1]
-                        if held >= STAGE_CHUNK_BYTES:
+                        if held >= bound:
                             break
                 chunk = backlog[:k]
                 del backlog[:k]
@@ -1188,8 +1261,37 @@ class JaxDevice(Device):
             self._window_tasks = 0
             self._prefetched.clear()
             self._landing = []
+            self._note_wait()
         finally:
             self._manager_lock.release()
+
+    def _note_wait(self) -> None:  # holds: self._manager_lock
+        """The observation behind ``_stage_bound``, taken once a
+        ``wait()``, at its exit: what the always-on brackets moved by
+        since the wait before: ``chip_wait`` (the manager blocked on
+        its chip) and the five it WORKS in, less ``first_call_ns`` (a
+        program's first call traces, lowers and loads inside
+        ``dispatch``: tens of seconds in a cold call, which would read
+        as "the manager is the bound" whatever the chip did).  A wait
+        that retired no call leaves the last reading as it is.  One
+        reading a wait: a call's sets are all treated alike, and
+        nothing is computed a record or a task.  Under
+        ``CHIP_WAIT_SHARE`` the chip was not what the call waited for,
+        and the next wait's sets are cut only over
+        ``STAGE_WHOLE_FACTOR`` times ``STAGE_CHUNK_BYTES``."""
+        st = self.stats
+        calls, waited, worked = mark = (
+            st["retired_calls"], st["chip_wait_ns"],
+            sum(st[b + "_ns"] for b in BRACKETS if b != "chip_wait")
+            - st["first_call_ns"])
+        calls0, waited0, worked0 = self._wait_mark
+        self._wait_mark = mark
+        if calls != calls0:
+            waited, worked = waited - waited0, worked - worked0
+            self.wait_reading = (waited, worked)
+            self._stage_bound = STAGE_CHUNK_BYTES * (
+                STAGE_WHOLE_FACTOR if waited < CHIP_WAIT_SHARE * worked
+                else 1)
 
     def _retire(self, rec: _InFlight, es=None, context=None) -> None:
         """Release one call's window entry: drop its load contribution

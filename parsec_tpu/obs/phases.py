@@ -42,10 +42,10 @@ are disjoint, so their sum is at most the call's root span.  Each
 engine's counters of that device moved by (``RESHAPE_COUNTERS``), and
 under ``placement`` the tasks the device ran and which rule of
 ``get_best_device`` sent them there (``PLACEMENT_COUNTERS``), and under
-``stage`` the puts of its chunked set pass and the tasks it sent ahead
-of a copy (``STAGE_COUNTERS``), and under ``scratch`` the bytes of
-runtime-made buffers staged in from the host and written by its tasks
-(``SCRATCH_COUNTERS``).
+``stage`` the puts of its chunked set pass, the tasks it sent ahead
+of a copy and the sets it left whole (``STAGE_COUNTERS``), and under
+``scratch`` the bytes of runtime-made buffers staged in from the host
+and written by its tasks (``SCRATCH_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -96,9 +96,12 @@ PLACEMENT_COUNTERS = ("tasks",) + PLACED_BY
 
 #: what the chunked set pass of a device counts (``devices/tpu.py::
 #: _dispatch_ready``): the list puts it issued, one a chunk of a drained
-#: ready set, and the tasks it dispatched while host tiles of their own
-#: set were still to be copied; a record's ``stage``
-STAGE_COUNTERS = ("stage_chunks", "tasks_ahead_of_copy")
+#: ready set, the tasks it dispatched while host tiles of their own set
+#: were still to be copied, and the sets over ``STAGE_CHUNK_BYTES`` that
+#: went whole because the chip had not made the manager wait; a
+#: record's ``stage``
+STAGE_COUNTERS = ("stage_chunks", "tasks_ahead_of_copy",
+                  "sets_whole_by_wait")
 
 #: runtime-made buffers that are nobody's ``Data`` (a WRITE-only flow's,
 #: handed from the task that writes it to its readers; ``devices/tpu.py``):
@@ -456,12 +459,14 @@ def format_report(record: Dict[str, Any]) -> str:
         lines.append(f"in no bracket: {(root - inside / managers) / 1e9:.6f} "
                      f"s of the root span (the brackets' mean over "
                      f"{managers} manager(s) taken out)")
-        chunks, ahead = (sum(e.get("stage", {}).get(c, 0)
-                             for e in record["by_device"])
-                         for c in STAGE_COUNTERS)
-        if ahead:
+        chunks, ahead, whole = (sum(e.get("stage", {}).get(c, 0)
+                                    for e in record["by_device"])
+                                for c in STAGE_COUNTERS)
+        if ahead or whole:
             lines.append(f"set pass: {chunks} list puts, {ahead} tasks "
-                         f"dispatched ahead of a copy of their own set")
+                         f"dispatched ahead of a copy of their own set, "
+                         f"{whole} sets over the bound whole (the chip "
+                         f"had not made the manager wait)")
         conv = {c: sum(e.get("reshape", {}).get(c, 0)
                        for e in record["by_device"])
                 for c in RESHAPE_COUNTERS}

@@ -72,8 +72,10 @@ def _check_device_entry(entry, root_ns):
     # no host body made a buffer a device task read
     assert entry["scratch"]["scratch_stage_in_bytes"] == 0
     assert set(entry["stage"]) == set(phases.STAGE_COUNTERS)
-    # small tiles: every set under the bound, a put a pass at most
+    # small tiles: every set under the bound, a put a pass at most,
+    # and none that the last wait's reading had to leave whole
     assert entry["stage"]["tasks_ahead_of_copy"] == 0
+    assert entry["stage"]["sets_whole_by_wait"] == 0
     assert 0 < entry["stage"]["stage_chunks"] <= entry["set_stage"]["count"]
     assert set(entry["reshape"]) == set(phases.RESHAPE_COUNTERS)
     assert all(v >= 0 for v in entry["reshape"].values())
@@ -147,6 +149,31 @@ def test_untraced_call_leaves_one_record(one_device_ctx, records, op):
     # nothing was switched on for it
     assert one_device_ctx._root_call is None
     assert one_device_ctx._phase_clock is None and dev._phases is None
+
+
+def test_a_set_left_whole_by_the_last_wait_shows_in_the_record(
+        one_device_ctx, records, monkeypatch):
+    """``sets_whole_by_wait`` rides under ``by_device[*]["stage"]``
+    beside the two counters it joins: the bound at two tiles, a manager
+    whose last wait never waited for its chip (as the device's own
+    counters say), and the call's record and report count the sets
+    between the two bounds that went in one put."""
+    from parsec_tpu.devices import tpu
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", 2 * NB * NB * 4)
+    dev, = _accel(one_device_ctx)
+    dev.stats["dispatch_ns"] += 190_000_000
+    dev.stats["chip_wait_ns"] += 10_000_000
+    dev.stats["retired_calls"] += 1
+    dev.drain(one_device_ctx)
+    before = dict(dev.stats)
+    ops.dpotrf(one_device_ctx, _matrix())
+    rec, = records()
+    entry, = rec["by_device"]
+    moved = _moved(dev, before)
+    assert entry["stage"] == {c: moved[c] for c in phases.STAGE_COUNTERS}
+    assert entry["stage"]["sets_whole_by_wait"] > 0
+    assert f"{entry['stage']['sets_whole_by_wait']} sets over the bound " \
+        "whole" in phases.format_report(rec)
 
 
 def test_each_device_has_its_own_disjoint_brackets(ctx4, records):

@@ -3,8 +3,11 @@ set whose host tiles pass ``devices.tpu.STAGE_CHUNK_BYTES`` is staged a
 chunk at a time, each chunk's tasks dispatched right behind its ONE
 list ``device_put``, the tasks that wait for no host tile ahead of the
 first; a set under the bound is one put and one grouping as before.
-Counts, orders and values only: no time is asserted.  The bound is
-moved by monkeypatch, never by a parameter.
+Where the manager's last finished wait says its chip had not made it
+wait (``JaxDevice._note_wait``) the bound is ``STAGE_WHOLE_FACTOR``
+times that.  Counts, orders and values only: no time is asserted.  The
+bound is moved by monkeypatch, never by a parameter, and a reading is
+what ``drain`` takes from the device's own counters.
 """
 import contextlib
 
@@ -25,15 +28,28 @@ TASK = 3 * TILE     # a burst task brings three tiles of its own
 BURST = 12
 ABOVE_EVERY_SET = 1 << 62
 KEYS = ("stage_in_tiles", "stage_in_transfers", "stage_in_bytes",
-        "stage_chunks", "tasks_ahead_of_copy", "batches", "batched_tasks",
-        "dispatch_tasks", "tasks", "set_stage_n", "group_n")
+        "stage_chunks", "tasks_ahead_of_copy", "sets_whole_by_wait",
+        "batches", "batched_tasks", "dispatch_tasks", "tasks",
+        "set_stage_n", "group_n")
+MS = 1_000_000
+#: what a finished wait's brackets moved by (``_finish_wait``): the chip
+#: made the manager wait a ninth of its work, exactly a tenth (not
+#: UNDER it), an eleventh; and a cold wait: next to nothing by the
+#: brackets, but all of ``dispatch`` was programs' first calls
+CHIP_BOUND = {"chip_wait": 10 * MS, "dispatch": 50 * MS, "epilog": 40 * MS}
+A_TENTH = {"chip_wait": 10 * MS, "group": 100 * MS}
+MANAGER_BOUND = {"chip_wait": 10 * MS, "set_stage": 20 * MS,
+                 "group": 20 * MS, "dispatch": 40 * MS, "epilog": 20 * MS,
+                 "complete": 10 * MS}
+COLD = {"chip_wait": 10 * MS, "dispatch": 60_000 * MS,
+        "first_call": 60_000 * MS, "complete": 30 * MS}
 
 
 def _context(nb_cores=1, **over):
     """ONE accelerator and, unless asked otherwise, one worker (the
     caller, inside ``wait``): a burst inserted before ``wait`` then
     reaches the device's queue whole."""
-    over["device_tpu_max"] = 1
+    over.setdefault("device_tpu_max", 1)
     with contextlib.ExitStack() as stack:
         for k, v in over.items():
             stack.enter_context(params.cmdline_override(k, str(v)))
@@ -43,6 +59,17 @@ def _context(nb_cores=1, **over):
 def _dev(ctx):
     dev, = (d for d in ctx.devices if d.device_type == "tpu")
     return dev
+
+
+def _finish_wait(ctx, dev, moved, calls=1):
+    """A ``wait()`` of ``ctx`` ends in which ``dev``'s manager retired
+    ``calls`` calls and its brackets moved by ``moved`` (ns by bracket;
+    ``first_call``: the part of ``dispatch`` in programs' first calls):
+    the counters are the device's own, the reading ``drain`` takes."""
+    for name, ns in moved.items():
+        dev.stats[name + "_ns"] += ns
+    dev.stats["retired_calls"] += calls
+    dev.drain(ctx)
 
 
 def _burst(ctx, n=BURST, nbs=()):
@@ -104,13 +131,16 @@ def events(monkeypatch):
     return log
 
 
-def _run_burst(prestaged=0, nbs=()):
+def _run_burst(prestaged=0, nbs=(), last_wait=None):
     """The burst through a fresh context; the first ``prestaged`` tasks'
-    tiles are on the chip before anything is drained.  Returns the
+    tiles are on the chip before anything is drained, and the
+    accelerator's wait before this one read ``last_wait``.  Returns the
     accelerator's counters."""
     ctx = _context(device_batch_max=BURST)
     try:
         dev = _dev(ctx)
+        if last_wait is not None:
+            _finish_wait(ctx, dev, last_wait)
         tp, tiles = _burst(ctx, nbs=nbs)
         for task_tiles in tiles[:prestaged]:
             assert len(dev.prestage_many([t.data for t in task_tiles])) == 3
@@ -260,6 +290,153 @@ def test_a_failure_mid_set_leaves_the_rest_where_drain_finds_it(
             ctx.fini()
 
 
+# ---------------------------------------------------------------- #
+# the bound is what the manager's last finished wait says          #
+# ---------------------------------------------------------------- #
+@pytest.mark.parametrize("last_wait", [None, CHIP_BOUND, A_TENTH, COLD],
+                         ids=["no-wait-yet", "a-ninth", "a-tenth",
+                              "cold-first-calls"])
+def test_a_manager_its_chip_made_wait_cuts_at_the_bound(
+        monkeypatch, events, last_wait):
+    """No wait finished yet, the chip waited for a tenth of the work
+    or more, or a wait whose ``dispatch`` bracket was all programs'
+    first calls (``first_call_ns`` is left out of the work: a cold call
+    does not read as manager-bound): the set is cut exactly as
+    ``test_a_set_over_the_bound_goes_chunk_by_chunk`` says."""
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", 2 * TASK)
+    d = _run_burst(last_wait=last_wait)
+    assert events == [("put", 6), ("call", 2)] * 6
+    assert d["stage_in_transfers"] == d["stage_chunks"] == 6
+    assert d["tasks_ahead_of_copy"] == BURST - 2
+    assert d["sets_whole_by_wait"] == 0
+    assert d["set_stage_n"] == d["group_n"] == 6
+
+
+def test_a_manager_that_never_waited_takes_a_set_between_the_bounds_whole(
+        monkeypatch, events):
+    """The chip waited for an eleventh of the work: twelve tasks' bytes,
+    between two tasks' and sixteen, go in ONE put and ONE grouping, the
+    path of a set under the bound count for count, and the counter
+    says why."""
+    under = _run_burst()
+    del events[:]
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", 2 * TASK)
+    d = _run_burst(last_wait=MANAGER_BOUND)
+    assert events == [("put", 3 * BURST), ("call", 8), ("call", 4)]
+    assert d["stage_in_transfers"] == d["stage_chunks"] == 1
+    assert d["tasks_ahead_of_copy"] == 0
+    assert d["set_stage_n"] == d["group_n"] == 1
+    assert d.pop("sets_whole_by_wait") == 1
+    assert under.pop("sets_whole_by_wait") == 0
+    assert d == under
+
+
+@pytest.mark.parametrize("last_wait, cut", [
+    (MANAGER_BOUND, [("put", 9), ("call", 2), ("call", 1)] * 4),
+    (CHIP_BOUND, [("put", 3), ("call", 1)] * BURST)],
+    ids=["at-the-larger-bound", "at-the-bound"])
+def test_a_set_over_the_larger_bound_is_cut_there(
+        monkeypatch, events, last_wait, cut):
+    """The bound at one tile: eight tiles is the larger one, and twelve
+    tasks' 36 tiles pass both.  A manager that never waited closes a
+    chunk at the task that reaches eight tiles (the third: four puts),
+    one its chip made wait at every task (twelve)."""
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", TILE)
+    assert tpu.STAGE_WHOLE_FACTOR * TILE < BURST * TASK
+    d = _run_burst(last_wait=last_wait)
+    assert events == cut
+    puts = sum(1 for kind, _n in cut if kind == "put")
+    assert d["stage_chunks"] == d["set_stage_n"] == puts
+    assert d["tasks_ahead_of_copy"] == BURST - BURST // puts
+    assert d["sets_whole_by_wait"] == 0
+    assert d["stage_in_tiles"] == 3 * BURST
+
+
+def test_a_finished_wait_leaves_what_the_brackets_moved_by():
+    """A device's first wait decides at the module's bound and leaves
+    the reading: ``chip_wait`` and the five working brackets less
+    ``first_call_ns``, as the device's own counters have them."""
+    ctx = _context(device_batch_max=BURST)
+    try:
+        dev = _dev(ctx)
+        assert dev.wait_reading is None
+        assert dev._stage_bound == tpu.STAGE_CHUNK_BYTES
+        tp, tiles = _burst(ctx)
+        tp.wait()
+        ctx.wait()
+        _assert_burst_result(tiles)
+        st = dev.stats
+        worked = sum(st[b + "_ns"] for b in (
+            "set_stage", "group", "dispatch", "epilog", "complete"))
+        assert st["retired_calls"] == 2 and st["first_call_ns"] > 0
+        assert dev.wait_reading == (st["chip_wait_ns"],
+                                    worked - st["first_call_ns"])
+        assert dev._stage_bound in (
+            tpu.STAGE_CHUNK_BYTES,
+            tpu.STAGE_WHOLE_FACTOR * tpu.STAGE_CHUNK_BYTES)
+    finally:
+        ctx.fini()
+
+
+def test_a_wait_that_retired_nothing_keeps_the_last_reading():
+    """The reading is of the last wait that retired a call; what the
+    brackets moved by in a wait without one (an empty ``wait()``, a
+    taskpool of host tasks) is not carried into the next reading
+    either: the deltas are since the previous ``drain``."""
+    ctx = _context()
+    try:
+        dev = _dev(ctx)
+        whole = tpu.STAGE_WHOLE_FACTOR * tpu.STAGE_CHUNK_BYTES
+        _finish_wait(ctx, dev, MANAGER_BOUND)
+        assert dev.wait_reading == (10 * MS, 110 * MS)
+        assert dev._stage_bound == whole
+        _finish_wait(ctx, dev, CHIP_BOUND, calls=0)
+        ctx.wait()      # nothing to run: nothing retired
+        assert dev.wait_reading == (10 * MS, 110 * MS)
+        assert dev._stage_bound == whole
+        _finish_wait(ctx, dev, A_TENTH)
+        assert dev.wait_reading == (10 * MS, 100 * MS)
+        assert dev._stage_bound == tpu.STAGE_CHUNK_BYTES
+        _finish_wait(ctx, dev, COLD)
+        assert dev.wait_reading == (10 * MS, 30 * MS)
+        assert dev._stage_bound == tpu.STAGE_CHUNK_BYTES
+        _finish_wait(ctx, dev, MANAGER_BOUND, calls=3)
+        assert dev._stage_bound == whole
+    finally:
+        ctx.fini()
+
+
+def test_two_devices_of_one_context_decide_apart(monkeypatch):
+    """The reading is a device's own: of two accelerators in one
+    context the one that never waited for its chip takes its twelve
+    tasks' tiles in one put, the other cuts the same set in six."""
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", 2 * TASK)
+    ctx = _context(device_batch_max=BURST, device_tpu_max=2)
+    try:
+        never, waited = (d for d in ctx.devices if d.device_type == "tpu")
+        _finish_wait(ctx, never, MANAGER_BOUND)
+        _finish_wait(ctx, waited, CHIP_BOUND)
+        assert never._stage_bound == 8 * waited._stage_bound
+        tp, tiles = _burst(ctx, 2 * BURST)
+        for dev, mine in ((never, tiles[:BURST]), (waited, tiles[BURST:])):
+            for c, _a, _b in mine:
+                dev.data_advise(c.data, "preferred_device")
+        before = [{k: d.stats[k] for k in KEYS} for d in (never, waited)]
+        tp.wait()
+        ctx.wait()
+        _assert_burst_result(tiles)
+        d0, d1 = ({k: d.stats[k] - b[k] for k in KEYS}
+                  for d, b in zip((never, waited), before))
+        assert d0["tasks"] == d1["tasks"] == BURST
+        assert d0["stage_in_tiles"] == d1["stage_in_tiles"] == 3 * BURST
+        assert (d0["stage_chunks"], d0["tasks_ahead_of_copy"],
+                d0["sets_whole_by_wait"]) == (1, 0, 1)
+        assert (d1["stage_chunks"], d1["tasks_ahead_of_copy"],
+                d1["sets_whole_by_wait"]) == (6, BURST - 2, 0)
+    finally:
+        ctx.fini()
+
+
 def test_the_task_kept_for_a_busy_manager_goes_to_the_scheduler():
     """``schedule_keep_best`` keeps the best released task for the
     releasing thread; a manager that stays in its loop hands it to the
@@ -340,10 +517,12 @@ OPERATIONS = {
 OVERRIDES = {"pdgemm": {"device_batch_max": 1}}
 
 
-def _run(op, nb_cores=4):
+def _run(op, nb_cores=4, last_wait=None):
     ctx = _context(nb_cores, **OVERRIDES.get(op, {}))
     try:
         dev = _dev(ctx)
+        if last_wait is not None:
+            _finish_wait(ctx, dev, last_wait)
         before = {k: dev.stats[k] for k in KEYS}
         out = OPERATIONS[op](ctx)
         assert dev._backlog == []
@@ -372,6 +551,26 @@ def test_an_operation_is_bit_equal_however_its_sets_are_cut(
     assert d1["stage_in_bytes"] == d0["stage_in_bytes"]
     assert d1["stage_chunks"] > d0["stage_chunks"]
     assert d1["tasks_ahead_of_copy"] > 0
+
+
+@pytest.mark.parametrize("op", sorted(OPERATIONS))
+def test_an_operation_is_bit_equal_whichever_way_the_last_wait_read(
+        monkeypatch, op):
+    """The bound at two tiles' bytes, so the larger one at sixteen: a
+    manager that never waited for its chip takes the sets between them
+    whole, one its chip made wait cuts them.  The results are equal to
+    the bit, and so are the tiles and bytes staged."""
+    monkeypatch.setattr(tpu, "STAGE_CHUNK_BYTES", 2 * 32 * 32 * 4)
+    whole, d0 = _run(op, last_wait=MANAGER_BOUND)
+    cut, d1 = _run(op, last_wait=CHIP_BOUND)
+    for a, b in zip(whole, cut):
+        np.testing.assert_array_equal(a, b)
+    assert d0["tasks"] == d1["tasks"]
+    assert d0["stage_in_tiles"] == d1["stage_in_tiles"]
+    assert d0["stage_in_bytes"] == d1["stage_in_bytes"]
+    assert d0["sets_whole_by_wait"] > 0 == d1["sets_whole_by_wait"]
+    assert d0["stage_chunks"] < d1["stage_chunks"]
+    assert d0["tasks_ahead_of_copy"] < d1["tasks_ahead_of_copy"]
 
 
 def test_small_tiles_take_the_path_before_chunks_count_for_count(
