@@ -98,10 +98,20 @@ class DeviceBatchSpec:
     stacked dispatch (:func:`settle`); an answer replaces ``call`` with
     one built from the token alone, so the shared program holds no
     taskpool.
+
+    ``ahead`` (0 unless the operation that built the taskpool sets it):
+    the most tasks of the class one device can be handed at once, where
+    the operation knows it (``ops.dgetrf_1d`` over ``g`` accelerators:
+    a chip's share of the block columns).  A device then builds the
+    class's programs at its first group of the class, all of them: the
+    lone task's and every stacked bucket up to that many
+    (``JaxDevice._build_ahead``), so which buckets the arrival of its
+    ready sets happens to form in a later call builds nothing there.
     """
 
     __slots__ = ("name", "extract", "call", "batchable", "cache",
-                 "cache_token", "late_token", "mesh_ok", "__weakref__")
+                 "cache_token", "late_token", "mesh_ok", "ahead",
+                 "__weakref__")
 
     def __init__(self, name: str,
                  extract: Callable[[Any, Any], Optional[Tuple]],
@@ -119,6 +129,7 @@ class DeviceBatchSpec:
         # cleared when the mesh-sharded stacking of THIS class fails to
         # trace/dispatch (the single-chip stacked path stays available)
         self.mesh_ok = True
+        self.ahead = 0
 
 
 def bucket_size(navail: int, batch_max: int) -> int:

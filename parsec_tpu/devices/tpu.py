@@ -158,16 +158,17 @@ class _Xfer:
     telemetry sink (``DeviceObs.xfer``: histogram, overlap gauge, trace
     span) and, for a stage-in, the open root span's phase clock."""
 
-    __slots__ = ("obs", "clock", "direction", "nbytes", "t0")
+    __slots__ = ("obs", "clock", "direction", "nbytes", "args", "t0")
 
-    def __init__(self, obs, clock, direction: str, nbytes: int) -> None:
+    def __init__(self, obs, clock, direction: str, nbytes: int,
+                 args: Dict[str, Any]) -> None:
         self.obs, self.clock = obs, clock
-        self.direction, self.nbytes = direction, nbytes
+        self.direction, self.nbytes, self.args = direction, nbytes, args
         self.t0 = 0
 
     def __enter__(self) -> None:
         if self.clock is not None:
-            self.clock.push("stage_in", bytes=self.nbytes)
+            self.clock.push("stage_in", bytes=self.nbytes, **self.args)
         self.t0 = time.monotonic_ns()
 
     def __exit__(self, *exc) -> bool:
@@ -273,6 +274,14 @@ class JaxDevice(Device):
                       # the part of stage_in_bytes pulled from a copy
                       # on another chip
                       "stage_in_peer_bytes": 0,
+                      # chip-to-chip ``device_put`` calls issued for a
+                      # stage-in, a tile each (a Data's tile whose
+                      # newest copy another chip holds; a runtime-made
+                      # buffer another chip's task wrote), and the wall
+                      # ns of those calls on the manager's thread:
+                      # inside ``group`` (the per-task stage-in is), not
+                      # a bracket of their own
+                      "peer_pulls": 0, "peer_pull_ns": 0,
                       # ``device_put`` calls that moved tiles here for
                       # a stage-in, from the host or another chip (the
                       # one call of a set's list is one), and the tiles
@@ -364,6 +373,8 @@ class JaxDevice(Device):
         # stacked into one jitted call per (class, shapes, dtypes,
         # bucket) at the next manager flush
         self.batch_max = int(params.get("device_batch_max"))
+        #: signatures whose programs ``_build_ahead`` built here
+        self._built_ahead: set = set()
         # read by the stage compiler's prestager and the tuner only:
         # the manager stages a drained set by chunks of bytes
         # (``_dispatch_ready``)
@@ -491,15 +502,16 @@ class JaxDevice(Device):
     # ------------------------------------------------------------------ #
     # stage-in / execute                                                 #
     # ------------------------------------------------------------------ #
-    def _xfer(self, direction: str, nbytes: int):
+    def _xfer(self, direction: str, nbytes: int, **args: Any):
         """``with self._xfer("in", nbytes): <the transfer>`` — the one
         timing site of every stage-in / stage-out; a shared no-op
-        unless telemetry or a root span listens."""
+        unless telemetry or a root span listens.  ``args`` go with a
+        stage-in's span (``cls="peer"``: pulled from another chip)."""
         obs = self._obs
         clock = self._phases if direction == "in" else None
         if obs is None and clock is None:
             return _NO_XFER
-        return _Xfer(obs, clock, direction, nbytes)
+        return _Xfer(obs, clock, direction, nbytes, args)
 
     def _stage_in(self, task: Task,
                   donate_ok: Optional[Dict[int, bool]] = None) -> List[Any]:
@@ -549,8 +561,10 @@ class JaxDevice(Device):
                     self.stats["stage_in_tiles"] += 1
                     self.stats["scratch_stage_in_bytes"] += getattr(
                         payload, "nbytes", 0)
-                if from_host or _arr_device(payload) is not target:
+                if from_host:
                     payload = jax.device_put(payload, target)
+                elif _arr_device(payload) is not target:
+                    payload = self._peer_pull(payload, target)
                 arrays.append(payload)
                 continue
             copy = data.get_copy(self.device_index)
@@ -564,14 +578,17 @@ class JaxDevice(Device):
                 # credit the stale payload being replaced before reserving
                 self._account(-getattr(copy.payload, "nbytes", 0))
                 self._reserve(nbytes)
-                with self._xfer("in", nbytes):
-                    copy.payload = jax.device_put(
+                if is_device_array(src.payload):
+                    copy.payload = self._peer_pull(
                         src.payload, self._placement(data, target))
+                    self.stats["stage_in_peer_bytes"] += nbytes
+                else:
+                    with self._xfer("in", nbytes):
+                        copy.payload = jax.device_put(
+                            src.payload, self._placement(data, target))
                 self.stats["stage_in_bytes"] += nbytes
                 self.stats["stage_in_transfers"] += 1
                 self.stats["stage_in_tiles"] += 1
-                if is_device_array(src.payload):
-                    self.stats["stage_in_peer_bytes"] += nbytes
                 self._prefetched.pop(id(copy), None)  # staged-over: stale
             elif self._prefetched.pop(id(copy), None) is not None:
                 # the set pass staged this tile with its drained set
@@ -584,6 +601,20 @@ class JaxDevice(Device):
                 donate_ok[flow.flow_index] = True
             arrays.append(self._localize(copy.payload, target))
         return arrays
+
+    def _peer_pull(self, payload: Any, placement: Any) -> Any:
+        """ONE chip-to-chip ``device_put`` of a tile another chip holds,
+        for a stage-in: counted (``peer_pulls``), its wall on this
+        manager's thread kept (``peer_pull_ns``), and with a phase
+        clock a ``stage_in`` span of class ``peer``."""
+        import jax
+        t0 = _now()
+        with self._xfer("in", getattr(payload, "nbytes", 0), cls="peer"):
+            pulled = jax.device_put(payload, placement)
+        st = self.stats
+        st["peer_pulls"] += 1
+        st["peer_pull_ns"] += _now() - t0
+        return pulled
 
     # mesh seam (JaxMeshDevice overrides; the single-chip base is the
     # identity so the pre-mesh behavior is byte-for-byte unchanged)
@@ -863,12 +894,15 @@ class JaxDevice(Device):
             spec, static, shapes, donate = key
             g = groups[key]
             try:
-                if len(g) >= 2 and spec.late_token is not None \
+                if (len(g) >= 2 or spec.ahead > 1) \
+                        and spec.late_token is not None \
                         and not settle(spec):
                     # the spec's first stacked dispatch named its
                     # programs, and an earlier taskpool's trace of them
                     # failed: given up again, without tracing
                     self.stats["batch_downgrades"] += 1
+                if spec.ahead > 1 and spec.batchable and not any(donate):
+                    self._build_ahead(spec, static, shapes, g[0])
                 # re-check batchable each bucket: a trace failure in the
                 # first chunk must not re-trace/re-fail the rest
                 while len(g) >= 2 and spec.batchable:
@@ -891,6 +925,50 @@ class JaxDevice(Device):
                         self.pending.push_back((t2, e2))
                 raise
         return n
+
+    def _build_ahead(self, spec, static, shapes, entry: Tuple) -> None:
+        """Build on this device every program of a class whose operation
+        said how many of its tasks a device can hold at once
+        (``spec.ahead``): each stacked bucket up to that many, then the
+        lone task's, called on the arrays of ``entry`` (the first task
+        of the device's first group of this signature; a bucket of
+        ``b`` gets them ``b`` times) with the results dropped.  Once a
+        signature a device; a program the device has called is not
+        called again.  The wall is ``first_call_ns`` inside the
+        ``dispatch`` bracket and no call is counted: nothing was
+        dispatched.  A failure is the real dispatch's to meet."""
+        from .batching import bucket_size, cached_stacked_callable
+        key = (spec.cache_token, static, shapes)
+        if spec.cache_token is None or key in self._built_ahead:
+            return
+        self._built_ahead.add(key)
+        task, _est, inputs, bargs = entry
+        nargs = len(shapes)
+        clock = self._phases
+        t0 = _now()
+        if clock is not None:
+            clock.push("first_call", t0, cls=task.task_class.name, n=0)
+        built = 0
+        try:
+            b, top = 2, bucket_size(spec.ahead, self.batch_max)
+            while b <= top:
+                fn = cached_stacked_callable(spec, b, nargs, static,
+                                             shapes, (False,) * nargs)
+                if fn.first_call_on(self.name):
+                    fn(*[a for a in bargs for _ in range(b)])
+                    built += 1
+                b *= 2
+            task.task_class.incarnations[task.selected_chore].dyld_fn(
+                task, inputs)
+        except Exception as exc:
+            plog.warning("building %s ahead failed (%s: %s)", spec.name,
+                         type(exc).__name__, exc)
+        t1 = _now()
+        if clock is not None:
+            clock.pop("first_call", tasks=0, at_ns=t1)
+        self.stats["dispatch_ns"] += t1 - t0
+        self.stats["first_call_ns"] += t1 - t0
+        self.stats["first_calls"] += built
 
     def _dispatch_stacked(self, es, spec, static, shapes, donate,
                           chunk: List[Tuple]) -> None:

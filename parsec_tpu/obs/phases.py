@@ -45,7 +45,9 @@ under ``placement`` the tasks the device ran and which rule of
 ``stage`` the puts of its chunked set pass, the tasks it sent ahead
 of a copy and the sets it left whole (``STAGE_COUNTERS``), and under
 ``scratch`` the bytes of runtime-made buffers staged in from the host
-and written by its tasks (``SCRATCH_COUNTERS``).
+and written by its tasks (``SCRATCH_COUNTERS``), and under ``peer``
+the tiles it pulled from other chips, their bytes and what the pulls
+cost its manager's thread (``PEER_COUNTERS``).
 
 Closed root spans leave one record each in a bounded process-wide list
 (``completed()``): ``op``, ``id``, ``t0_ns``, ``t1_ns``, ``traced``,
@@ -63,6 +65,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = ["PHASES", "BRACKETS", "RESHAPE_COUNTERS", "PLACED_BY",
            "PLACEMENT_COUNTERS", "STAGE_COUNTERS", "SCRATCH_COUNTERS",
+           "PEER_COUNTERS",
            "PhaseClock", "root_span",
            "session_recording", "completed", "clear_completed",
            "format_report"]
@@ -110,10 +113,18 @@ STAGE_COUNTERS = ("stage_chunks", "tasks_ahead_of_copy",
 #: such buffers; a record's ``scratch``
 SCRATCH_COUNTERS = ("scratch_stage_in_bytes", "scratch_out_bytes")
 
+#: what a device pulled from other chips for a stage-in
+#: (``devices/tpu.py: _peer_pull``): the chip-to-chip ``device_put``
+#: calls, a tile each, the bytes of the ``Data`` tiles among them, and
+#: the wall ns of the calls on the manager's thread (inside ``group``);
+#: a record's ``peer``
+PEER_COUNTERS = ("peer_pulls", "stage_in_peer_bytes", "peer_pull_ns")
+
 #: the counter groups a record's ``by_device`` entry holds beside the
 #: brackets, by the key each goes under
 _GROUPS = {"reshape": RESHAPE_COUNTERS, "placement": PLACEMENT_COUNTERS,
-           "stage": STAGE_COUNTERS, "scratch": SCRATCH_COUNTERS}
+           "stage": STAGE_COUNTERS, "scratch": SCRATCH_COUNTERS,
+           "peer": PEER_COUNTERS}
 
 _now = time.monotonic_ns    # the clock of profiling.trace.ThreadStream
 _get_ident = threading.get_ident
@@ -483,6 +494,14 @@ def format_report(record: Dict[str, Any]) -> str:
         if staged or wrote:
             lines.append(f"runtime-made buffers: {wrote} bytes written by "
                          f"tasks, {staged} staged in from the host")
+        pulls, pulled, pull_ns = (sum(e.get("peer", {}).get(c, 0)
+                                      for e in record["by_device"])
+                                  for c in PEER_COUNTERS)
+        if pulls:
+            lines.append(f"peer pulls: {pulls} tiles from other chips, "
+                         f"{pulled} bytes of collection tiles, "
+                         f"{pull_ns / 1e9:.6f} s on the managers' threads "
+                         f"(inside group)")
     t0 = record["t0_ns"]
     for i, part in enumerate(record.get("parts", ())):
         lines.append(

@@ -45,9 +45,29 @@ flow of ``PANEL(k)`` read by the ``NT-1-k`` updates, by ``PANEL(k+1)``
 pulled to the host inside the call.  ``IPIV``, the second descriptor,
 has two tiles: the state before the first panel (staged in once) and the
 last panel's tile.
+
+Over several accelerators.  On a context with ``g > 1`` accelerators
+the entry point lays the block columns out ``1 x g`` as HPL does on a
+``1 x Q`` grid: column ``n`` is advised to accelerator ``n mod g`` in
+index order (``data_advise(.., "preferred_device")``;
+``layout_1xq``), so its first writer goes there by advice and every
+later one because that chip owns it: ``PANEL(n)``, every
+``UPDATE(k, n)`` and ``LASWP(n)`` run on one chip, and the columns with
+the most updates behind them never share one.  The panel reaches the
+other chips when their updates stage it in: each pulls the whole block
+column from the chip that factored it, once (``dev.stats``:
+``peer_pulls``, ``stage_in_peer_bytes``).  A column the caller advised
+stays where the caller said; with one accelerator nothing is advised.
+A chip then holds at most its ``ceil(NT / g)`` columns' ``UPDATE`` or
+``LASWP`` tasks at once, and in what buckets they reach its manager is
+the arrival's to say, call by call: the entry point tells the two
+classes' batch specs that count (``ahead``), and each chip builds the
+lone program and every stacked bucket up to it at its first task of the
+class, in the process's first call, and none in a later one.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any
 
 import numpy as np
@@ -161,6 +181,24 @@ def dgetrf_1d_taskpool(A: TiledMatrix, IPIV: TiledMatrix):
     return tp
 
 
+def layout_1xq(context, A: TiledMatrix) -> int:
+    """Advise block column ``n`` of ``A`` to accelerator ``n mod g`` of
+    the context's ``g`` accelerators, in index order; a column that
+    already names an accelerator of the context keeps it.  Nothing with
+    fewer than two accelerators (nothing to decide).  Returns the most
+    columns one accelerator was given, 0 where nothing was decided."""
+    devs = [d for d in context.devices if d.device_type == "tpu"]
+    if len(devs) < 2:
+        return 0
+    indices = {d.device_index for d in devs}
+    for n in range(A.nt):
+        data = A.data_of(0, n)
+        if data.preferred_device not in indices:
+            devs[n % len(devs)].data_advise(data, "preferred_device")
+    return max(Counter(A.data_of(0, n).preferred_device
+                       for n in range(A.nt)).values())
+
+
 def dgetrf_1d(context, A: TiledMatrix) -> Any:
     """Factor ``P A = L U`` in place with exact partial pivoting: on
     return ``A`` (block columns) holds unit-lower ``L`` strictly below
@@ -168,8 +206,17 @@ def dgetrf_1d(context, A: TiledMatrix) -> Any:
     block columns on the left are interchanged too.  Returns the pivots
     as LAPACK's ``ipiv`` (0-based, length ``min(M, N)``: row ``i`` was
     interchanged with row ``ipiv[i]``, in order), an array that stays
-    where the last panel ran: nothing is pulled to the host here.
-    Blocking: enqueue + wait."""
+    where the last panel ran: nothing is pulled to the host here.  On
+    several accelerators the block columns are laid out cyclically over
+    them first (``layout_1xq``).  Blocking: enqueue + wait."""
+    share = layout_1xq(context, A)
     IPIV = dgetrf_1d_ipiv(A)
-    run_blocking(context, "dgetrf_1d", [dgetrf_1d_taskpool(A, IPIV)])
+    tp = dgetrf_1d_taskpool(A, IPIV)
+    if share > 1:
+        # a chip holds at most its columns' updates (or LASWPs) at once
+        for tc in tp.task_classes:
+            for chore in tc.incarnations:
+                if tc.name != "PANEL" and chore.batch_spec is not None:
+                    chore.batch_spec.ahead = share
+    run_blocking(context, "dgetrf_1d", [tp])
     return IPIV.data_of(0, 1).newest_copy().payload[3, :min(A.lm, A.ln)]

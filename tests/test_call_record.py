@@ -64,10 +64,16 @@ def _check_device_entry(entry, root_ns):
     """One device's six brackets: all there, disjoint (they add to no
     more than the root span); beside them the reshape engine's
     counters, still where nothing declares a type, and the tasks the
-    device ran with the rule that placed each."""
+    device ran with the rule that placed each, and what it pulled from
+    other chips."""
     assert set(entry) == set(phases.BRACKETS) | {"device", "reshape",
                                                  "placement", "stage",
-                                                 "scratch"}
+                                                 "scratch", "peer"}
+    assert set(entry["peer"]) == set(phases.PEER_COUNTERS)
+    assert all(v >= 0 for v in entry["peer"].values())
+    # a pull is counted, measured and its tile's bytes booked together
+    assert bool(entry["peer"]["peer_pulls"]) \
+        == bool(entry["peer"]["peer_pull_ns"])
     assert set(entry["scratch"]) == set(phases.SCRATCH_COUNTERS)
     # no host body made a buffer a device task read
     assert entry["scratch"]["scratch_stage_in_bytes"] == 0
@@ -189,7 +195,11 @@ def test_each_device_has_its_own_disjoint_brackets(ctx4, records):
     assert [e["device"] for e in rec["by_device"]] == [d.name for d in devs]
     for entry, dev, was in zip(rec["by_device"], devs, before):
         _check_device_entry(entry, root)
-        assert entry["dispatch"]["count"] == _device_calls(_moved(dev, was))
+        moved = _moved(dev, was)
+        assert entry["dispatch"]["count"] == _device_calls(moved)
+        assert entry["peer"] == {c: moved[c] for c in phases.PEER_COUNTERS}
+    # a panel tile is read on other chips than the one that wrote it
+    assert sum(e["peer"]["peer_pulls"] for e in rec["by_device"]) > 0
     for b, total in rec["manager"].items():
         for f in total:
             assert total[f] == sum(e[b][f] for e in rec["by_device"])

@@ -296,7 +296,8 @@ def test_the_metric_reads_the_kernel_by_its_name_and_nothing_else():
     assert len(entry) == 1 and entry[0]["moves"] == "factor_s"
     assert entry[0]["layer"] == "tile kernels"
     cells = [w["name"] for w in bench["workloads"]
-             if spec.Cell(bench, w["name"]).op_name == "dgetrf_1d"]
+             if spec.Cell(bench, w["name"]).op["entry"]
+             .endswith(":dgetrf_1d")]
     assert len(cells) >= 2 and entry[0]["workloads"] == cells
     read = spec.metric_reader("update_kernel_device_s").read
     call = ('%lu_update_vmem{} = f32[16384,512] custom-call(s32[1] %r, '

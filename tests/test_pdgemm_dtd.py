@@ -395,8 +395,8 @@ def test_benchmark_lists_the_cell_and_its_readers():
     bench = spec.load_benchmark()
     cells = [w["name"] for w in bench["workloads"]]
     assert cells.index(CELL) == 10 and len(cells) >= 11  # later PRs append
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
-        == [CELL4, CELL]
+    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4][:2] \
+        == [CELL4, CELL]            # later PRs append theirs
     assert bench["configs"][8]["name"] == "dgemm-dtd-f32-4chip"
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"]
@@ -409,7 +409,8 @@ def test_benchmark_lists_the_cell_and_its_readers():
         "stage_out_gb", "busiest_chip_busy_s"]
     for name, (_value, listed) in READERS.items():
         m = per_layer[name]
-        assert m["workloads"] == listed and m["moves"] == "factor_s"
+        assert m["workloads"][:len(listed)] == listed   # later PRs append
+        assert m["moves"] == "factor_s"
         assert m["layer"] in layers
     # what the cell reports of what was there: the front end's spans,
     # its one class, and everything every cell reports
